@@ -730,8 +730,6 @@ def sharded_serving(dataset: str = "synthetic", *, quick: bool = True,
     (XLA_FLAGS=--xla_force_host_platform_device_count=8)."""
     import time as _time
 
-    import jax as _jax
-
     from repro.core.graph import BucketLadder
     from repro.core.partition import (modelled_sharded_latency,
                                       partition_graph)
@@ -791,12 +789,12 @@ def sharded_serving(dataset: str = "synthetic", *, quick: bool = True,
             exchange_widths=sharded_exchange_widths(cfg))
         modelled_rps.append(1.0 / modelled)
         s = eng.summary()
-        placement = ("shard_map" if 1 < shards <= len(_jax.devices())
-                     else ("vmap" if shards > 1 else "unsharded"))
+        placed = s["sharded_placement"].get(
+            shards, {"placement": "unsharded", "devices": 1})
         rows.append(record(
             f"sharded_serving/gcn/{dataset}/shards{shards}", wall,
-            f"devices={min(shards, len(_jax.devices()))} "
-            f"placement={placement} bucket={bucket} "
+            f"devices={placed['devices']} "
+            f"placement={placed['placement']} bucket={bucket} "
             f"modelled_rps={1.0 / modelled:.0f} "
             f"halo_bytes={s['halo_bytes_exchanged']} "
             f"exact_bytes={s['collective_bytes_exact']} "

@@ -77,6 +77,9 @@ def main() -> None:
                          "unknown name lists the registry")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import gnn_paper, lm_subs
     from .common import ROWS
 

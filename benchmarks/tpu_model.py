@@ -73,8 +73,6 @@ def analyze(fn: Callable, *args) -> Dict[str, float]:
     """Compile fn(*args) and derive the TPU-model latency terms."""
     compiled = jax.jit(fn).lower(*args).compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):       # older jax: one dict per device
-        ca = ca[0] if ca else {}
     txt = compiled.as_text()
 
     gather_bytes = 0

@@ -921,6 +921,9 @@ class ExecutionPlan:
     shards: int = 0                           # 0 = unsharded plan
     fn: Callable = dataclasses.field(default=None, repr=False)
     trace_count: int = 0
+    # Sharded plans only: how the shard axis is placed — "shard_map" over
+    # shards x replicas devices, or "vmap" simulating it on one device.
+    placement: str = ""
     # Captured AT TRACE TIME for grasp plans: True when the kernel routing
     # lowered the aggregation through the dense `ref` path (no skip grid).
     # The compiled blob keeps whatever lowering it was traced with, so
@@ -1209,6 +1212,8 @@ def build_sharded_plan(cfg: GNNConfig, shard_cap: int, shards: int,
     else replicated). With fewer devices — the common 1-CPU test box — the
     shard axis is vmap-simulated (`axis_name` collectives are identical),
     so the plan's math and trace structure never depend on device count.
+    Which of the two ran is recorded in `plan.placement` ("shard_map" or
+    "vmap"), so a fallback to one device is never silent.
     Sharded plans are dense, fusion="none", single-graph (the shard axis
     occupies the leading dim a batched plan would use); call with
     `plan(params, x, ops, quant, node_mask=mask)`.
@@ -1240,10 +1245,6 @@ def build_sharded_plan(cfg: GNNConfig, shard_cap: int, shards: int,
 
         from repro.dist.sharding import spec_for_axes
         from repro.launch.mesh import make_shard_mesh
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:                   # newer jax moved it
-            from jax import shard_map
         if replicas == 1:
             mesh = make_shard_mesh(shards)
             row = spec_for_axes(("graph_shard",), (shards,), mesh)
@@ -1261,11 +1262,13 @@ def build_sharded_plan(cfg: GNNConfig, shard_cap: int, shards: int,
                            sq(mask), quant)
             return out.reshape((1,) * lead + out.shape)
 
-        plan.fn = jax.jit(shard_map(
+        plan.placement = "shard_map"
+        plan.fn = jax.jit(jax.shard_map(
             _spmd, mesh=mesh,
             in_specs=(P(), x_spec, P(*row), mask_spec, P()),
-            out_specs=x_spec, check_rep=False))
+            out_specs=x_spec, check_vma=False))
     else:
+        plan.placement = "vmap"
         fn = jax.vmap(_forward, in_axes=(None, 0, 0, 0, None),
                       axis_name="shard")
         if replicas > 1:

@@ -49,16 +49,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .gat_attention import attend
+
 DEFAULT_BLOCK = (128, 128, 128)  # (bm, bn, bk)
 _INT8_MAX = 127.0
-_ROW_SLAB = 8                    # GrAx3 slab rows: 8*128*Fin*4B stays < VMEM
+_ROW_SLAB = 8                    # GrAx3 slab: one sublane tile of neighbours
 
 
 def _act(z: jnp.ndarray, activation: str) -> jnp.ndarray:
     if activation == "relu":
         return jnp.maximum(z, 0.0)
-    if activation == "elu":
-        return jnp.where(z > 0, z, jnp.expm1(z))
+    if activation == "elu":             # exp - 1: Mosaic lowers no expm1
+        return jnp.where(z > 0, z, jnp.exp(jnp.minimum(z, 0.0)) - 1.0)
     if activation == "none":
         return z
     raise ValueError(f"unknown activation {activation!r}")
@@ -301,32 +303,28 @@ def _gat_full_kernel(x_ref, w_ref, asv_ref, adv_ref, bias_ref, b_ref, o_ref,
     k = pl.program_id(2)
 
     # Combine phase: produce this head's H blocks into VMEM and reduce the
-    # alpha terms from them as they appear (GrAx2's operands).
+    # alpha terms from them as they appear (GrAx2's operands): dst terms as
+    # a column, src terms as a row — the two shapes the broadcast-add takes.
     @pl.when(i == 0)
     def _combine():
-        hblk = jnp.dot(x_ref[...], w_ref[...][:, 0, :],
+        hblk = jnp.dot(x_ref[...], w_ref[...],
                        preferred_element_type=jnp.float32)      # (bk, F)
-        hbuf_ref[pl.ds(k * bk, bk), :] = hblk
-        asb_ref[pl.ds(k * bk, bk), :] = jnp.sum(
-            hblk * asv_ref[...], axis=1, keepdims=True)
-        adb_ref[pl.ds(k * bk, bk), :] = jnp.sum(
-            hblk * adv_ref[...], axis=1, keepdims=True)
+        start = pl.multiple_of(k * bk, bk)
+        hbuf_ref[pl.ds(start, bk), :] = hblk
+        asb_ref[:, pl.ds(start, bk)] = jnp.sum(
+            (hblk * asv_ref[...]).T, axis=0, keepdims=True)    # (1, bk)
+        adb_ref[pl.ds(start, bk), :] = jnp.sum(
+            hblk * adv_ref[...], axis=1, keepdims=True)         # (bk, 1)
 
     # Attention phase: GrAx2 broadcast-add, leaky, GrAx1 additive mask, row
     # softmax, attn @ H, bias + act — the (bm, N) score strip never leaves
     # VMEM.
     @pl.when(k == k_steps - 1)
     def _attend():
-        ad = adb_ref[pl.ds(i * bm, bm), :]                      # (bm, 1)
-        e = ad + asb_ref[...][:, 0][None, :]                    # GrAx2
-        e = jnp.where(e >= 0, e, negative_slope * e)
-        e = e + bias_ref[...]                                   # GrAx1
-        e = e - jnp.max(e, axis=1, keepdims=True)
-        p = jnp.exp(e)
-        attn = p / jnp.maximum(p.sum(axis=1, keepdims=True), 1e-12)
-        z = jnp.dot(attn, hbuf_ref[...],
-                    preferred_element_type=jnp.float32) + b_ref[...]
-        o_ref[...] = _act(z, activation).astype(o_ref.dtype)[:, None, :]
+        ad = adb_ref[pl.ds(pl.multiple_of(i * bm, bm), bm), :]  # (bm, 1)
+        z = attend(ad, asb_ref[...], bias_ref[...], hbuf_ref[...],
+                   negative_slope) + b_ref[...]
+        o_ref[...] = _act(z, activation).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "negative_slope",
@@ -336,35 +334,35 @@ def fused_gat_full(x: jnp.ndarray, w: jnp.ndarray, a_src: jnp.ndarray,
                    *, block: tuple = DEFAULT_BLOCK,
                    negative_slope: float = 0.2, activation: str = "none",
                    interpret: bool = False) -> jnp.ndarray:
-    """Whole fp32 GAT layer in one grid, per head.
-
-    x: (N, Fin); w: (Fin, H, F); a_src/a_dst: (H, F); bias_add: (N, N);
-    b: (H, F) per-head bias rows -> out (N, H, F).
+    """Whole fp32 GAT layer in one grid, per head. Head-major throughout
+    (see `gat_attention`): x: (N, Fin); w: (H, Fin, F); a_src/a_dst/b:
+    (H, 1, F) per-head rows; bias_add: (N, N) -> out (H, N, F).
     """
     n, fin = x.shape
-    _, heads, f = w.shape
-    assert a_src.shape == (heads, f) and bias_add.shape == (n, n)
-    assert b.shape == (heads, f)
+    heads, _, f = w.shape
+    assert a_src.shape == (heads, 1, f) and a_dst.shape == (heads, 1, f)
+    assert b.shape == (heads, 1, f) and bias_add.shape == (n, n)
     bm, _, bk = block
     bm, bk = min(bm, n), min(bk, n)
     assert n % bm == 0 and n % bk == 0, (n, block)
     k_steps = n // bk
+    head_row = pl.BlockSpec((None, 1, f), lambda hd, i, k: (hd, 0, 0))
     return pl.pallas_call(
         functools.partial(_gat_full_kernel, k_steps=k_steps, bm=bm, bk=bk,
                           negative_slope=negative_slope, activation=activation),
         grid=(heads, n // bm, k_steps),
         in_specs=[
-            pl.BlockSpec((bk, fin), lambda hd, i, k: (k, 0)),      # X
-            pl.BlockSpec((fin, 1, f), lambda hd, i, k: (0, hd, 0)),  # W head
-            pl.BlockSpec((1, f), lambda hd, i, k: (hd, 0)),        # a_src
-            pl.BlockSpec((1, f), lambda hd, i, k: (hd, 0)),        # a_dst
-            pl.BlockSpec((bm, n), lambda hd, i, k: (i, 0)),        # bias strip
-            pl.BlockSpec((1, f), lambda hd, i, k: (hd, 0)),        # b head
+            pl.BlockSpec((bk, fin), lambda hd, i, k: (k, 0)),        # X
+            pl.BlockSpec((None, fin, f), lambda hd, i, k: (hd, 0, 0)),  # W
+            head_row,                                                # a_src
+            head_row,                                                # a_dst
+            pl.BlockSpec((bm, n), lambda hd, i, k: (i, 0)),          # bias
+            head_row,                                                # b
         ],
-        out_specs=pl.BlockSpec((bm, 1, f), lambda hd, i, k: (i, hd, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, heads, f), x.dtype),
+        out_specs=pl.BlockSpec((None, bm, f), lambda hd, i, k: (hd, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((heads, n, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, f), jnp.float32),
-                        pltpu.VMEM((n, 1), jnp.float32),
+                        pltpu.VMEM((1, n), jnp.float32),
                         pltpu.VMEM((n, 1), jnp.float32)],
         interpret=interpret,
     )(x, w, a_src, a_dst, bias_add, b)
@@ -375,18 +373,9 @@ def fused_gat_full(x: jnp.ndarray, w: jnp.ndarray, a_src: jnp.ndarray,
 
 def _gat_pre_kernel(ad_ref, as_ref, bias_ref, h_ref, b_ref, o_ref, *,
                     negative_slope: float, activation: str):
-    ad = ad_ref[...]                      # (bm, 1)
-    a_src = as_ref[...][:, 0]             # (N,)
-    e = ad + a_src[None, :]               # GrAx2
-    e = jnp.where(e >= 0, e, negative_slope * e)
-    e = e + bias_ref[...]                 # GrAx1
-    e = e - jnp.max(e, axis=1, keepdims=True)
-    p = jnp.exp(e)
-    attn = p / jnp.maximum(p.sum(axis=1, keepdims=True), 1e-12)
-    h = h_ref[...][:, 0, :]               # (N, F)
-    z = jnp.dot(attn.astype(h.dtype), h,
-                preferred_element_type=jnp.float32) + b_ref[...]
-    o_ref[...] = _act(z, activation).astype(o_ref.dtype)[:, None, :]
+    z = attend(ad_ref[...], as_ref[...], bias_ref[...], h_ref[...],
+               negative_slope) + b_ref[...]
+    o_ref[...] = _act(z, activation).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "negative_slope",
@@ -398,10 +387,13 @@ def fused_gat_precombined(h: jnp.ndarray, alpha_dst: jnp.ndarray,
                           activation: str = "none",
                           interpret: bool = False) -> jnp.ndarray:
     """QuantGr GAT: H from the int8 combine outside; attention + bias + act
-    fused. h: (N, H, F); alpha_*: (N, H); bias_add: (N, N); b: (H, F)."""
-    n, heads, f = h.shape
-    assert alpha_dst.shape == (n, heads) and bias_add.shape == (n, n)
-    assert b.shape == (heads, f)
+    fused. Head-major like `gat_attention`: h (H, N, F), alpha_dst
+    (H, N, 1), alpha_src (H, 1, N), bias_add (N, N), b (H, 1, F) -> out
+    (H, N, F)."""
+    heads, n, f = h.shape
+    assert alpha_dst.shape == (heads, n, 1), alpha_dst.shape
+    assert alpha_src.shape == (heads, 1, n) and bias_add.shape == (n, n)
+    assert b.shape == (heads, 1, f)
     bm = min(bm, n)
     assert n % bm == 0, (n, bm)
     return pl.pallas_call(
@@ -409,14 +401,14 @@ def fused_gat_precombined(h: jnp.ndarray, alpha_dst: jnp.ndarray,
                           activation=activation),
         grid=(heads, n // bm),
         in_specs=[
-            pl.BlockSpec((bm, 1), lambda hd, i: (i, hd)),       # alpha_dst
-            pl.BlockSpec((n, 1), lambda hd, i: (0, hd)),        # alpha_src
-            pl.BlockSpec((bm, n), lambda hd, i: (i, 0)),        # bias strip
-            pl.BlockSpec((n, 1, f), lambda hd, i: (0, hd, 0)),  # h, this head
-            pl.BlockSpec((1, f), lambda hd, i: (hd, 0)),        # b head
+            pl.BlockSpec((None, bm, 1), lambda hd, i: (hd, i, 0)),  # alpha_dst
+            pl.BlockSpec((None, 1, n), lambda hd, i: (hd, 0, 0)),   # alpha_src
+            pl.BlockSpec((bm, n), lambda hd, i: (i, 0)),            # bias strip
+            pl.BlockSpec((None, n, f), lambda hd, i: (hd, 0, 0)),   # h, this head
+            pl.BlockSpec((None, 1, f), lambda hd, i: (hd, 0, 0)),   # b head
         ],
-        out_specs=pl.BlockSpec((bm, 1, f), lambda hd, i: (i, hd, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, heads, f), h.dtype),
+        out_specs=pl.BlockSpec((None, bm, f), lambda hd, i: (hd, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((heads, n, f), h.dtype),
         interpret=interpret,
     )(alpha_dst, alpha_src, bias_add, h, b)
 
@@ -436,7 +428,10 @@ def _sage_kernel(mm_ref, xk_ref, xs_ref, ws_ref, wn_ref, b_ref, o_ref,
 
     # Aggregate phase (j == 0 only: the buffer is shared by every output
     # strip of this row-block): mean is M @ X on the MXU; max is the GrAx3
-    # masked multiply + max-pool streamed in row slabs.
+    # masked multiply + max-pool streamed in slabs of neighbours. For max
+    # the mask block arrives TRANSPOSED, (bk, bm): a slab of neighbours is
+    # then an 8-row sublane slice of both operands — a dynamic lane slice
+    # of the (bm, bk) block is not tile-aligned and the TPU refuses it.
     @pl.when(j == 0)
     def _agg():
         if aggregator == "mean":
@@ -444,12 +439,12 @@ def _sage_kernel(mm_ref, xk_ref, xs_ref, ws_ref, wn_ref, b_ref, o_ref,
                                        preferred_element_type=jnp.float32)
         else:
             def body(r, _):
-                sl = pl.ds(r * slab, slab)
-                msk = mm_ref[:, sl]                       # (bm, slab)
+                sl = pl.ds(pl.multiple_of(r * slab, slab), slab)
+                msk = mm_ref[sl, :]                       # (slab, bm)
                 pkk = xk_ref[sl, :]                       # (slab, Fin)
-                prod = msk[:, :, None] * pkk[None, :, :]  # GrAx3
+                prod = msk[:, :, None] * pkk[:, None, :]  # GrAx3
                 aggbuf_ref[...] = jnp.maximum(aggbuf_ref[...],
-                                              jnp.max(prod, axis=1))
+                                              jnp.max(prod, axis=0))
                 return 0
 
             jax.lax.fori_loop(0, n_slabs, body, 0)
@@ -486,12 +481,17 @@ def fused_sage(mask: jnp.ndarray, xk: jnp.ndarray, x: jnp.ndarray,
     assert n % bm == 0 and n % bk == 0 and o % bn == 0, (x.shape, w_self.shape)
     slab = min(bk, _ROW_SLAB)
     k_steps = n // bk
+    if aggregator == "mean":
+        mask_spec = pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))
+    else:                                # GrAx3 slabs index mask^T rows
+        mask, mask_spec = mask.T, pl.BlockSpec((bk, bm),
+                                               lambda i, j, k: (k, i))
     return pl.pallas_call(
         functools.partial(_sage_kernel, k_steps=k_steps, aggregator=aggregator,
                           slab=slab, n_slabs=bk // slab, activation=activation),
         grid=(n // bm, o // bn, k_steps),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),     # mask
+            mask_spec,                                          # mask
             pl.BlockSpec((bk, fin), lambda i, j, k: (k, 0)),    # xk
             pl.BlockSpec((bm, fin), lambda i, j, k: (i, 0)),    # X row strip
             pl.BlockSpec((fin, bn), lambda i, j, k: (0, j)),    # Wself strip

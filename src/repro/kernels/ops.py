@@ -68,6 +68,23 @@ def _pad2(x: jnp.ndarray, m0: int, m1: int) -> jnp.ndarray:
     return jnp.pad(x, ((0, p0), (0, p1)))
 
 
+def _head_major(h: jnp.ndarray, npad: int) -> jnp.ndarray:
+    """(N, H, F) -> the GAT kernels' (H, N + npad, F), F padded to 128."""
+    f = h.shape[2]
+    return jnp.pad(jnp.transpose(h, (1, 0, 2)),
+                   ((0, 0), (0, npad), (0, (-f) % 128)))
+
+
+def _head_major_alphas(alpha_dst: jnp.ndarray, alpha_src: jnp.ndarray):
+    """(N, H) alpha terms -> dst (H, N, 1) columns, src (H, 1, N) rows."""
+    return alpha_dst.T[:, :, None], alpha_src.T[:, None, :]
+
+
+def _node_major(out: jnp.ndarray, n: int, f: int) -> jnp.ndarray:
+    """Kernel (H, N', F') output -> (n, H, f), padding stripped."""
+    return jnp.transpose(out, (1, 0, 2))[:n, :, :f]
+
+
 def matmul(a: jnp.ndarray, b: jnp.ndarray, *, out_dtype=None) -> jnp.ndarray:
     """StaGr aggregation backbone: C = A @ B (MXU-tiled on TPU)."""
     mode = _mode()
@@ -141,12 +158,11 @@ def gat_attention(h: jnp.ndarray, alpha_dst: jnp.ndarray, alpha_src: jnp.ndarray
     mode = _mode()
     if mode == "ref":
         return ref.gat_attention_ref(h, alpha_dst, alpha_src, bias_add)
-    n, heads, f = h.shape
-    fpad = (-f) % 128
-    hp = jnp.pad(h, ((0, 0), (0, 0), (0, fpad))) if fpad else h
-    out = _gat_kernel(hp, alpha_dst, alpha_src, bias_add,
-                      interpret=(mode == "interpret"))
-    return out[:, :, :f]
+    f = h.shape[2]
+    out = _gat_kernel(_head_major(h, 0), *_head_major_alphas(alpha_dst,
+                                                             alpha_src),
+                      bias_add, interpret=(mode == "interpret"))
+    return _node_major(out, h.shape[0], f)
 
 
 def sage_max(mask01: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
@@ -264,20 +280,22 @@ def fused_gat_layer(x: Optional[jnp.ndarray], w: Optional[jnp.ndarray],
     # win the row softmax, padded rows produce garbage that is stripped.
     bias_p = jnp.pad(bias_add, ((0, npad), (0, npad)),
                      constant_values=ref.NEG_INF)
-    bp = _pad2(b, 1, 128)
+    bp = _pad2(b, 1, 128)[:, None, :]                  # (H, 1, F) head rows
     if precombined is not None:
         h, alpha_dst, alpha_src = precombined
         out = _fused_gat_pre_kernel(
-            jnp.pad(h, ((0, npad), (0, 0), (0, (-f) % 128))),
-            _pad2(alpha_dst, 128, 1), _pad2(alpha_src, 128, 1), bias_p, bp,
-            activation=activation, interpret=interp)
-        return out[:n, :, :f]
+            _head_major(h, npad),
+            *_head_major_alphas(_pad2(alpha_dst, 128, 1),
+                                _pad2(alpha_src, 128, 1)),
+            bias_p, bp, activation=activation, interpret=interp)
+        return _node_major(out, n, f)
+    w_hm = jnp.transpose(w, (1, 0, 2))                 # (H, Fin, F)
     out = _fused_gat_full_kernel(
         _pad2(x, 128, 128),
-        jnp.pad(w, ((0, (-w.shape[0]) % 128), (0, 0), (0, (-f) % 128))),
-        _pad2(a_src, 1, 128), _pad2(a_dst, 1, 128), bias_p, bp,
-        activation=activation, interpret=interp)
-    return out[:n, :, :f]
+        jnp.pad(w_hm, ((0, 0), (0, (-w.shape[0]) % 128), (0, (-f) % 128))),
+        _pad2(a_src, 1, 128)[:, None, :], _pad2(a_dst, 1, 128)[:, None, :],
+        bias_p, bp, activation=activation, interpret=interp)
+    return _node_major(out, n, f)
 
 
 def fused_sage_layer(x: jnp.ndarray, w_self: jnp.ndarray,

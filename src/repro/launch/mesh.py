@@ -29,10 +29,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, found {len(devs)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 (dryrun.py "
             f"sets this before importing jax)")
-    try:
-        return jax.make_mesh(shape, axes, devices=devs[:need])
-    except TypeError:  # older jax without the devices kwarg
-        return Mesh(np.asarray(devs[:need]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devs[:need])
 
 
 def make_host_mesh(*, data: int | None = None, model: int = 1):

@@ -467,6 +467,9 @@ class GraphServe:
         # sharded registry (§12): graph_id -> (partition, source Graph) for
         # graphs attach() auto-sharded past the top ladder bucket
         self._sharded: Dict[int, Tuple[GraphShards, Graph]] = {}
+        # shard count -> where its last dispatch actually ran: the plan's
+        # placement ("shard_map" | "vmap") and the output's device count
+        self._sharded_placement: Dict[int, Dict[str, object]] = {}
         self._graph_version: Dict[int, int] = {}
         self._warm_blobs: Optional[int] = None
         self._uid = 0
@@ -2068,6 +2071,8 @@ class GraphServe:
                           stack_operands([r.ops for r in slots]), quant,
                           node_mask=jnp.stack([r.shard_mask for r in slots]))
         logits.block_until_ready()
+        placed = {"placement": plan.placement,
+                  "devices": len(logits.sharding.device_set)}
         self.clock.on_batch(bkey)
         now = self.clock.now()
         host_logits = np.asarray(logits)
@@ -2099,6 +2104,7 @@ class GraphServe:
             self.metrics["slots_filled"] += len(batch)
             self.metrics["slots_total"] += R
             self.metrics["sharded_batches"] += 1
+            self._sharded_placement[head.shards] = placed
             self.metrics["halo_bytes_exchanged"] += (
                 comp_total if self.sc.halo_compress else exact_total)
             self.metrics["collective_bytes_compressed"] += comp_total
@@ -2176,12 +2182,15 @@ class GraphServe:
             "backend_fallbacks": self.metrics["backend_fallbacks"],
             # sharded serving (DESIGN.md §12): which attached graphs run
             # partitioned (and across how many shards), how many width-1
-            # sharded dispatches ran, and the collective traffic — actual
+            # sharded dispatches ran, where each shard count was placed
+            # (shard_map over N devices, or the one-device vmap), and the
+            # collective traffic — actual
             # bytes on the halo wire plus both counterfactual framings
             # (compressed vs exact), so the int8-wire win is inspectable
             "shard_counts": {gid: p.shards
                              for gid, (p, _) in self._sharded.items()},
             "sharded_batches": self.metrics["sharded_batches"],
+            "sharded_placement": dict(self._sharded_placement),
             "halo_bytes_exchanged": self.metrics["halo_bytes_exchanged"],
             "collective_bytes_compressed":
                 self.metrics["collective_bytes_compressed"],

@@ -96,10 +96,7 @@ def test_compressed_psum_subprocess():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.dist.compress import compressed_psum_mean, exact_psum_mean
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:   # pre-0.5 jax: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        shard_map = jax.shard_map
         mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("data",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64, 32))
 

@@ -430,6 +430,26 @@ def test_replica_groups_widen_sharded_dispatch():
         eng.detach(gid)
 
 
+@pytest.mark.parametrize("replicas,n_nodes,shards", [(1, 260, 4),
+                                                     (2, 200, 2)])
+def test_summary_reports_sharded_placement(replicas, n_nodes, shards):
+    """Where a sharded dispatch ran is observable, never a silent fallback:
+    under shard_map on shards x replicas devices when the host has them
+    (the multi-device leg), under the one-device vmap otherwise."""
+    eng = _replica_engine(replicas)
+    gid = eng.attach(_graph(n_nodes, 23), model="gcn")
+    assert eng._sharded[gid][0].shards == shards
+    eng.query(gid)
+    eng.run()
+    placed = eng.summary()["sharded_placement"][shards]
+    need = shards * replicas
+    if len(jax.devices()) >= need:
+        assert placed == {"placement": "shard_map", "devices": need}
+    else:
+        assert placed == {"placement": "vmap", "devices": 1}
+    eng.detach(gid)
+
+
 def test_partition_method_config_reaches_attach():
     """`GraphServeConfig.partition_method` selects the attach()-time
     partitioner: "greedy" reproduces the §12 streaming cut verbatim,
