@@ -515,7 +515,8 @@ def pipeline_overlap(dataset: str = "cora", *, n_requests: int = 24,
     upper bound no online scheduler can beat). Two claims, each against
     the sync driver where it is meaningful: THROUGHPUT — async beats the
     online `run()` baseline (batch window + overlap vs 1-of-N junk-width
-    batches); DEVICE IDLE — async's `device_idle_fraction` lands far
+    batches); DISPATCH IDLE — async's `dispatch_idle_fraction` (host
+    clock: the share of the span with no dispatch in progress) lands far
     below the offline `run()`'s, whose device sits provably idle through
     the entire host submit loop (the online driver's junk-slot batches
     keep its device busy on WASTED width, so its idle fraction measures
@@ -604,13 +605,13 @@ def pipeline_overlap(dataset: str = "cora", *, n_requests: int = 24,
             f"pipeline_overlap/{mode}/{dataset}/throughput",
             wall / n_requests,
             f"{n_requests / wall:.1f} req/s over {n_requests} mixed "
-            f"kind/bucket/tier requests, device_idle={idle:.2f} "
+            f"kind/bucket/tier requests, dispatch_idle={idle:.2f} "
             f"occupancy={occ:.2f} (best of 3 interleaved passes)"))
     (ws, _, _), (wa, ai, _) = stats["sync"], stats["async"]
     (wo, oi, _) = stats["offline"]
     rows.append(record(
         f"pipeline_overlap/{dataset}/speedup", 0.0,
-        f"{ws / wa:.2f}x async vs online run(); device_idle "
+        f"{ws / wa:.2f}x async vs online run(); dispatch_idle "
         f"{oi:.2f} (submit-all run()) -> {ai:.2f} (pipelined); "
         f"offline oracle wall at {wo / wa:.2f}x of async"))
     return rows

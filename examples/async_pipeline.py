@@ -66,7 +66,7 @@ def main():
     sync_s = time.perf_counter() - t0
     s = eng.summary()
     print(f"sync  run(): {N_REQ / sync_s:5.1f} req/s  "
-          f"device_idle={s['device_idle_fraction']:.2f}  "
+          f"dispatch_idle={s['dispatch_idle_fraction']:.2f}  "
           f"occupancy={s['batch_occupancy']:.2f}")
 
     # --- async pipeline: same arrivals, host workers + batching dispatcher
@@ -82,7 +82,7 @@ def main():
     eng.assert_warm()                 # overlap won, zero recompiles paid
     s = eng.summary()
     print(f"async pipe : {N_REQ / async_s:5.1f} req/s  "
-          f"device_idle={s['device_idle_fraction']:.2f}  "
+          f"dispatch_idle={s['dispatch_idle_fraction']:.2f}  "
           f"occupancy={s['batch_occupancy']:.2f}  "
           f"(host workers={pc.host_workers}, window={pc.window_ms}ms)")
     print(f"\n{sync_s / async_s:.2f}x async vs sync; "

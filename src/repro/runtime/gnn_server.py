@@ -173,6 +173,7 @@ from repro.runtime.cache import (CacheAdmissionError, DeviceCacheManager,
 from repro.runtime.clock import WALL, Clock
 from repro.runtime.ewma import LatencyBank
 from repro.runtime.slo import SLOConfig, SLOGovernor
+from repro.runtime.tracing import Tracer, default_tracer
 
 # Per-kind serving techniques for models registered WITHOUT a tier ladder.
 # GraSp is deliberately NOT a technique flag here: block-sparse aggregation
@@ -428,13 +429,17 @@ class _ModelEntry:
 class GraphServe:
     def __init__(self, sc: Optional[GraphServeConfig] = None, *, seed: int = 0,
                  clock: Optional[Clock] = None,
-                 slo: Optional[SLOConfig] = None):
+                 slo: Optional[SLOConfig] = None,
+                 tracer: Optional[Tracer] = None):
         self.sc = sc or GraphServeConfig()
         self.seed = seed
         # §14: every timestamp, deadline comparison, and latency sample in
         # the serving path reads THIS clock — tests inject a fake one and
         # drive the whole SLO loop without a single real sleep
         self.clock = clock if clock is not None else WALL
+        # spans of the request and dispatch paths (runtime/tracing.py), on
+        # this engine's clock; the process's default ring unless injected
+        self.tracer = tracer if tracer is not None else default_tracer()
         # §14: measured-latency oracle per BatchKey, roofline-seeded; the
         # single cost source behind backend routing and the tier router
         self.bank = LatencyBank()
@@ -485,6 +490,9 @@ class GraphServe:
         self.metrics = {"batches": 0, "slots_filled": 0, "slots_total": 0,
                         "rebucket_events": 0, "latency_s": [],
                         "first_submit_s": None, "last_finish_s": None,
+                        # host clock from the start of each dispatch span to
+                        # the end of its d2h child: the whole device stage
+                        # as the host sees it, not time the chip was busy
                         "device_busy_s": 0.0,
                         "operand_bytes_h2d": 0, "operand_cache_hits": 0,
                         "operand_cache_misses": 0, "cacheg_fallbacks": 0,
@@ -1941,9 +1949,13 @@ class GraphServe:
         tier, backend, fusion) key, from exactly ONE thread at a time (the sync
         `run()` loop, or the pipeline scheduler's dispatcher). Junk slots
         repeat a real request so batch width never changes shape; their
-        outputs are dropped. `device_busy_s` accumulates the wall-clock of
-        this stage — the pipeline's device-idle fraction is measured
-        against it. A grasp dispatch whose plan was TRACED through the
+        outputs are dropped. The dispatch is a `dispatch` span tiled by
+        five children (`dispatch.stack`, `.h2d`, `.operands`, `.device`,
+        `.d2h`; runtime/tracing.py), all under one dispatch serial.
+        `device_busy_s` accumulates host clock from the dispatch span's
+        start to the end of its d2h child — the whole stage as the host
+        sees it, which `summary()["dispatch_idle_fraction"]` is measured
+        against. A grasp dispatch whose plan was TRACED through the
         `ref` kernel routing ran the aggregation dense (plain XLA over the
         block form, no skip grid) — every request in it is counted as
         `backend_fallbacks` so the degradation is observable, never
@@ -1964,42 +1976,83 @@ class GraphServe:
         b = self.sc.batch_slots
         bkey = (head.model, head.bucket, head.tier, head.backend,
                 head.fusion, 0)
-        t0 = self.clock.now()
-        # fixed batch width: junk slots repeat a real request, outputs dropped
-        slots = batch + [batch[-1]] * (b - len(batch))
-        e = self.models[head.model]
-        x = jnp.asarray(stack_padded([r.pg for r in slots]).features)
-        # CacheG: r.ops are device-resident (materialized or cached), so this
-        # stack is a device-side concat — only the activations `x` crossed
-        # the host→device link for this dispatch (DESIGN.md §7).
-        ops = stack_operands([r.ops for r in slots])
-        tops = (stack_tier_operands([r.tier_ops for r in slots])
-                if slots[0].tier_ops is not None else None)
-        plan = self.plan_for(head.model, head.bucket, head.tier,
-                             head.backend, head.fusion)
-        logits = plan(e.params, x, ops, e.calibrations.get(head.tier), tops)
-        logits.block_until_ready()
-        # trace-time capture, not a dispatch-time env read: the compiled
-        # blob keeps whatever lowering it was traced with
-        ran_dense_fallback = plan.grasp_ref_fallback
+        serial = self._next_serial()
+        span = self.tracer.span
+        with span("dispatch", self.clock, serial) as disp:
+            # fixed batch width: junk slots repeat a real request, outputs
+            # dropped
+            slots = batch + [batch[-1]] * (b - len(batch))
+            e = self.models[head.model]
+            with span("dispatch.stack", self.clock, serial):
+                features = stack_padded([r.pg for r in slots]).features
+            with span("dispatch.h2d", self.clock, serial,
+                      bytes=features.nbytes, filled=len(batch)):
+                x = jnp.asarray(features)
+            # CacheG: r.ops are device-resident (materialized or cached), so
+            # this stack is a device-side concat — only the activations `x`
+            # crossed the host→device link for this dispatch (DESIGN.md §7).
+            with span("dispatch.operands", self.clock, serial):
+                ops = stack_operands([r.ops for r in slots])
+                tops = (stack_tier_operands([r.tier_ops for r in slots])
+                        if slots[0].tier_ops is not None else None)
+            with span("dispatch.device", self.clock, serial):
+                plan = self.plan_for(head.model, head.bucket, head.tier,
+                                     head.backend, head.fusion)
+                logits = plan(e.params, x, ops, e.calibrations.get(head.tier),
+                              tops)
+                logits.block_until_ready()
+                # §14: fake clocks advance scripted per-key latency here —
+                # between the dispatch timestamps — so batch cost is a test
+                # input
+                self.clock.on_batch(bkey)
+            # trace-time capture, not a dispatch-time env read: the compiled
+            # blob keeps whatever lowering it was traced with
+            ran_dense_fallback = plan.grasp_ref_fallback
+            with span("dispatch.d2h", self.clock, serial) as d2h:
+                host_logits = np.asarray(logits)
+                for i, r in enumerate(batch):
+                    lg = host_logits[i, : r.pg.num_nodes]
+                    r.preds = lg.argmax(axis=-1).astype(np.int32)
+                    if self.sc.return_logits:
+                        r.logits = lg
+            counts = {}
+            if head.backend == "grasp":
+                counts["grasp_batches"] = 1
+                if ran_dense_fallback:
+                    # per REQUEST (same unit as tier_fallbacks and the
+                    # forced-but-ineligible count): every request in this
+                    # dispatch ran its aggregation dense under ref routing
+                    counts["backend_fallbacks"] = len(batch)
+            self._finish_dispatch(batch, bkey, serial, b, disp.start,
+                                  d2h.end, counts)
 
-        # §14: fake clocks advance scripted per-key latency here — between
-        # the dispatch timestamps — so batch cost is a test input
-        self.clock.on_batch(bkey)
-        now = self.clock.now()
-        host_logits = np.asarray(logits)
-        for i, r in enumerate(batch):
-            lg = host_logits[i, : r.pg.num_nodes]
-            r.preds = lg.argmax(axis=-1).astype(np.int32)
-            if self.sc.return_logits:
-                r.logits = lg
-            r.done = True
+    def _next_serial(self) -> int:
+        """A fresh dispatch serial: the id of a dispatch's spans, the parent
+        of its requests' spans, and the fairness stamp of its model."""
+        with self._lock:
+            serial = self._dispatch_serial
+            self._dispatch_serial += 1
+        return serial
+
+    def _finish_dispatch(self, batch: List[GNNRequest], bkey: BatchKey,
+                         serial: int, width: int, t0: float, now: float,
+                         counts: Dict[str, int]) -> None:
+        """Close one dispatch whose answers are unpacked: stamp every
+        request finished at `now` (the end of the dispatch's d2h span),
+        record its `request.queue` span (submission to `t0`, the
+        dispatch's start), and account the dispatch — the latency bank,
+        latencies, slots, `device_busy_s` and the path's own `counts` —
+        under the lock."""
+        for r in batch:
             r.finished_s = now
+            r.done = True
             if r.deadline_s is not None and now > r.deadline_s:
                 # executed but late (§14): the answer is delivered, the
                 # breach is flagged — distinct from pre-dispatch expiry,
                 # where preds stay None
                 r.deadline_missed = True
+            self.tracer.record("request.queue", r.submitted_s, t0, r.uid,
+                               serial)
         with self._lock:
             self.bank.observe(bkey, now - t0)
             for r in batch:
@@ -2012,18 +2065,12 @@ class GraphServe:
                     self.governor.observe(lat)
             self.metrics["batches"] += 1
             self.metrics["slots_filled"] += len(batch)
-            self.metrics["slots_total"] += b
-            if head.backend == "grasp":
-                self.metrics["grasp_batches"] += 1
-                if ran_dense_fallback:
-                    # per REQUEST (same unit as tier_fallbacks and the
-                    # forced-but-ineligible count): every request in this
-                    # dispatch ran its aggregation dense under ref routing
-                    self.metrics["backend_fallbacks"] += len(batch)
+            self.metrics["slots_total"] += width
+            for name, n in counts.items():
+                self.metrics[name] += n
             self.metrics["device_busy_s"] += now - t0
             self.metrics["last_finish_s"] = now
-            self._last_dispatch[head.model] = self._dispatch_serial
-            self._dispatch_serial += 1
+            self._last_dispatch[bkey[0]] = serial
 
     def _halo_bytes(self, cfg: GNNConfig, part: GraphShards
                     ) -> Tuple[int, int]:
@@ -2051,68 +2098,56 @@ class GraphServe:
         within itself (the psum names only the shard axis), so collective
         bytes are accounted per REAL request — both what the compressed
         wire moved and what exact fp32 would have, so the compression win
-        is a metric, not a claim."""
+        is a metric, not a claim. The spans are `_execute_batch`'s but for
+        `dispatch.stack` and `dispatch.h2d`: the shard features are
+        device-resident since the host stage, so nothing is stacked on the
+        host or sent here, and the replica stack of `shard_x` (R > 1) is a
+        device-side concat under `dispatch.operands`."""
         head = batch[0]
         R = self.sc.replica_groups
         bkey = (head.model, head.bucket, head.tier, "dense", "none",
                 head.shards)
-        t0 = self.clock.now()
-        e = self.models[head.model]
-        plan = self.plan_for(head.model, head.bucket, head.tier,
-                             shards=head.shards)
-        quant = e.calibrations.get(head.tier)
-        if R == 1:
-            logits = plan(e.params, head.shard_x, head.ops, quant,
-                          node_mask=head.shard_mask)
-        else:
+        serial = self._next_serial()
+        span = self.tracer.span
+        with span("dispatch", self.clock, serial) as disp:
+            e = self.models[head.model]
             slots = batch + [batch[-1]] * (R - len(batch))
-            logits = plan(e.params,
-                          jnp.stack([r.shard_x for r in slots]),
-                          stack_operands([r.ops for r in slots]), quant,
-                          node_mask=jnp.stack([r.shard_mask for r in slots]))
-        logits.block_until_ready()
-        placed = {"placement": plan.placement,
-                  "devices": len(logits.sharding.device_set)}
-        self.clock.on_batch(bkey)
-        now = self.clock.now()
-        host_logits = np.asarray(logits)
-        comp_total = exact_total = 0
-        for i, r in enumerate(batch):
-            lg = unshard_logits(host_logits[i] if R > 1 else host_logits,
-                                r.part)
-            r.preds = lg.argmax(axis=-1).astype(np.int32)
-            if self.sc.return_logits:
-                r.logits = lg
-            r.done = True
-            r.finished_s = now
-            if r.deadline_s is not None and now > r.deadline_s:
-                r.deadline_missed = True
-            comp, exact = self._halo_bytes(e.cfg, r.part)
-            comp_total += comp
-            exact_total += exact
-        with self._lock:
-            self.bank.observe(bkey, now - t0)
-            for r in batch:
-                lat = now - r.submitted_s
-                self.metrics["latency_s"].append(lat)
-                self.finished.append(r)
-                if r.deadline_missed:
-                    self.metrics["deadline_misses"] += 1
-                if self.governor is not None:
-                    self.governor.observe(lat)
-            self.metrics["batches"] += 1
-            self.metrics["slots_filled"] += len(batch)
-            self.metrics["slots_total"] += R
-            self.metrics["sharded_batches"] += 1
-            self._sharded_placement[head.shards] = placed
-            self.metrics["halo_bytes_exchanged"] += (
-                comp_total if self.sc.halo_compress else exact_total)
-            self.metrics["collective_bytes_compressed"] += comp_total
-            self.metrics["collective_bytes_exact"] += exact_total
-            self.metrics["device_busy_s"] += now - t0
-            self.metrics["last_finish_s"] = now
-            self._last_dispatch[head.model] = self._dispatch_serial
-            self._dispatch_serial += 1
+            with span("dispatch.operands", self.clock, serial):
+                x, mask, ops = head.shard_x, head.shard_mask, head.ops
+                if R > 1:
+                    x = jnp.stack([r.shard_x for r in slots])
+                    mask = jnp.stack([r.shard_mask for r in slots])
+                    ops = stack_operands([r.ops for r in slots])
+            with span("dispatch.device", self.clock, serial):
+                plan = self.plan_for(head.model, head.bucket, head.tier,
+                                     shards=head.shards)
+                logits = plan(e.params, x, ops, e.calibrations.get(head.tier),
+                              node_mask=mask)
+                logits.block_until_ready()
+                placed = {"placement": plan.placement,
+                          "devices": len(logits.sharding.device_set)}
+                self.clock.on_batch(bkey)
+            with span("dispatch.d2h", self.clock, serial) as d2h:
+                host_logits = np.asarray(logits)
+                comp_total = exact_total = 0
+                for i, r in enumerate(batch):
+                    lg = unshard_logits(host_logits[i] if R > 1
+                                        else host_logits, r.part)
+                    r.preds = lg.argmax(axis=-1).astype(np.int32)
+                    if self.sc.return_logits:
+                        r.logits = lg
+                    comp, exact = self._halo_bytes(e.cfg, r.part)
+                    comp_total += comp
+                    exact_total += exact
+            with self._lock:
+                self._sharded_placement[head.shards] = placed
+            self._finish_dispatch(
+                batch, bkey, serial, R, disp.start, d2h.end,
+                {"sharded_batches": 1,
+                 "halo_bytes_exchanged": (comp_total if self.sc.halo_compress
+                                          else exact_total),
+                 "collective_bytes_compressed": comp_total,
+                 "collective_bytes_exact": exact_total})
 
     # -------------------------------------------------------------- pipeline
     def scheduler(self, pc=None):
@@ -2159,10 +2194,12 @@ class GraphServe:
             "batch_occupancy": (self.metrics["slots_filled"]
                                 / max(self.metrics["slots_total"], 1)),
             "device_busy_s": self.metrics["device_busy_s"],
-            # fraction of the serving span the device stage sat idle —
+            # fraction of the serving span no dispatch was in progress —
             # the pipeline scheduler's overlap claim is judged on this
-            # (DESIGN.md §9); 1 - busy/span, 0 when nothing ran
-            "device_idle_fraction": (
+            # (DESIGN.md §9); 1 - busy/span, 0 when nothing ran. Host
+            # clock around whole dispatches: the chip itself can sit idle
+            # through most of a dispatch, which only a profile shows
+            "dispatch_idle_fraction": (
                 max(0.0, 1.0 - self.metrics["device_busy_s"] / span)
                 if span > 0 else 0.0),
             "rebucket_events": self.metrics["rebucket_events"],

@@ -233,6 +233,11 @@ class PipelineScheduler:
                                          deadline_ms=w.deadline_ms,
                                          tolerance=w.tolerance)
 
+    def _host_span(self):
+        """The `host` span of one request's host stage, on the engine's
+        clock and tracer; `host_busy_s` accumulates its length."""
+        return self.engine.tracer.span("host", self.engine.clock)
+
     def _host_loop(self) -> None:
         while True:
             with self._cond:
@@ -243,15 +248,15 @@ class PipelineScheduler:
                 w = self._pending.popleft()
                 self._inflight_host += 1
                 self._cond.notify_all()          # intake space freed
-            t0 = time.perf_counter()
             req = err = None
-            try:
-                req = self._prepare(w)
-            except BaseException as exc:         # noqa: BLE001 — recorded,
-                err = exc                        # re-raised by drain()
-            dt = time.perf_counter() - t0
+            with self._host_span() as sp:
+                try:
+                    req = self._prepare(w)
+                    sp.id = req.uid
+                except BaseException as exc:     # noqa: BLE001 — recorded,
+                    err = exc                    # re-raised by drain()
             with self._cond:
-                self.metrics["host_busy_s"] += dt
+                self.metrics["host_busy_s"] += sp.end - sp.start
                 if err is not None:
                     self._errors[w.ticket] = err
                     self._inflight_host -= 1
@@ -376,9 +381,10 @@ class PipelineScheduler:
         only ready work remains). Deterministic mode only."""
         if self._pending and self._ready_count < self.pc.max_ready:
             w = self._pending.popleft()
-            t0 = time.perf_counter()
-            req = self._prepare(w)               # inline: errors propagate
-            self.metrics["host_busy_s"] += time.perf_counter() - t0
+            with self._host_span() as sp:
+                req = self._prepare(w)           # inline: errors propagate
+                sp.id = req.uid
+            self.metrics["host_busy_s"] += sp.end - sp.start
             self._push_ready_locked(w.ticket, req)
             return
         if self._ready_count:
@@ -440,8 +446,9 @@ class PipelineScheduler:
 
     # -------------------------------------------------------------- metrics
     def summary(self) -> Dict[str, object]:
-        """Engine summary (device_busy_s / device_idle_fraction included)
-        plus the pipeline's own counters under `"pipeline"`."""
+        """Engine summary (device_busy_s / dispatch_idle_fraction included)
+        plus the pipeline's own counters under `"pipeline"`. Both are host
+        clock around whole stages, not time the chip was busy."""
         s = self.engine.summary()
         s["pipeline"] = {
             "host_workers": self.pc.host_workers,
