@@ -11,7 +11,7 @@ the JAX analogue of the paper's "one precompiled blob" (jit cache hit).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -314,39 +314,6 @@ class BucketLadder:
                       test_mask=_grown(pg.test_mask, False, bool))
         cap = self.bucket_for(num_nodes)
         return pad_graph(fresh, capacity=cap, norm=norm), True
-
-
-@dataclasses.dataclass
-class BatchedGraphs:
-    """Same-bucket PaddedGraphs stacked with a leading batch dimension."""
-
-    capacity: int
-    num_nodes: np.ndarray     # (B,) int32
-    features: np.ndarray      # (B, cap, F)
-    norm_adj: np.ndarray      # (B, cap, cap)
-    adj: np.ndarray           # (B, cap, cap)
-    node_mask: np.ndarray     # (B, cap)
-
-    @property
-    def batch(self) -> int:
-        return int(self.features.shape[0])
-
-
-def stack_padded(pgs: Sequence[PaddedGraph]) -> BatchedGraphs:
-    """Stack PaddedGraphs of one bucket for vmapped batched execution."""
-    if not pgs:
-        raise ValueError("cannot stack an empty graph batch")
-    caps = {pg.capacity for pg in pgs}
-    if len(caps) != 1:
-        raise ValueError(f"mixed NodePad buckets in one batch: {sorted(caps)}")
-    return BatchedGraphs(
-        capacity=pgs[0].capacity,
-        num_nodes=np.asarray([pg.num_nodes for pg in pgs], np.int32),
-        features=np.stack([pg.features for pg in pgs]),
-        norm_adj=np.stack([pg.norm_adj for pg in pgs]),
-        adj=np.stack([pg.adj for pg in pgs]),
-        node_mask=np.stack([pg.node_mask for pg in pgs]),
-    )
 
 
 @dataclasses.dataclass

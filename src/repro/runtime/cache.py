@@ -1,10 +1,11 @@
 """Byte-budgeted device cache for GraphServe's operand hierarchy (§13).
 
-CacheG (DESIGN.md §7) keeps four device-resident forms per attached graph
-— the fp32 operand set, the derived int8 Â, the derived GraSp structure,
-and the sharded slice tuple — all keyed by (graph_id, structure_version)
-and NOTHING else. Unbounded, that pins O(cap²) device bytes per graph and
-OOMs long before production graph counts. This module bounds it:
+CacheG (DESIGN.md §7) keeps five device-resident forms per attached graph
+— the fp32 operand set, the padded features, the derived int8 Â, the
+derived GraSp structure, and the sharded slice tuple — all keyed by
+(graph_id, structure_version) and NOTHING else. Unbounded, that pins
+O(cap²) device bytes per graph and OOMs long before production graph
+counts. This module bounds it:
 
   * every entry carries its MEASURED device-byte cost (`pytree_nbytes` of
     the actual leaves, not an estimate) and a re-materialization cost
@@ -38,8 +39,9 @@ import jax
 
 Key = Tuple[int, int]                    # (graph_id, structure_version)
 
-# derived forms (rank 0) evict before the primary they hang off (rank 1)
-KIND_RANK = {"tier": 0, "grasp": 0, "operand": 1, "shard": 1}
+# derived forms and the features (rank 0) evict before the primary they
+# hang off (rank 1); only primaries spill
+KIND_RANK = {"tier": 0, "grasp": 0, "features": 0, "operand": 1, "shard": 1}
 PRIMARY_KINDS = ("operand", "shard")
 
 
@@ -86,7 +88,7 @@ class CacheEntry:
 
 
 class DeviceCacheManager:
-    """The four operand caches behind one byte budget (DESIGN.md §13)."""
+    """The five device caches behind one byte budget (DESIGN.md §13)."""
 
     def __init__(self, *, budget_bytes: Optional[int] = None,
                  spill_to_host: bool = True):
@@ -120,7 +122,7 @@ class DeviceCacheManager:
 
     def view(self, kind: str) -> Dict[Key, object]:
         """Snapshot of one kind's entries as a plain {key: value} dict —
-        the shape the four caches had before the manager existed."""
+        the shape the caches had before the manager existed."""
         return {e.key: e.value for e in self._entries.values()
                 if e.kind == kind}
 

@@ -46,10 +46,11 @@ of *graphs* — the paper's actual workload:
   * CacheG (DESIGN.md §7) — operands cross the host→device link as a
     bit-packed compact form (SymG triangular for undirected graphs) and are
     expanded to the dense float32 set ON DEVICE by a jitted materializer;
-    attached graphs cache the materialized result per
-    (graph_id, structure_version), so repeated queries of an unchanged
-    graph move ZERO operand bytes and `_run_batch` stacks device-resident
-    buffers. `update()` bumps the version and re-materializes once.
+    attached graphs cache the materialized result and their padded
+    features per (graph_id, structure_version), so repeated queries of an
+    unchanged graph move ZERO operand or feature bytes and `_run_batch`
+    stacks device-resident buffers. `update()` bumps the version and
+    re-materializes once.
     Directed GCN/GAT graphs fall back to the eager dense upload (counted as
     `cacheg_fallbacks`) — same plans, no extra traces.
   * GraSp agg backends (DESIGN.md §10) — every request's aggregation
@@ -142,12 +143,13 @@ import dataclasses
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import (BucketLadder, Graph, PaddedGraph,
                               apply_edge_delta, edge_index_from_adjacency,
-                              is_symmetric_adjacency, pad_graph, stack_padded)
+                              is_symmetric_adjacency, pad_graph)
 from repro.core.layers import Techniques
 from repro.core.models import (FUSION_MODES, OPERAND_FIELDS, DeltaSpec,
                                ExecutionPlan, GNNConfig, GranniteOperands,
@@ -352,6 +354,8 @@ class GNNRequest:
     ops: GranniteOperands
     bucket: int
     submitted_s: float
+    x: Optional[jnp.ndarray] = None        # (cap, F) device features of an
+    # unsharded request; dropped once its dispatch has run
     tier: str = "fp32"                     # resolved tier (post-fallback)
     backend: str = "dense"                 # resolved agg backend (§10)
     fusion: str = "none"                   # resolved fusion mode (§11)
@@ -454,16 +458,20 @@ class GraphServe:
         self._agg_quantizer = build_agg_quantizer()
         self._block_compactor = build_block_compactor()
         self._delta_patcher = build_delta_patcher()
+        # the dispatch's device-side stack of its slots' resident features:
+        # one compiled program per (batch_slots, bucket, in_feats)
+        self._stack_x = jax.jit(jnp.stack)
         if self.sc.admission not in ("evict", "reject"):
             raise ValueError(f"unknown admission policy "
                              f"{self.sc.admission!r}; pick evict|reject")
         # CacheG device-resident operand hierarchy, keyed by (graph_id,
         # structure_version) and NOTHING else: the primary fp32 operands
-        # ("operand"), the DERIVED forms of the same version — GCN's int8 Â
-        # ("tier") and the resolved agg backend plus budget-padded block
-        # structure ("grasp", DESIGN.md §10) — and the sharded slice tuple
-        # ("shard", §12). Since §13 all four live under one byte-budgeted
-        # manager (`runtime/cache.py`): cost-aware LRU eviction against
+        # ("operand"), the graph's padded fp32 features ("features"), the
+        # DERIVED forms of the same version — GCN's int8 Â ("tier") and the
+        # resolved agg backend plus budget-padded block structure ("grasp",
+        # DESIGN.md §10) — and the sharded slice tuple ("shard", §12).
+        # Since §13 all five live under one byte-budgeted manager
+        # (`runtime/cache.py`): cost-aware LRU eviction against
         # `device_cache_budget_bytes`, evicted primaries spilling to a
         # host-RAM compact form, update()/detach() invalidating by key.
         self._cache = DeviceCacheManager(
@@ -494,7 +502,8 @@ class GraphServe:
                         # the end of its d2h child: the whole device stage
                         # as the host sees it, not time the chip was busy
                         "device_busy_s": 0.0,
-                        "operand_bytes_h2d": 0, "operand_cache_hits": 0,
+                        "operand_bytes_h2d": 0, "feature_bytes_h2d": 0,
+                        "operand_cache_hits": 0,
                         "operand_cache_misses": 0, "cacheg_fallbacks": 0,
                         "tier_fallbacks": 0, "backend_fallbacks": 0,
                         "grasp_batches": 0, "sharded_batches": 0,
@@ -537,17 +546,20 @@ class GraphServe:
     # ------------------------------------------------------- cache cost model
     def _projected_primary_bytes(self, model: str, pg: PaddedGraph,
                                  part: Optional[GraphShards]) -> int:
-        """Projected device cost of the PRIMARY entry this graph pins on
-        first query — what attach() admission control (§13) sizes against.
-        Derived forms (int8 Â, grasp structure) are not counted: they rank
-        below the primary in eviction order and never exceed it."""
+        """Projected device cost of the entries this graph pins on first
+        query — what attach() admission control (§13) sizes against: the
+        PRIMARY and the (cap, F) fp32 features (a sharded slice tuple holds
+        its features itself). Derived forms (int8 Â, grasp structure) are
+        not counted: they rank below the primary in eviction order and
+        never exceed it."""
         cfg = self.models[model].cfg
         nf = len(OPERAND_FIELDS[cfg.kind])
         if part is not None:
             return estimate_shard_entry_bytes(part.shards, part.shard_cap,
                                               part.full_rows, nf,
                                               cfg.in_feats)
-        return estimate_dense_entry_bytes(nf, pg.capacity)
+        return (estimate_dense_entry_bytes(nf, pg.capacity)
+                + pg.capacity * cfg.in_feats * 4)
 
     @staticmethod
     def _shard_entry_nbytes(slices: Tuple[ShardSlice, ...]) -> int:
@@ -607,7 +619,6 @@ class GraphServe:
         way, so the default is a routing preference, not a compile
         commitment.
         """
-        import jax
         if params is None:
             params = init_params(jax.random.PRNGKey(self.seed), cfg)
         if tiers is None:
@@ -792,7 +803,8 @@ class GraphServe:
                 else:
                     single = build_operands(pg, e.cfg, lean=True)
                 ops = stack_operands([single] * b)
-                x = jnp.zeros((b, bucket, e.cfg.in_feats), jnp.float32)
+                x = self._stack_x([jnp.zeros((bucket, e.cfg.in_feats),
+                                             jnp.float32)] * b)
                 ops_grasp = None
                 if self._grasp_capable(e):
                     # placeholder block structure at the bucket budget —
@@ -1213,8 +1225,17 @@ class GraphServe:
             self._count("cacheg_fallbacks")
         return realize_operands(ho, self._materializer)
 
+    def _device_features(self, pg: PaddedGraph) -> jnp.ndarray:
+        """Put one graph's padded (cap, F) features on the device, counted
+        in `feature_bytes_h2d`: once per CacheG miss of an attached graph,
+        once per one-shot or non-CacheG request, always in the host stage."""
+        x = jnp.asarray(pg.features)
+        self._count("feature_bytes_h2d", x.nbytes)
+        return x
+
     def _prepare(self, model: str, pg: PaddedGraph,
                  ops: Optional[GranniteOperands] = None, *,
+                 x: Optional[jnp.ndarray] = None,
                  tier: Optional[str] = None,
                  tier_ops: Optional[TierOperands] = None,
                  tier_resolved: bool = False,
@@ -1225,9 +1246,10 @@ class GraphServe:
                  tolerance: Optional[float] = None) -> GNNRequest:
         """Host-stage tail shared by every intake path: resolve the tier
         (router-aware, §14), agg backend, and fusion mode, realize
-        operands if the caller didn't, assign the uid. Returns the
-        ready-to-dispatch request WITHOUT touching the
-        engine queue — the sync path pushes it (`_push`), the pipeline
+        operands and put the features on the device if the caller didn't,
+        assign the uid. Returns the ready-to-dispatch request WITHOUT
+        touching the engine queue — the sync path pushes it (`_push`), the
+        pipeline
         scheduler hands it to its own ready stage. `submitted_s` lets the
         scheduler pin latency accounting to intake time (queue wait
         included) rather than to host-stage completion; `deadline_ms` is
@@ -1249,6 +1271,8 @@ class GraphServe:
         if tier_ops is None and self._needs_tier_ops(self.models[model], tier):
             # one-shot request: derive without caching (nothing to key on)
             tier_ops = self._agg_quantizer(ops.norm_adj)
+        if x is None:
+            x = self._device_features(pg)
         with self._lock:
             uid = self._uid
             self._uid += 1
@@ -1257,7 +1281,7 @@ class GraphServe:
         deadline_s = (submitted_s + deadline_ms * 1e-3
                       if deadline_ms is not None else None)
         return GNNRequest(uid=uid, model=model, pg=pg, ops=ops,
-                          bucket=pg.capacity, submitted_s=submitted_s,
+                          bucket=pg.capacity, submitted_s=submitted_s, x=x,
                           tier=tier, backend=backend, fusion=fusion,
                           tier_ops=tier_ops, deadline_s=deadline_s,
                           tolerance=tolerance)
@@ -1352,7 +1376,8 @@ class GraphServe:
         return gid
 
     def detach(self, graph_id: int) -> None:
-        """Release an attached graph and its device-resident operands.
+        """Release an attached graph, its device-resident operands and its
+        device features.
 
         The cache pins O(cap²) float32 per attached graph in device memory
         (~32 MB for GAT at cap=2048), plus O(cap²) int8 per graph that took
@@ -1557,7 +1582,8 @@ class GraphServe:
         of a rebuild through the warm `DeltaPatcher` traces — fp32 Â
         row/col renorm, GAT mask/bias rescatter, int8 Â re-quantization of
         exactly the rows whose fp32 values changed, grasp block-list
-        re-derivation from the patched Â, and on sharded graphs the
+        re-derivation from the patched Â, the unchanged device features
+        carried over as they are, and on sharded graphs the
         concatenated permuted row blocks with the partition (and halo
         observability via `core.partition.patch_halo`) carried forward.
         The patched entries land under the NEW (graph_id, version+1) key —
@@ -1657,6 +1683,9 @@ class GraphServe:
             ops_old = self._cache.get("operand", old_key)
             tops_old = self._cache.get("tier", old_key)
             had_grasp = self._cache.get("grasp", old_key) is not None
+            # an edge delta leaves the features as they are: the same
+            # buffer moves to the new key
+            x_old = self._cache.get("features", old_key)
         new_ops = new_tops = new_grasp = None
         if self.sc.use_cacheg and ops_old is not None:
             fields = OPERAND_FIELDS[e.cfg.kind]
@@ -1696,6 +1725,10 @@ class GraphServe:
             if new_grasp is not None:
                 self._cache.put("grasp", new_key, new_grasp,
                                 nbytes=pytree_nbytes(new_grasp))
+            if x_old is not None:
+                self._cache.put("features", new_key, x_old,
+                                nbytes=x_old.nbytes,
+                                remat_s=transfer_cost(x_old.nbytes))
             self.metrics["delta_updates"] += 1
         return True
 
@@ -1710,7 +1743,9 @@ class GraphServe:
 
         CacheG hit path: an unchanged structure serves straight from the
         device-resident cache — zero host-side operand construction, zero
-        operand bytes over the link. The cache keys carry NO tier: the
+        operand or feature bytes over the link (the padded features are
+        cached beside the operands, put on the device once per version;
+        `update()` is what changes them). The cache keys carry NO tier: the
         same fp32 operands feed every tier's plan, and the int8 Â that
         QuantGr GCN tiers read is quantized from them once per structure
         version into the tier cache below — so mixed-tier traffic over one
@@ -1778,6 +1813,14 @@ class GraphServe:
                                                         model))
         else:
             self._count("operand_cache_hits")
+        with self._lock:
+            x = self._cache.get("features", key)
+        if x is None:
+            x = self._device_features(pg)
+            with self._lock:
+                if self._graph_version.get(graph_id) == ver:
+                    self._cache.put("features", key, x, nbytes=x.nbytes,
+                                    remat_s=transfer_cost(x.nbytes))
         tops = None
         resolved = self._route_tier(model, tier, tolerance, pg.capacity)
         e = self.models[model]
@@ -1811,9 +1854,10 @@ class GraphServe:
             self._count_forced_fallback(e, backend)   # per request, cached
             if backend == "grasp":                    # decision or not
                 ops = dataclasses.replace(ops, block_sparse=bsp)
-        return self._prepare(model, pg, ops, tier=resolved, tier_ops=tops,
-                             tier_resolved=True, backend=backend,
-                             fusion=fusion, submitted_s=submitted_s,
+        return self._prepare(model, pg, ops, x=x, tier=resolved,
+                             tier_ops=tops, tier_resolved=True,
+                             backend=backend, fusion=fusion,
+                             submitted_s=submitted_s,
                              deadline_ms=deadline_ms, tolerance=tolerance)
 
     def _prepare_sharded(self, graph_id: int, model: str, pg: PaddedGraph,
@@ -1902,6 +1946,7 @@ class GraphServe:
             r.done = True
             r.deadline_missed = True
             r.finished_s = now
+            r.x = None          # a finished request pins no device features
         with self._lock:
             for r in expired:
                 self.metrics["latency_s"].append(now - r.submitted_s)
@@ -1949,9 +1994,12 @@ class GraphServe:
         tier, backend, fusion) key, from exactly ONE thread at a time (the sync
         `run()` loop, or the pipeline scheduler's dispatcher). Junk slots
         repeat a real request so batch width never changes shape; their
-        outputs are dropped. The dispatch is a `dispatch` span tiled by
-        five children (`dispatch.stack`, `.h2d`, `.operands`, `.device`,
-        `.d2h`; runtime/tracing.py), all under one dispatch serial.
+        outputs are dropped. Every request arrives with its features on the
+        device (cached per version, or put there in its host stage), so
+        nothing crosses the host→device link here. The dispatch is a
+        `dispatch` span tiled by four children (`dispatch.stack`,
+        `.operands`, `.device`, `.d2h`; runtime/tracing.py), all under one
+        dispatch serial.
         `device_busy_s` accumulates host clock from the dispatch span's
         start to the end of its d2h child — the whole stage as the host
         sees it, which `summary()["dispatch_idle_fraction"]` is measured
@@ -1983,14 +2031,11 @@ class GraphServe:
             # dropped
             slots = batch + [batch[-1]] * (b - len(batch))
             e = self.models[head.model]
+            # CacheG: r.x and r.ops are device-resident (cached, or built in
+            # the host stage), so both stacks are device-side concats
+            # (DESIGN.md §7)
             with span("dispatch.stack", self.clock, serial):
-                features = stack_padded([r.pg for r in slots]).features
-            with span("dispatch.h2d", self.clock, serial,
-                      bytes=features.nbytes, filled=len(batch)):
-                x = jnp.asarray(features)
-            # CacheG: r.ops are device-resident (materialized or cached), so
-            # this stack is a device-side concat — only the activations `x`
-            # crossed the host→device link for this dispatch (DESIGN.md §7).
+                x = self._stack_x([r.x for r in slots])
             with span("dispatch.operands", self.clock, serial):
                 ops = stack_operands([r.ops for r in slots])
                 tops = (stack_tier_operands([r.tier_ops for r in slots])
@@ -2046,6 +2091,9 @@ class GraphServe:
         for r in batch:
             r.finished_s = now
             r.done = True
+            # a finished request pins no device features (a cached buffer
+            # lives on in the cache)
+            r.x = None
             if r.deadline_s is not None and now > r.deadline_s:
                 # executed but late (§14): the answer is delivered, the
                 # breach is flagged — distinct from pre-dispatch expiry,
@@ -2099,10 +2147,9 @@ class GraphServe:
         bytes are accounted per REAL request — both what the compressed
         wire moved and what exact fp32 would have, so the compression win
         is a metric, not a claim. The spans are `_execute_batch`'s but for
-        `dispatch.stack` and `dispatch.h2d`: the shard features are
-        device-resident since the host stage, so nothing is stacked on the
-        host or sent here, and the replica stack of `shard_x` (R > 1) is a
-        device-side concat under `dispatch.operands`."""
+        `dispatch.stack`: the replica stack of the device-resident
+        `shard_x` (R > 1) is a device-side concat under
+        `dispatch.operands`."""
         head = batch[0]
         R = self.sc.replica_groups
         bkey = (head.model, head.bucket, head.tier, "dense", "none",
@@ -2204,6 +2251,7 @@ class GraphServe:
                 if span > 0 else 0.0),
             "rebucket_events": self.metrics["rebucket_events"],
             "operand_bytes_h2d": self.metrics["operand_bytes_h2d"],
+            "feature_bytes_h2d": self.metrics["feature_bytes_h2d"],
             "operand_cache_hits": self.metrics["operand_cache_hits"],
             "operand_cache_misses": self.metrics["operand_cache_misses"],
             "cacheg_fallbacks": self.metrics["cacheg_fallbacks"],

@@ -2,8 +2,8 @@
 
 A `Tracer` keeps a bounded ring of `Span` records: name, start, end, an
 `id` (a request's uid or a dispatch's serial), a `parent` (for a request,
-the serial of the dispatch that answered it) and a small `attrs` dict
-(bytes sent, requests answered). Recording is always on; the ring is read in
+the serial of the dispatch that answered it) and an optional small `attrs`
+dict of counts. Recording is always on; the ring is read in
 process (`spans()`), and nothing is written out.
 
 Times come from the engine's `Clock` (`perf_counter` in production, a fake
@@ -18,9 +18,7 @@ Span names (`PERF.md` lists the metric that reads each):
 
   host               the scheduler's host stage of one request (id: uid)
   dispatch           one whole device-stage dispatch (id: serial)
-  dispatch.stack     stacking the slots' padded graphs on the host
-  dispatch.h2d       copying the stacked features to the device (attrs:
-                     bytes sent, filled: the real requests among the slots)
+  dispatch.stack     stacking the slots' resident features on the device
   dispatch.operands  stacking the resident operands on the device
   dispatch.device    the plan call through `block_until_ready`
   dispatch.d2h       copying the logits back and unpacking each answer
@@ -28,9 +26,10 @@ Span names (`PERF.md` lists the metric that reads each):
                      (id: uid, parent: serial; ring only)
 
 The `dispatch.*` spans tile `dispatch` (the sharded path has no
-`.stack` or `.h2d`: its features are on the device since the host stage);
-what they leave uncovered is the dispatch's bookkeeping under the engine
-lock.
+`.stack`: its replica stack of features is part of `.operands`); what
+they leave uncovered is the dispatch's bookkeeping under the engine lock.
+No dispatch sends anything to the device: every request's features are
+there since its host stage.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """CacheG operand pipeline (DESIGN.md §7): SymG bit-packed transfer, device
-materialization, the device-resident operand cache, byte accounting, and the
-satellite fixes that ride along (grow() supervision carry, vectorized SAGE
-sampling, bucket-rule dedup)."""
+materialization, the device-resident operand and feature caches, byte
+accounting, and the satellite fixes that ride along (grow() supervision
+carry, vectorized SAGE sampling, bucket-rule dedup)."""
 import dataclasses
 
 import jax.numpy as jnp
@@ -18,7 +18,9 @@ from repro.core.masks import sage_sample_adjacency
 from repro.core.models import (GNNConfig, build_operands, compact_operands,
                                forward_grannite, materialize_operands,
                                operand_nbytes, _unpack_adjacency)
+from repro.core.partition import transfer_cost
 from repro.data.graphs import planetoid_like
+from repro.runtime.cache import estimate_dense_entry_bytes
 from repro.runtime.gnn_server import GraphServe, GraphServeConfig
 
 IN_FEATS, CLASSES = 16, 4
@@ -239,6 +241,147 @@ def test_detach_releases_cache_and_graph():
     eng.detach(gid)
     assert eng._operand_cache == {} and gid not in eng.graphs
     eng.detach(gid)                         # idempotent
+
+
+# --------------------------------------------- device-resident features
+
+FEATURE_BYTES = 128 * IN_FEATS * 4          # one padded (cap, F) fp32 buffer
+
+
+def _sent(eng):
+    return eng.summary()["feature_bytes_h2d"]
+
+
+def test_attached_features_cross_the_link_once_per_version():
+    """The features of an attached graph go to the device on the first
+    query of each structure version and never again: later queries, and
+    the dispatches that serve them, send none."""
+    eng = _engine("gcn")
+    g = _graph(100)
+    gid = eng.attach(g, model="gcn")
+    assert _sent(eng) == 0
+    eng.query(gid)
+    eng.run()
+    assert _sent(eng) == FEATURE_BYTES
+    first = eng.finished[-1].logits
+    for _ in range(4):
+        eng.query(gid)
+    eng.run()
+    assert _sent(eng) == FEATURE_BYTES
+    assert eng.summary()["operand_cache_hits"] == 4
+    np.testing.assert_array_equal(eng.finished[-1].logits, first)
+    eng.update(gid, g.edge_index, g.num_nodes, g.features)
+    eng.query(gid)
+    eng.query(gid)
+    eng.run()
+    assert _sent(eng) == 2 * FEATURE_BYTES
+    eng.assert_warm()
+
+
+def test_one_shot_requests_send_their_features_in_the_host_stage():
+    """Each one-shot submit, and each query of a non-CacheG engine, puts
+    its features on the device once, before any dispatch; a finished
+    request holds no device features."""
+    for use_cacheg in (True, False):
+        eng = _engine("gcn", use_cacheg=use_cacheg)
+        for i in range(3):
+            req = eng.prepare_submit(_graph(60 + i, seed=i), model="gcn")
+            assert req.x is not None and req.x.shape == (128, IN_FEATS)
+            assert _sent(eng) == (i + 1) * FEATURE_BYTES
+            eng._push(req)
+        if not use_cacheg:
+            gid = eng.attach(_graph(100), model="gcn")
+            eng.query(gid)
+            eng.query(gid)
+            assert _sent(eng) == 5 * FEATURE_BYTES
+        sent = _sent(eng)
+        eng.run()
+        assert _sent(eng) == sent
+        assert eng.finished and all(r.done and r.x is None
+                                    for r in eng.finished)
+        eng.assert_warm()
+
+
+@pytest.mark.parametrize("kind", ["gcn", "gat"])
+def test_update_delta_carries_the_features(kind):
+    """An edge delta moves the cached features to the new version: no new
+    feature bytes, and the answers equal a full update() of the same
+    edges."""
+    g = _graph(100)
+    patched, rebuilt = _engine(kind), _engine(kind)
+    gids = [e.attach(g, model=kind) for e in (patched, rebuilt)]
+    for e, gid in zip((patched, rebuilt), gids):
+        e.query(gid)
+        e.run()
+    adj = patched.graphs[gids[0]][1].adj
+    iu, ju = np.nonzero(np.triu(adj[:100, :100], 1))
+    pair = (int(iu[0]), int(ju[0]))
+    assert patched.update_delta(gids[0], remove_edges=[pair])
+    assert patched.metrics["delta_updates"] == 1
+    assert _sent(patched) == FEATURE_BYTES
+    assert len(patched._cache.view("features")) == 1
+    assert patched._cache._entries[("features", (gids[0], 1))].remat_s == \
+        transfer_cost(FEATURE_BYTES)
+    keep = ~(((g.edge_index[0] == pair[0]) & (g.edge_index[1] == pair[1]))
+             | ((g.edge_index[0] == pair[1]) & (g.edge_index[1] == pair[0])))
+    rebuilt.update(gids[1], g.edge_index[:, keep], g.num_nodes, g.features)
+    for e, gid in zip((patched, rebuilt), gids):
+        e.query(gid)
+        e.run()
+    assert _sent(patched) == FEATURE_BYTES
+    assert _sent(rebuilt) == 2 * FEATURE_BYTES
+    np.testing.assert_allclose(patched.finished[-1].logits,
+                               rebuilt.finished[-1].logits, atol=1e-6)
+    patched.assert_warm()
+
+
+def test_detach_releases_the_features():
+    eng = _engine("gcn")
+    keep = eng.attach(_graph(90, seed=3), model="gcn")
+    eng.query(keep)
+    eng.run()
+    before = eng.summary()["cache_resident_bytes"]
+    gid = eng.attach(_graph(100), model="gcn")
+    eng.query(gid)
+    eng.run()
+    feats = eng._cache.view("features")
+    assert set(feats) == {(keep, 0), (gid, 0)}
+    assert eng._cache.entry_sizes()[("features", (gid, 0))] == FEATURE_BYTES
+    # rebuilding the features is a transfer, not a device re-derivation:
+    # ties inside a key group evict the cheaper derived forms first
+    assert eng._cache._entries[("features", (gid, 0))].remat_s == \
+        transfer_cost(FEATURE_BYTES) > 0
+    eng.detach(gid)
+    assert set(eng._cache.view("features")) == {(keep, 0)}
+    assert eng.summary()["cache_resident_bytes"] == before
+
+
+def test_evicted_features_come_back_and_answer_the_same():
+    """Under a budget that holds one tenant, querying the other evicts the
+    first one's features (before its operands, never spilled); its next
+    query puts them on the device again and answers bit for bit the
+    same."""
+    entry = estimate_dense_entry_bytes(1, 128)
+    sc = GraphServeConfig(ladder=BucketLadder(buckets=(128,)),
+                          batch_slots=2, return_logits=True,
+                          device_cache_budget_bytes=entry + entry // 2)
+    eng = GraphServe(sc, seed=0)
+    eng.register_model("gcn", _cfg("gcn"))
+    eng.warmup()
+    a = eng.attach(_graph(100, seed=1), model="gcn")
+    b = eng.attach(_graph(90, seed=2), model="gcn")
+    eng.query(a)
+    first = np.asarray(eng.run()[-1].logits)
+    eng.query(b)
+    eng.run()
+    assert set(eng._cache.view("features")) == {(b, 0)}
+    assert eng._cache.spill_entries == 1     # a's operands, not features
+    assert eng.summary()["cache_resident_bytes"] <= entry + entry // 2
+    eng.query(a)
+    np.testing.assert_array_equal(np.asarray(eng.run()[-1].logits), first)
+    assert _sent(eng) == 3 * FEATURE_BYTES
+    assert eng.metrics["cache_spill_hits"] == 1
+    eng.assert_warm()
 
 
 # ------------------------------------------------------ h2d byte accounting

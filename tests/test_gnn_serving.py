@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.graph import BucketLadder, Graph, pad_graph, stack_padded
+from repro.core.graph import BucketLadder, Graph, pad_graph
 from repro.core.models import (GNNConfig, build_operands, build_plan,
                                forward_grannite, stack_operands)
 from repro.data.graphs import dynamic_graph_stream, planetoid_like
@@ -63,13 +63,22 @@ def test_ladder_slack_reserves_headroom():
 
 
 def test_stack_padded_rejects_mixed_buckets():
-    a = pad_graph(_graph(50, 0), capacity=128)
-    b = pad_graph(_graph(200, 1), capacity=256)
+    """A dispatch stacks its slots' device features with one program per
+    bucket: slots of two buckets are refused, slots of one bucket stack
+    to (slots, cap, F)."""
+    sc = GraphServeConfig(ladder=BucketLadder(buckets=(128, 256)),
+                          batch_slots=2)
+    eng = GraphServe(sc, seed=0)
+    eng.register_model("gcn", GNNConfig(kind="gcn", in_feats=IN_FEATS,
+                                        hidden=16, num_classes=CLASSES))
+    a = eng.prepare_submit(_graph(50, 0), model="gcn")
+    b = eng.prepare_submit(_graph(200, 1), model="gcn")
+    assert (a.bucket, b.bucket) == (128, 256)
     with pytest.raises(ValueError):
-        stack_padded([a, b])
-    st = stack_padded([a, a])
-    assert st.features.shape == (2, 128, IN_FEATS)
-    assert st.norm_adj.shape == (2, 128, 128)
+        eng._stack_x([a.x, b.x])
+    with pytest.raises(ValueError):
+        eng._execute_batch([a, b])
+    assert eng._stack_x([a.x, a.x]).shape == (2, 128, IN_FEATS)
 
 
 # ----------------------------------------------------- zero-recompile serving

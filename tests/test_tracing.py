@@ -1,5 +1,6 @@
 """Spans of GraphServe's request and dispatch paths (runtime/tracing.py):
-each dispatch tiled by its five stages under one serial, every answered
+each dispatch tiled by its four stages under one serial, none of them a
+copy to the device, every answered
 request's queue span parented to the dispatch that answered it,
 `finished_s` after the copy back, the bounded ring under concurrent
 recording, the sharded path's names, the scheduler's host stage, and the
@@ -24,8 +25,8 @@ from repro.runtime.scheduler import PipelineConfig
 from repro.runtime.tracing import PREFIX, Tracer, default_tracer
 
 IN_FEATS, CLASSES, BUCKET, SLOTS = 16, 4, 128, 2
-STAGES = ("dispatch.stack", "dispatch.h2d", "dispatch.operands",
-          "dispatch.device", "dispatch.d2h")
+STAGES = ("dispatch.stack", "dispatch.operands", "dispatch.device",
+          "dispatch.d2h")
 LIVE = ("host", "dispatch") + STAGES
 
 
@@ -70,10 +71,19 @@ def engine(warm_engine):
 
 
 def _by_serial(tracer):
+    """Every `dispatch` and `dispatch.*` span, by serial."""
     out = {}
     for s in tracer.spans():
-        if s.name == "dispatch" or s.name in STAGES:
+        if s.name == "dispatch" or s.name.startswith("dispatch."):
             out.setdefault(s.id, {})[s.name] = s
+    return out
+
+
+def _filled(tracer):
+    """Requests answered per dispatch serial, from their queue spans."""
+    out = {}
+    for q in tracer.spans("request.queue"):
+        out[q.parent] = out.get(q.parent, 0) + 1
     return out
 
 
@@ -88,11 +98,18 @@ def _serve(eng, n):
 
 
 def test_dispatch_spans_tile_the_dispatch_in_order(engine):
+    _serve(engine, 3)                       # every tenant's features cached
+    engine.tracer = Tracer()
+    batches, sent = engine.metrics["batches"], \
+        engine.metrics["feature_bytes_h2d"]
     _serve(engine, 5)                       # 3 dispatches: 2 + 2 + 1
     dispatches = _by_serial(engine.tracer)
-    assert len(dispatches) == engine.metrics["batches"] == 3
+    assert len(dispatches) == engine.metrics["batches"] - batches == 3
+    # CacheG hits: nothing crosses to the device, and no dispatch.h2d
+    assert engine.metrics["feature_bytes_h2d"] == sent
     for serial, spans in dispatches.items():
         assert set(spans) == {"dispatch", *STAGES}
+        assert "dispatch.h2d" not in spans
         d = spans["dispatch"]
         t = d.start
         for name in STAGES:
@@ -100,16 +117,12 @@ def test_dispatch_spans_tile_the_dispatch_in_order(engine):
             assert s.parent is None and s.id == serial
             assert t <= s.start < s.end <= d.end, name
             t = s.end
-        assert d.attrs is None and spans["dispatch.stack"].attrs is None
-        # every slot's features cross to the device, junk slots too
-        h2d = spans["dispatch.h2d"].attrs
-        assert h2d["bytes"] == SLOTS * BUCKET * IN_FEATS * 4
-        assert h2d["filled"] in (1, 2)
+        assert all(s.attrs is None for s in spans.values())
         # the fake clock's scripted batch cost lands in the device stage
         assert spans["dispatch.device"].end - spans["dispatch.device"].start \
             >= 0.003
-    assert [d["dispatch.h2d"].attrs["filled"] for _, d in
-            sorted(dispatches.items())] == [2, 2, 1]
+    filled = _filled(engine.tracer)
+    assert [filled[s] for s in sorted(dispatches)] == [2, 2, 1]
 
 
 def test_device_busy_and_latency_read_the_span_stamps(engine):
@@ -137,8 +150,7 @@ def test_answered_requests_have_queue_spans(engine):
         assert q.start == r.submitted_s
         assert q.end == d["dispatch"].start
         per_dispatch[q.parent] = per_dispatch.get(q.parent, 0) + 1
-    assert {s: d["dispatch.h2d"].attrs["filled"]
-            for s, d in dispatches.items()} == per_dispatch
+    assert [per_dispatch[s] for s in sorted(dispatches)] == [2, 2, 1]
 
 
 def test_finished_after_the_copy_back(engine):
@@ -224,9 +236,9 @@ def test_engine_records_into_the_default_tracer_unless_given_one():
 
 
 def test_sharded_path_records_the_same_names():
-    """The batched path's names, but for `dispatch.stack` and
-    `dispatch.h2d`: the shard features are on the device since the host
-    stage, so the dispatch neither stacks on the host nor sends."""
+    """The batched path's names, but for `dispatch.stack`: the shard
+    features are on the device since the host stage, and their replica
+    stack is part of `dispatch.operands`."""
     sc = GraphServeConfig(ladder=BucketLadder(buckets=(BUCKET,)),
                           batch_slots=SLOTS, shard_counts=(2,),
                           return_logits=True)
