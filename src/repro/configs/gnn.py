@@ -1,5 +1,6 @@
 """The paper's own model configs (Section V): 2-layer GCN / GAT / GraphSAGE
-on Cora/Citeseer-shaped graphs, hidden width 64, GAT 8 heads, SAGE fan-out 10.
+on Cora/Citeseer-shaped graphs, hidden width 64, GAT 8 heads, SAGE fan-out 10;
+and OGB's ogbn-arxiv GraphSAGE (`sage("arxiv")`).
 """
 from __future__ import annotations
 
@@ -7,6 +8,7 @@ from repro.core.models import GNNConfig
 
 CORA_FEATS, CORA_CLASSES = 1433, 7
 CITESEER_FEATS, CITESEER_CLASSES = 3703, 6
+ARXIV_FEATS, ARXIV_CLASSES = 128, 40
 
 
 def gcn(dataset: str = "cora") -> GNNConfig:
@@ -22,6 +24,12 @@ def gat(dataset: str = "cora") -> GNNConfig:
 
 
 def sage(dataset: str = "cora", aggregator: str = "mean") -> GNNConfig:
+    if dataset == "arxiv":
+        # OGB's example (arXiv:2005.00687, examples/nodeproppred/arxiv/
+        # gnn.py --use_sage): 3 layers of 256, BatchNorm, every neighbour
+        return GNNConfig(kind="sage", in_feats=ARXIV_FEATS, hidden=256,
+                         num_classes=ARXIV_CLASSES, aggregator=aggregator,
+                         max_neighbors=None, num_layers=3, batch_norm=True)
     f, c = ((CORA_FEATS, CORA_CLASSES) if dataset == "cora"
             else (CITESEER_FEATS, CITESEER_CLASSES))
     return GNNConfig(kind="sage", in_feats=f, hidden=64, num_classes=c,

@@ -190,23 +190,37 @@ class PaddedGraph:
     capacity: int
     num_nodes: int
     features: np.ndarray      # (cap, F)
-    norm_adj: np.ndarray      # (cap, cap)  Â (PreG-normalized)
-    adj: np.ndarray           # (cap, cap)  raw 0/1 (no self loops) for GAT masks
+    norm_adj: Optional[np.ndarray]  # (cap, cap)  Â (PreG-normalized)
+    adj: Optional[np.ndarray]       # (cap, cap)  raw 0/1 (no self loops)
     node_mask: np.ndarray     # (cap,) 1.0 for real nodes
     labels: Optional[np.ndarray] = None
     train_mask: Optional[np.ndarray] = None
     test_mask: Optional[np.ndarray] = None
+    # the edge-list form (`pad_graph(dense=False)`): the (2, E) edges in
+    # place of the two dense matrices, which are then None
+    edge_index: Optional[np.ndarray] = None
+
+    @property
+    def dense(self) -> bool:
+        return self.edge_index is None
 
 
 def pad_graph(g: Graph, *, capacity: Optional[int] = None, slack: float = 0.0,
-              norm: str = "gcn") -> PaddedGraph:
+              norm: str = "gcn", dense: bool = True) -> PaddedGraph:
+    """NodePad one graph. `dense=False` keeps the edge list and builds no
+    (cap, cap) array: the form of a graph whose dense operands the device
+    cannot hold (`edge_arrays` turns it into operands)."""
     cap = capacity if capacity is not None else node_bucket(g.num_nodes, slack=slack)
-    if norm == "gcn":
+    if not dense:
+        na = adj = None
+    elif norm == "gcn":
         na = gcn_norm_adjacency(g.edge_index, g.num_nodes, cap)
     elif norm == "mean":
         na = mean_adjacency(g.edge_index, g.num_nodes, cap)
     else:
         raise ValueError(f"unknown norm {norm!r}")
+    if dense:
+        adj = dense_adjacency(g.edge_index, cap, self_loops=False)
     mask = np.zeros((cap,), dtype=np.float32)
     mask[: g.num_nodes] = 1.0
 
@@ -222,12 +236,54 @@ def pad_graph(g: Graph, *, capacity: Optional[int] = None, slack: float = 0.0,
         num_nodes=g.num_nodes,
         features=pad_features(g.features, cap),
         norm_adj=na,
-        adj=dense_adjacency(g.edge_index, cap, self_loops=False),
+        adj=adj,
         node_mask=mask,
         labels=None if g.labels is None else pad_labels(g.labels, cap),
         train_mask=_pad_bool(g.train_mask),
         test_mask=_pad_bool(g.test_mask),
+        edge_index=None if dense else np.asarray(g.edge_index, np.int32),
     )
+
+
+# ---------------------------------------------------------------------------
+# Edge-list operands: a graph whose dense (cap, cap) operands the device
+# cannot hold aggregates from its edge list, padded to an edge rung so that
+# graphs of one bucket and similar edge counts replay one compiled plan.
+# ---------------------------------------------------------------------------
+
+def edge_rung(num_edges: int) -> int:
+    """Padded edge count: the next multiple of 1/16 of the power of two at
+    or below `num_edges` (at least 128), so padding wastes at most 1/16."""
+    step = max(1 << max(int(num_edges).bit_length() - 5, 0), MXU_TILE)
+    return max(-(-int(num_edges) // step) * step, MXU_TILE)
+
+
+def edge_arrays(edge_index: np.ndarray, num_nodes: int, capacity: int
+                ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(src, dst, inv_deg) of one graph's edge-list operands.
+
+    `src`/`dst` are int32 of `edge_rung(E)` entries, sorted by (dst, src);
+    the spare entries run from node 0 into row `capacity`, one past the
+    bucket, which the aggregation sums and drops. `inv_deg` (cap,) float32
+    is 1 / in-degree, 0 for nodes with no in-edges and for padding rows.
+    """
+    ei = np.asarray(edge_index)
+    if ei.size and (ei.min() < 0 or ei.max() >= num_nodes):
+        raise ValueError(f"edge_index references a node outside "
+                         f"[0, {num_nodes})")
+    if num_nodes > capacity:
+        raise ValueError(f"{num_nodes} nodes exceed capacity {capacity}")
+    src, dst = ei[0].astype(np.int32), ei[1].astype(np.int32)
+    order = np.lexsort((src, dst))
+    rung = edge_rung(src.shape[0])
+    out_src = np.zeros((rung,), np.int32)
+    out_dst = np.full((rung,), capacity, np.int32)
+    out_src[:src.shape[0]] = src[order]
+    out_dst[:dst.shape[0]] = dst[order]
+    deg = np.bincount(dst, minlength=capacity).astype(np.float32)
+    inv_deg = np.where(deg > 0, 1.0 / np.maximum(deg, 1.0), 0.0
+                       ).astype(np.float32)
+    return out_src, out_dst, inv_deg
 
 
 # ---------------------------------------------------------------------------
@@ -420,6 +476,32 @@ def apply_edge_delta(adj: np.ndarray, norm_adj: np.ndarray, num_nodes: int,
                      flip_j=flips[:, 1].astype(np.int32),
                      flip_v=vals,
                      touched=touched.astype(np.int32))
+
+
+def edge_list_delta(edge_index: np.ndarray, num_nodes: int, add_edges,
+                    remove_edges) -> Optional[np.ndarray]:
+    """An undirected edge delta applied to an edge list (the edge-list
+    form's counterpart of `apply_edge_delta`): each (k, 2) pair is added or
+    removed in both directions, self-loop pairs skipped. Returns the new
+    (2, E) edges, or None when nothing effective changed."""
+    def _both(edges) -> np.ndarray:
+        e = np.asarray(edges if edges is not None else [],
+                       dtype=np.int64).reshape(-1, 2)
+        if e.size and (e.min() < 0 or e.max() >= num_nodes):
+            raise ValueError(
+                f"edge delta references node outside [0, {num_nodes}) — "
+                "node-set changes take the full update() path")
+        e = e[e[:, 0] != e[:, 1]]
+        return np.concatenate([e, e[:, ::-1]]) * [num_nodes, 1]
+
+    cur = np.asarray(edge_index, np.int64)
+    keys = cur[0] * num_nodes + cur[1]
+    add = np.setdiff1d(_both(add_edges).sum(axis=1), keys)
+    keep = ~np.isin(keys, _both(remove_edges).sum(axis=1))
+    if not add.size and keep.all():
+        return None
+    new = np.concatenate([keys[keep], add])
+    return np.stack([new // num_nodes, new % num_nodes]).astype(np.int32)
 
 
 def edge_index_from_adjacency(adj: np.ndarray, num_nodes: int) -> np.ndarray:

@@ -245,6 +245,23 @@ def sage_init(key, in_feats: int, out_feats: int, *, aggregator: str) -> Dict:
     return p
 
 
+# PyTorch's BatchNorm1d default, which OGB's ogbn-arxiv example keeps
+BN_EPS = 1e-5
+
+
+def batch_norm_init(width: int) -> Dict:
+    """Eval-mode BatchNorm state: scale, shift and running statistics,
+    at the identity."""
+    return {"gamma": jnp.ones((width,)), "beta": jnp.zeros((width,)),
+            "mean": jnp.zeros((width,)), "var": jnp.ones((width,))}
+
+
+def batch_norm_eval(params: Dict, h: jnp.ndarray) -> jnp.ndarray:
+    """BatchNorm1d in eval mode: the running statistics, never the batch's."""
+    return ((h - params["mean"]) / jnp.sqrt(params["var"] + BN_EPS)
+            * params["gamma"] + params["beta"])
+
+
 def sage_baseline(params: Dict, x: jnp.ndarray, edge_index: jnp.ndarray,
                   num_nodes: int, *, aggregator: str) -> jnp.ndarray:
     """Edge-list SAGE. max: sequential per-neighborhood segment_max (DSP)."""

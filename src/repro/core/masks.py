@@ -38,13 +38,16 @@ def adj_with_self_loops(adj: np.ndarray, num_nodes: int) -> np.ndarray:
     return out
 
 
-def sage_sample_adjacency(adj: np.ndarray, num_nodes: int, *, max_neighbors: int,
+def sage_sample_adjacency(adj: np.ndarray, num_nodes: int, *,
+                          max_neighbors: Optional[int],
                           rng: Optional[np.random.Generator] = None,
                           include_self: bool = True) -> np.ndarray:
     """StaGr for GraphSAGE: precomputed *sampled* adjacency, reused at inference.
 
     Uniformly samples up to `max_neighbors` in-neighbors per node (paper
-    uses 10 on Cora). Returns a 0/1 (cap, cap) mask.
+    uses 10 on Cora). Returns a 0/1 (cap, cap) mask. `max_neighbors=None`
+    keeps every in-neighbour of the real rows and adds no self loop
+    (`GNNConfig.max_neighbors`: OGB's full-batch inference).
 
     Vectorized: every edge draws one uniform key and each row keeps its
     `max_neighbors` smallest-keyed neighbors (a per-row random permutation
@@ -53,9 +56,12 @@ def sage_sample_adjacency(adj: np.ndarray, num_nodes: int, *, max_neighbors: int
     runs on the serving hot path at every structure miss. Deterministic for
     a seeded rng (default seed 0, matching the historical behavior).
     """
+    out = np.zeros_like(adj)
+    if max_neighbors is None:
+        out[:num_nodes] = adj[:num_nodes] > 0
+        return out
     rng = rng or np.random.default_rng(0)
     cap = adj.shape[0]
-    out = np.zeros_like(adj)
     if num_nodes > 0 and max_neighbors > 0:
         live = adj[:num_nodes] > 0
         keys = np.where(live, rng.random((num_nodes, cap)), np.inf)

@@ -57,24 +57,65 @@ class GNNConfig:
     num_classes: int = 7
     heads: int = 8             # GAT only (hidden per-head = hidden // heads)
     aggregator: str = "mean"   # SAGE only: "mean" | "max"
-    max_neighbors: int = 10    # SAGE sampling cap (paper: 10)
+    # SAGE: an int samples up to that many in-neighbours and adds the node
+    # itself (the paper's 10); None takes every in-neighbour and no self
+    # loop, as OGB's full-batch SAGEConv inference does
+    max_neighbors: Optional[int] = 10
+    num_layers: int = 2        # SAGE only may differ from 2
+    batch_norm: bool = False   # SAGE only: eval BatchNorm before each ReLU
+
+    def __post_init__(self):
+        if self.num_layers < 1:
+            raise ValueError(f"num_layers must be >= 1, got {self.num_layers}")
+        if self.kind != "sage" and (self.num_layers != 2 or self.batch_norm):
+            raise ValueError(f"{self.kind} models are two layers without "
+                             "BatchNorm; num_layers and batch_norm are "
+                             "SAGE's")
+
+
+def layer_widths(cfg: GNNConfig) -> Tuple[int, ...]:
+    """Input width of each layer, then the output width of the last one."""
+    return (cfg.in_feats,) + (cfg.hidden,) * (cfg.num_layers - 1) + (
+        cfg.num_classes,)
+
+
+def has_edge_form(cfg: GNNConfig) -> bool:
+    """Whether the model can aggregate from the edge-list operands
+    (`EdgeOperands`): SAGE-mean over every in-neighbour."""
+    return (cfg.kind == "sage" and cfg.aggregator == "mean"
+            and cfg.max_neighbors is None)
 
 
 def init_params(key, cfg: GNNConfig) -> Dict:
-    k1, k2 = jax.random.split(key)
+    keys = jax.random.split(key, cfg.num_layers)
     if cfg.kind == "gcn":
-        return {"l1": layers.gcn_init(k1, cfg.in_feats, cfg.hidden),
-                "l2": layers.gcn_init(k2, cfg.hidden, cfg.num_classes)}
+        return {"l1": layers.gcn_init(keys[0], cfg.in_feats, cfg.hidden),
+                "l2": layers.gcn_init(keys[1], cfg.hidden, cfg.num_classes)}
     if cfg.kind == "gat":
         per_head = cfg.hidden // cfg.heads
-        return {"l1": layers.gat_init(k1, cfg.in_feats, per_head, cfg.heads),
-                "l2": layers.gat_init(k2, cfg.heads * per_head, cfg.num_classes, 1)}
+        return {"l1": layers.gat_init(keys[0], cfg.in_feats, per_head,
+                                      cfg.heads),
+                "l2": layers.gat_init(keys[1], cfg.heads * per_head,
+                                      cfg.num_classes, 1)}
     if cfg.kind == "sage":
-        return {"l1": layers.sage_init(k1, cfg.in_feats, cfg.hidden,
-                                       aggregator=cfg.aggregator),
-                "l2": layers.sage_init(k2, cfg.hidden, cfg.num_classes,
-                                       aggregator=cfg.aggregator)}
+        w = layer_widths(cfg)
+        params = {f"l{i + 1}": layers.sage_init(k, w[i], w[i + 1],
+                                                aggregator=cfg.aggregator)
+                  for i, k in enumerate(keys)}
+        if cfg.batch_norm:
+            params.update({f"bn{i}": layers.batch_norm_init(w[i])
+                           for i in range(1, cfg.num_layers)})
+        return params
     raise ValueError(cfg.kind)
+
+
+def _sage_between(params: Dict, cfg: GNNConfig, i: int, h: jnp.ndarray
+                  ) -> jnp.ndarray:
+    """What follows SAGE layer `i` (1-based) when another comes after it:
+    eval BatchNorm if the model has it, then ReLU."""
+    if cfg.batch_norm:
+        h = layers.batch_norm_eval(params[f"bn{i}"], h)
+    return jax.nn.relu(h)
 
 
 # ---------------------------------------------------------------------------
@@ -93,10 +134,15 @@ def forward_baseline(params: Dict, cfg: GNNConfig, x: jnp.ndarray,
         return layers.gat_baseline(params["l2"], h, edge_index, num_nodes,
                                    heads=1, out_feats=cfg.num_classes)
     if cfg.kind == "sage":
-        h = jax.nn.relu(layers.sage_baseline(params["l1"], x, edge_index, num_nodes,
-                                             aggregator=cfg.aggregator))
-        return layers.sage_baseline(params["l2"], h, edge_index, num_nodes,
-                                    aggregator=cfg.aggregator)
+        # the plain reference of every SAGE config: mean over the edge
+        # list's in-neighbours, L layers, BatchNorm as its own step
+        h = x
+        for i in range(1, cfg.num_layers + 1):
+            h = layers.sage_baseline(params[f"l{i}"], h, edge_index,
+                                     num_nodes, aggregator=cfg.aggregator)
+            if i < cfg.num_layers:
+                h = _sage_between(params, cfg, i, h)
+        return h
     raise ValueError(cfg.kind)
 
 
@@ -461,6 +507,69 @@ def realize_operands(ho: HostOperands,
     return ho.eager
 
 
+@dataclasses.dataclass
+class EdgeOperands:
+    """Device-resident edge-list operands of one graph (`core.graph.
+    edge_arrays`): int32 `src`/`dst` padded to the graph's edge rung and
+    sorted by destination, spare edges landing in row `capacity` (one past
+    the bucket), and (cap,) float32 `inv_deg`. Memory grows with the
+    edges, never with the bucket squared. A registered pytree, so a stack
+    of them crosses the batched plan's vmap like `GranniteOperands`."""
+    src: jnp.ndarray
+    dst: jnp.ndarray
+    inv_deg: jnp.ndarray
+
+
+jax.tree_util.register_pytree_node(
+    EdgeOperands, lambda o: ((o.src, o.dst, o.inv_deg), None),
+    lambda _, c: EdgeOperands(*c))
+
+
+def edge_operands(pg: PaddedGraph) -> EdgeOperands:
+    """Host side of the edge-list form: sort, pad and upload one graph's
+    edges (`pg` from `pad_graph(dense=False)`)."""
+    from .graph import edge_arrays
+    return EdgeOperands(*(jnp.asarray(a) for a in edge_arrays(
+        pg.edge_index, pg.num_nodes, pg.capacity)))
+
+
+def edge_mean(h: jnp.ndarray, eo: EdgeOperands) -> jnp.ndarray:
+    """Mean over each row's in-neighbours: gather the source rows, sum them
+    by destination (the edges are sorted by it) into cap + 1 rows, drop the
+    spare edges' row, scale by 1 / in-degree. A row with no in-edges gets
+    0."""
+    cap = h.shape[0]
+    msgs = h.at[eo.src].get(mode="promise_in_bounds")
+    summed = jax.ops.segment_sum(msgs, eo.dst, num_segments=cap + 1,
+                                 indices_are_sorted=True)
+    return summed[:cap] * eo.inv_deg[:, None]
+
+
+def forward_edges(params: Dict, cfg: GNNConfig, x: jnp.ndarray,
+                  eo: EdgeOperands) -> jnp.ndarray:
+    """SAGE-mean over every in-neighbour from edge-list operands, L layers,
+    eval BatchNorm and ReLU between them (PyG's SAGEConv: `w_neigh` maps
+    the mean and carries the bias, `w_self` maps the node itself). Each
+    layer aggregates on the narrower side of its neighbour map, which is
+    exact (mean(h) W = mean(h W)): the last layer's 40 classes, say, in
+    place of its 256 inputs. The aggregation of layer i runs under
+    `graphserve.agg.l<i>` (the ops' metadata); a TPU trace names its ops
+    by their output instead: the only f32 results of cap + 1 rows."""
+    h = x
+    for i in range(1, cfg.num_layers + 1):
+        p = params[f"l{i}"]
+        narrower = p["w_neigh"].shape[1] < p["w_neigh"].shape[0]
+        m = h @ p["w_neigh"] if narrower else h
+        with jax.named_scope(f"graphserve.agg.l{i}"):
+            agg = edge_mean(m, eo)
+        if not narrower:
+            agg = agg @ p["w_neigh"]
+        h = h @ p["w_self"] + agg + p["b"]
+        if i < cfg.num_layers:
+            h = _sage_between(params, cfg, i, h)
+    return h
+
+
 def operand_nbytes(ops: GranniteOperands) -> int:
     """Host→device bytes of one eagerly built operand set (the five dense
     fields; a GraSp structure's bytes are accounted where it is built —
@@ -772,11 +881,14 @@ def calibrate_tier(params: Dict, cfg: GNNConfig, x: jnp.ndarray,
                 ql["pool"] = quantize_linear(p["w_pool"], xin)
             return ql
 
-        q1 = _layer(params["l1"], x)
-        h1 = jax.nn.relu(layers.sage_grannite(
-            params["l1"], x, ops_.sample_mask, ops_.mean_mask, t0,
-            aggregator=cfg.aggregator))
-        return {"l1": q1, "l2": _layer(params["l2"], h1)}
+        out, h = {}, x
+        for i in range(1, cfg.num_layers + 1):
+            out[f"l{i}"] = _layer(params[f"l{i}"], h)
+            if i < cfg.num_layers:
+                h = _sage_between(params, cfg, i, layers.sage_grannite(
+                    params[f"l{i}"], h, ops_.sample_mask, ops_.mean_mask, t0,
+                    aggregator=cfg.aggregator))
+        return out
     raise ValueError(cfg.kind)
 
 
@@ -847,21 +959,25 @@ def forward_grannite(params: Dict, cfg: GNNConfig, x: jnp.ndarray,
                                    t, heads=1, out_feats=cfg.num_classes,
                                    quant=tq.get("l2"))
     if cfg.kind == "sage":
-        if fused:
-            h = layers.sage_grannite_fused(
-                params["l1"], x, ops_.sample_mask, ops_.mean_mask, t,
-                aggregator=cfg.aggregator, activation="relu",
-                quant=tq.get("l1"))
-            return layers.sage_grannite_fused(
-                params["l2"], h, ops_.sample_mask, ops_.mean_mask, t,
-                aggregator=cfg.aggregator, activation="none",
-                quant=tq.get("l2"))
-        h = jax.nn.relu(layers.sage_grannite(
-            params["l1"], x, ops_.sample_mask, ops_.mean_mask, t,
-            aggregator=cfg.aggregator, quant=tq.get("l1")))
-        return layers.sage_grannite(params["l2"], h, ops_.sample_mask,
-                                    ops_.mean_mask, t, aggregator=cfg.aggregator,
-                                    quant=tq.get("l2"))
+        h = x
+        for i in range(1, cfg.num_layers + 1):
+            last = i == cfg.num_layers
+            kw = dict(aggregator=cfg.aggregator, quant=tq.get(f"l{i}"))
+            if fused:
+                # the epilogue applies ReLU only where no BatchNorm
+                # stands between the layer and it
+                act = "none" if last or cfg.batch_norm else "relu"
+                h = layers.sage_grannite_fused(
+                    params[f"l{i}"], h, ops_.sample_mask, ops_.mean_mask, t,
+                    activation=act, **kw)
+                if not last and cfg.batch_norm:
+                    h = _sage_between(params, cfg, i, h)
+            else:
+                h = layers.sage_grannite(params[f"l{i}"], h, ops_.sample_mask,
+                                         ops_.mean_mask, t, **kw)
+                if not last:
+                    h = _sage_between(params, cfg, i, h)
+        return h
     raise ValueError(cfg.kind)
 
 
@@ -874,7 +990,10 @@ def forward_grannite(params: Dict, cfg: GNNConfig, x: jnp.ndarray,
 #   grasp — the block-sparse bitmap_spmm kernel over a compacted structure
 #           (the operands MUST carry `block_sparse`, padded to the bucket's
 #           grasp_max_nnz budget; dense plans must carry None).
-AGG_BACKENDS = ("dense", "grasp")
+#   edges — gather and segment-sum over edge-list operands (`EdgeOperands`,
+#           `forward_edges`): SAGE-mean over every in-neighbour, for buckets
+#           whose dense operands the device cannot hold.
+AGG_BACKENDS = ("dense", "grasp", "edges")
 
 # (cfg, capacity, batch, techniques, backend, fusion, shards)
 PlanKey = Tuple[GNNConfig, int, int, Techniques, str, str, int]
@@ -970,10 +1089,17 @@ def build_plan(cfg: GNNConfig, capacity: int, t: Techniques, *,
     `fusion="layer"` (DESIGN.md §11) executes each layer as one fused
     kernel pass — like the backend, a dispatch decision orthogonal to the
     tier, carried in the key because it changes the compiled blob.
+    `backend="edges"` (DESIGN.md §16) aggregates from `EdgeOperands`
+    (`forward_edges`); its operands' shapes follow the edge rung, and each
+    rung is one more trace of the same plan.
     """
     if backend not in AGG_BACKENDS:
         raise ValueError(f"unknown aggregation backend {backend!r}; pick "
                          f"from {AGG_BACKENDS}")
+    if backend == "edges" and not (has_edge_form(cfg) and fusion == "none"
+                                   and not t.quantgr):
+        raise ValueError("the edges backend runs unfused, unquantized "
+                         "SAGE-mean over every in-neighbour")
     if fusion not in FUSION_MODES:
         raise ValueError(f"unknown fusion mode {fusion!r}; pick from "
                          f"{FUSION_MODES}")
@@ -987,6 +1113,8 @@ def build_plan(cfg: GNNConfig, capacity: int, t: Techniques, *,
         if backend == "grasp":
             from repro.kernels.ops import bitmap_spmm_mode
             plan.grasp_ref_fallback = bitmap_spmm_mode() == "ref"
+        if backend == "edges":
+            return forward_edges(params, cfg, x, ops_)
         return forward_grannite(params, cfg, x, ops_, exec_t, quant=quant,
                                 tier_ops=tier_ops, fusion=fusion)
 

@@ -1,8 +1,9 @@
 """Byte-budgeted device cache for GraphServe's operand hierarchy (§13).
 
-CacheG (DESIGN.md §7) keeps five device-resident forms per attached graph
+CacheG (DESIGN.md §7) keeps six device-resident forms per attached graph
 — the fp32 operand set, the padded features, the derived int8 Â, the
-derived GraSp structure, and the sharded slice tuple — all keyed by
+derived GraSp structure, the sharded slice tuple, and the edge-list
+operands of a graph too large for dense ones (§16) — all keyed by
 (graph_id, structure_version) and NOTHING else. Unbounded, that pins
 O(cap²) device bytes per graph and OOMs long before production graph
 counts. This module bounds it:
@@ -41,7 +42,8 @@ Key = Tuple[int, int]                    # (graph_id, structure_version)
 
 # derived forms and the features (rank 0) evict before the primary they
 # hang off (rank 1); only primaries spill
-KIND_RANK = {"tier": 0, "grasp": 0, "features": 0, "operand": 1, "shard": 1}
+KIND_RANK = {"tier": 0, "grasp": 0, "features": 0, "operand": 1, "shard": 1,
+             "edges": 1}
 PRIMARY_KINDS = ("operand", "shard")
 
 
@@ -88,7 +90,7 @@ class CacheEntry:
 
 
 class DeviceCacheManager:
-    """The five device caches behind one byte budget (DESIGN.md §13)."""
+    """The six device caches behind one byte budget (DESIGN.md §13)."""
 
     def __init__(self, *, budget_bytes: Optional[int] = None,
                  spill_to_host: bool = True):
@@ -101,6 +103,7 @@ class DeviceCacheManager:
         self._spill: Dict[Tuple[str, Key], object] = {}
         self._clock = 0
         self._resident = 0
+        self._by_kind: Dict[str, int] = dict.fromkeys(KIND_RANK, 0)
         self.evictions = 0
         self.spilled = 0
         self.dropped = 0
@@ -110,6 +113,10 @@ class DeviceCacheManager:
     @property
     def resident_bytes(self) -> int:
         return self._resident
+
+    def kind_bytes(self, kind: str) -> int:
+        """Resident bytes of one kind's entries."""
+        return self._by_kind[kind]
 
     @property
     def spill_entries(self) -> int:
@@ -174,12 +181,14 @@ class DeviceCacheManager:
         old = self._entries.get((kind, key))
         if old is not None:
             self._resident -= old.nbytes
+            self._by_kind[kind] -= old.nbytes
         self._evict_until(nbytes, protect=set(protect) | {key})
         self._clock += 1
         self._entries[(kind, key)] = CacheEntry(
             kind=kind, key=key, value=value, nbytes=nbytes,
             remat_s=remat_s, spill_fn=spill_fn, last_use=self._clock)
         self._resident += nbytes
+        self._by_kind[kind] += nbytes
         return True
 
     def invalidate(self, key: Key) -> int:
@@ -191,6 +200,7 @@ class DeviceCacheManager:
             e = self._entries.pop((kind, key), None)
             if e is not None:
                 self._resident -= e.nbytes
+                self._by_kind[kind] -= e.nbytes
                 removed += 1
             if self._spill.pop((kind, key), None) is not None:
                 removed += 1
@@ -221,6 +231,7 @@ class DeviceCacheManager:
     def _evict(self, e: CacheEntry) -> None:
         del self._entries[(e.kind, e.key)]
         self._resident -= e.nbytes
+        self._by_kind[e.kind] -= e.nbytes
         self.evictions += 1
         spill_key = (e.kind, e.key)
         if (self.spill_to_host and e.kind in PRIMARY_KINDS

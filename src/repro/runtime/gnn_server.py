@@ -136,6 +136,15 @@ Engine contracts (what tests and operators may rely on):
     batch key (a dispatch never mixes fused and unfused plans) and warmup
     pre-traces BOTH fusion modes per (tier, backend) — mixed fused/unfused
     traffic replays warm.
+  * Edge-list form (DESIGN.md §16) — where a bucket's dense (cap, cap)
+    operands cannot be held on the device (`_dense_fits`, from the
+    device's own memory), a model registered with `agg_backend="auto"`
+    whose kind has an edge form (SAGE-mean over every in-neighbour) keeps
+    each graph as its edge list: `pad_graph(dense=False)` builds no dense
+    array, `attach` uploads `EdgeOperands` padded to the graph's edge rung
+    once per version, and the `edges` plan gathers and segment-sums them.
+    Such graphs serve unfused fp32; a model that cannot take the form is
+    refused at `attach` instead of allocating the dense operands.
 """
 from __future__ import annotations
 
@@ -149,6 +158,7 @@ import numpy as np
 
 from repro.core.graph import (BucketLadder, Graph, PaddedGraph,
                               apply_edge_delta, edge_index_from_adjacency,
+                              edge_list_delta, edge_rung,
                               is_symmetric_adjacency, pad_graph)
 from repro.core.layers import Techniques
 from repro.core.models import (FUSION_MODES, OPERAND_FIELDS, DeltaSpec,
@@ -159,8 +169,10 @@ from repro.core.models import (FUSION_MODES, OPERAND_FIELDS, DeltaSpec,
                                build_operands, build_plan,
                                build_sharded_operands, build_sharded_plan,
                                calibrate_tier, compact_operands,
-                               derive_tier_operands, forward_grannite,
-                               init_params, prepare_host_operands,
+                               derive_tier_operands, edge_operands,
+                               EdgeOperands, forward_grannite,
+                               has_edge_form, init_params, layer_widths,
+                               prepare_host_operands,
                                realize_operands, sharded_exchange_widths,
                                stack_operands, stack_shard_slices,
                                stack_tier_operands, unshard_logits)
@@ -199,6 +211,15 @@ AGG_BACKEND_MODES = ("dense", "auto", "grasp")
 # (model, bucket, tier, agg backend, fusion mode, shard count — 0 unsharded;
 # for a sharded request the bucket element is the PER-SHARD capacity)
 BatchKey = Tuple[str, int, str, str, str, int]
+
+
+def device_memory_bytes() -> Optional[int]:
+    """Memory of the device the plans run on, as its backend reports it
+    (`bytes_limit`); None where it reports none, as the CPU does, and then
+    every dense bucket counts as held."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return int(limit) if limit else None
 
 
 def best_fill_key(stats: Dict[BatchKey, Tuple[int, int]], batch_slots: int,
@@ -283,7 +304,7 @@ def pending_stats(reqs: Sequence["GNNRequest"]
     """Fold a pending-request sequence into `best_fill_key` stats."""
     stats: Dict[BatchKey, Tuple[int, int]] = {}
     for i, r in enumerate(reqs):
-        k = (r.model, r.bucket, r.tier, r.backend, r.fusion, r.shards)
+        k = r.batch_key
         c = stats.get(k)
         stats[k] = (1, i) if c is None else (c[0] + 1, c[1])
     return stats
@@ -294,7 +315,7 @@ def edf_pending_stats(reqs: Sequence["GNNRequest"], now: float
     """Fold pending requests into `edf_best_fill_key` stats at time `now`."""
     stats: Dict[BatchKey, Tuple[int, int, float]] = {}
     for i, r in enumerate(reqs):
-        k = (r.model, r.bucket, r.tier, r.backend, r.fusion, r.shards)
+        k = r.batch_key
         slack = (r.deadline_s - now if r.deadline_s is not None
                  else float("inf"))
         c = stats.get(k)
@@ -351,7 +372,8 @@ class GNNRequest:
     uid: int
     model: str
     pg: PaddedGraph
-    ops: GranniteOperands
+    ops: GranniteOperands                  # EdgeOperands when backend is
+    # "edges" (§16)
     bucket: int
     submitted_s: float
     x: Optional[jnp.ndarray] = None        # (cap, F) device features of an
@@ -376,6 +398,16 @@ class GNNRequest:
     done: bool = False
     preds: Optional[np.ndarray] = None     # (num_nodes,) argmax classes
     logits: Optional[np.ndarray] = None    # (num_nodes, C) if return_logits
+
+    @property
+    def batch_key(self) -> BatchKey:
+        """What the requests of one dispatch share. An edges request's
+        backend element also names its edge rung (§16): operands of two
+        rungs do not stack."""
+        backend = (f"edges/{self.ops.src.shape[0]}" if self.backend == "edges"
+                   else self.backend)
+        return (self.model, self.bucket, self.tier, backend, self.fusion,
+                self.shards)
 
 
 @dataclasses.dataclass
@@ -459,8 +491,11 @@ class GraphServe:
         self._block_compactor = build_block_compactor()
         self._delta_patcher = build_delta_patcher()
         # the dispatch's device-side stack of its slots' resident features:
-        # one compiled program per (batch_slots, bucket, in_feats)
+        # one compiled program per (batch_slots, bucket, in_feats); and of
+        # edge-form slots' operands, one per (batch_slots, bucket, rung)
         self._stack_x = jax.jit(jnp.stack)
+        self._stack_edges = jax.jit(lambda ops: jax.tree_util.tree_map(
+            lambda *a: jnp.stack(a), *ops))
         if self.sc.admission not in ("evict", "reject"):
             raise ValueError(f"unknown admission policy "
                              f"{self.sc.admission!r}; pick evict|reject")
@@ -518,6 +553,9 @@ class GraphServe:
                         "delta_halo_bytes_exchanged": 0,
                         "delta_halo_bytes_full": 0,
                         "delta_dirty_rows": 0,
+                        # §16: real directed edges the edges plans
+                        # aggregated, once per layer
+                        "edges_dispatched": 0,
                         "deadline_misses": 0, "shed_requests": 0}
 
     def _count(self, name: str, delta=1) -> None:
@@ -554,6 +592,9 @@ class GraphServe:
         never exceed it."""
         cfg = self.models[model].cfg
         nf = len(OPERAND_FIELDS[cfg.kind])
+        if not pg.dense:
+            return 4 * (2 * edge_rung(pg.edge_index.shape[1]) + pg.capacity
+                        + pg.capacity * cfg.in_feats)
         if part is not None:
             return estimate_shard_entry_bytes(part.shards, part.shard_cap,
                                               part.full_rows, nf,
@@ -678,7 +719,7 @@ class GraphServe:
         ORDER cold keys — the first measured sample replaces it outright,
         and `ewma_vs_model` in `summary()` tracks how wrong it was."""
         cfg = self.models[model].cfg
-        widths = [cfg.in_feats, cfg.hidden, cfg.num_classes]
+        widths = layer_widths(cfg)
         b = self.sc.replica_groups if shards else self.sc.batch_slots
         cap = bucket
         quant = self.models[model].tiers[tier].quantgr
@@ -784,17 +825,28 @@ class GraphServe:
         placeholder shard slices, so a giant graph attaching AFTER warmup
         serves with zero new traces — the zero-recompile contract covers
         mixed sharded/unsharded traffic too.
+
+        At a bucket whose dense operands the device cannot hold (§16) no
+        dense or fused plan is compiled: the edges plan is, at the edge
+        rung of each edge-form graph attached there so far.
         """
         buckets = buckets if buckets is not None else self.sc.ladder.buckets
         b = self.sc.batch_slots
         warm_cal: Dict[Tuple[str, str], Dict] = {}
         warmed: set = set()
         for bucket in buckets:
-            empty = pad_graph(Graph(edge_index=np.zeros((2, 0), np.int32),
-                                    num_nodes=1,
-                                    features=np.zeros((1, 1), np.float32)),
-                              capacity=bucket)
+            empty = None
             for name, e in self.models.items():
+                if not self._dense_fits(e.cfg, bucket):
+                    # no dense plan here (§16): the edge plans at the rungs
+                    # of the graphs attached so far
+                    self._warm_edges(name, bucket, warmed)
+                    continue
+                if empty is None:
+                    empty = pad_graph(Graph(
+                        edge_index=np.zeros((2, 0), np.int32), num_nodes=1,
+                        features=np.zeros((1, 1), np.float32)),
+                        capacity=bucket)
                 pg = dataclasses.replace(
                     empty, features=np.zeros((bucket, e.cfg.in_feats),
                                              np.float32))
@@ -910,6 +962,31 @@ class GraphServe:
         self._warm_blobs = self.compiled_blobs
         return self._warm_blobs
 
+    def _warm_edges(self, name: str, bucket: int, warmed: set) -> None:
+        """Compile the edges plan of one (model, bucket) at the edge rung
+        of every edge-form graph of that model attached there: a rung is
+        known only once a graph brings its edges."""
+        e = self.models[name]
+        b = self.sc.batch_slots
+        with self._lock:
+            rungs = {edge_rung(pg.edge_index.shape[1])
+                     for m, pg in self.graphs.values()
+                     if m == name and not pg.dense and pg.capacity == bucket}
+        if not rungs:
+            return
+        plan = self.plan_for(name, bucket, "fp32", "edges", "none")
+        x = self._stack_x([jnp.zeros((bucket, e.cfg.in_feats), jnp.float32)]
+                          * b)
+        for rung in sorted(rungs):
+            if (name, plan.key, rung) in warmed:
+                continue
+            warmed.add((name, plan.key, rung))
+            idx = jnp.zeros((rung,), jnp.int32)
+            eo = EdgeOperands(src=idx, dst=idx,
+                              inv_deg=jnp.zeros((bucket,), jnp.float32))
+            plan(e.params, x, self._stack_edges([eo] * b)
+                 ).block_until_ready()
+
     def _delta_pads(self, cap: int) -> Tuple[int, int]:
         """(touched, flip) static pad widths of the delta patcher at one
         capacity — the §13 delta-vs-rebuild threshold in shape form."""
@@ -971,7 +1048,7 @@ class GraphServe:
         agreement otherwise), in percentage points. Pure value work: no
         new traces, `assert_warm()` still holds afterwards.
         """
-        return self._calibrate(model, self.sc.ladder.pad(g), force=force)
+        return self._calibrate(model, self._pad(model, g), force=force)
 
     def _calibrate(self, model: str, pg: PaddedGraph, *,
                    force: bool = False) -> Dict[str, float]:
@@ -1164,6 +1241,78 @@ class GraphServe:
                                            max_nnz=grasp_max_nnz(capacity))
         return backend, bsp
 
+    def _dense_fits(self, cfg: GNNConfig, capacity: int) -> bool:
+        """Whether dense operands at this bucket can be held (§16): one
+        tenant's cached entry plus a dispatch's stacked copy of
+        `batch_slots` of them in half the device's memory, the other half
+        left to features, activations and the other tenants."""
+        mem = device_memory_bytes()
+        if mem is None:
+            return True
+        entry = estimate_dense_entry_bytes(len(OPERAND_FIELDS[cfg.kind]),
+                                           capacity)
+        return (self.sc.batch_slots + 1) * entry <= mem // 2
+
+    def _pad(self, model: str, g: Graph,
+             capacity: Optional[int] = None) -> PaddedGraph:
+        """NodePad `g` into its ladder bucket (or `capacity`), dense where
+        the bucket's dense operands fit the device and as its edge list
+        where they do not (§16): for a model registered "auto" whose kind
+        has an edge form; any other model is refused here, before a dense
+        array is built."""
+        e = self.models[model]
+        cap = (capacity if capacity is not None
+               else self.sc.ladder.bucket_for(g.num_nodes))
+        if self._dense_fits(e.cfg, cap):
+            return pad_graph(g, capacity=cap)
+        nbytes = estimate_dense_entry_bytes(len(OPERAND_FIELDS[e.cfg.kind]),
+                                            cap)
+        if not (e.agg_backend == "auto" and has_edge_form(e.cfg)):
+            raise ValueError(
+                f"model {model!r}: dense operands at bucket {cap} "
+                f"({nbytes} bytes a graph) do not fit the device's "
+                f"{device_memory_bytes()} bytes; only SAGE-mean over every "
+                f"in-neighbour registered with agg_backend='auto' serves "
+                f"such a bucket, from its edge list")
+        if e.default_fusion != "none" or any(t.quantgr
+                                             for t in e.tiers.values()):
+            raise ValueError(
+                f"model {model!r}: edge-list graphs serve unfused fp32; "
+                f"register it with fusion='none' and no quantized tier")
+        return pad_graph(g, capacity=cap, dense=False)
+
+    def _partition(self, model: str, g: Graph) -> GraphShards:
+        """Partition a graph past the top bucket (§12); sharded plans run
+        two-layer models without BatchNorm only."""
+        cfg = self.models[model].cfg
+        if cfg.num_layers != 2 or cfg.batch_norm:
+            raise ValueError(f"model {model!r}: sharded plans run two-layer "
+                             f"models without BatchNorm")
+        return partition_for_ladder(g.edge_index, g.num_nodes,
+                                    self.sc.ladder, self.sc.shard_counts,
+                                    method=self.sc.partition_method)
+
+    def _edge_operands(self, graph_id: Optional[int], pg: PaddedGraph
+                       ) -> EdgeOperands:
+        """Build and upload one edge-form graph's operands, under an
+        `operands.edges` span (id: the graph, None for a one-shot
+        request), their bytes counted in `operand_bytes_h2d`."""
+        with self.tracer.span("operands.edges", self.clock, graph_id):
+            eo = edge_operands(pg)
+        self._count("operand_bytes_h2d", pytree_nbytes(eo))
+        return eo
+
+    def _put_edges(self, graph_id: int, ver: int,
+                   eo: EdgeOperands) -> None:
+        """Cache one version's edge operands, if that version is still
+        current. No spill form: a miss rebuilds from the edge list the
+        engine keeps."""
+        nb = pytree_nbytes(eo)
+        with self._lock:
+            if self._graph_version.get(graph_id) == ver:
+                self._cache.put("edges", (graph_id, ver), eo, nbytes=nb,
+                                remat_s=transfer_cost(nb))
+
     def _resolve_and_build(self, model: str, tier: str, pg: PaddedGraph
                            ) -> Tuple[str, GranniteOperands]:
         """One-shot intake: resolve this request's agg backend AND build
@@ -1177,6 +1326,8 @@ class GraphServe:
         The eager path decides from host-side `block_stats`, whose bitmap
         the host block build then reuses instead of re-scanning."""
         e = self.models[model]
+        if not pg.dense:
+            return "edges", self._edge_operands(None, pg)
         if not self._grasp_capable(e) or e.tiers[tier].quantgr:
             return "dense", self._device_operands(model, pg)
         from repro.core.graph import is_symmetric_adjacency
@@ -1260,6 +1411,9 @@ class GraphServe:
         if not tier_resolved:
             tier = self._route_tier(model, tier, tolerance, pg.capacity)
         fusion = self._resolve_fusion(model, fusion)
+        if not pg.dense and fusion != "none":
+            raise ValueError("edge-list graphs serve fusion='none' only "
+                             "(DESIGN.md §16)")
         if backend is None:
             backend, ops = self._resolve_and_build(model, tier, pg)
         elif ops is None:
@@ -1298,7 +1452,7 @@ class GraphServe:
                        tolerance: Optional[float] = None) -> GNNRequest:
         """HOST stage of a one-shot request: NodePad padding + operand
         build/packing. Scheduler-callable from any worker thread."""
-        return self._prepare(model, self.sc.ladder.pad(g), tier=tier,
+        return self._prepare(model, self._pad(model, g), tier=tier,
                              fusion=fusion, submitted_s=submitted_s,
                              deadline_ms=deadline_ms, tolerance=tolerance)
 
@@ -1331,6 +1485,10 @@ class GraphServe:
         dispatches through the sharded plan. Without `shard_counts` the
         oversized graph raises, exactly as before.
 
+        Where the bucket's dense operands do not fit the device, the graph
+        keeps its edge list (§16, `_pad`), and its edge operands are built
+        and made resident here (after an `update()`, by the next query).
+
         With `device_cache_budget_bytes` set, attach() is the admission
         gate (§13): a graph whose projected primary operand entry can
         NEVER fit the budget raises `CacheAdmissionError` outright; under
@@ -1339,15 +1497,14 @@ class GraphServe:
         lets insert-time eviction make room on first query."""
         part = None
         try:
-            pg = self.sc.ladder.pad(g)
+            cap = self.sc.ladder.bucket_for(g.num_nodes)
         except ValueError:
             if not self.sc.shard_counts:
                 raise
-            part = partition_for_ladder(g.edge_index, g.num_nodes,
-                                        self.sc.ladder,
-                                        self.sc.shard_counts,
-                                        method=self.sc.partition_method)
+            part = self._partition(model, g)
             pg = pad_graph(g, capacity=part.full_rows)
+        else:
+            pg = self._pad(model, g, cap)
         if self.sc.device_cache_budget_bytes is not None:
             projected = self._projected_primary_bytes(model, pg, part)
             with self._lock:
@@ -1373,6 +1530,10 @@ class GraphServe:
             self._graph_version[gid] = 0
             if part is not None:
                 self._sharded[gid] = (part, g)
+        if not pg.dense:
+            # the host work of the edge form happens here, not in the
+            # first query's host stage
+            self._put_edges(gid, 0, self._edge_operands(gid, pg))
         return gid
 
     def detach(self, graph_id: int) -> None:
@@ -1407,18 +1568,22 @@ class GraphServe:
         bucket) pair is a pure value update like the unsharded case, a
         changed one counts as a rebucket. A graph that shrinks back into
         the ladder leaves the sharded path; an unsharded graph that grows
-        past the top bucket enters it (rebucket either way)."""
+        past the top bucket enters it (rebucket either way). An edge-form
+        graph (§16) is padded anew from the new edge list."""
         with self._lock:
             model, pg = self.graphs[graph_id]
             sharded = self._sharded.get(graph_id)
         new_sharded = None
-        if sharded is not None:
-            part, g_old = sharded
+        if sharded is None and pg.dense and num_nodes <= pg.capacity:
+            # GrAd inside the bucket: a pure value update, no recompile
+            pg, rebucketed = self.sc.ladder.grow(pg, edge_index, num_nodes,
+                                                 features)
+        else:
+            held = sharded[1] if sharded is not None else pg
 
-            # carry supervision arrays across the size change (same policy
-            # as BucketLadder.grow): new nodes are unlabeled, shrinks
-            # truncate — a stale (old-length) labels array would break
-            # padding the first time a sharded graph changes size
+            # carry supervision arrays across the size change: new nodes
+            # are unlabeled, shrinks truncate — a stale (old-length)
+            # labels array would break padding at the first size change
             def _resized(arr, fill, dtype):
                 if arr is None:
                     return None
@@ -1429,38 +1594,26 @@ class GraphServe:
 
             g2 = Graph(edge_index=edge_index, num_nodes=num_nodes,
                        features=features,
-                       labels=_resized(g_old.labels, -1, np.int32),
-                       train_mask=_resized(g_old.train_mask, False, bool),
-                       test_mask=_resized(g_old.test_mask, False, bool))
+                       labels=_resized(held.labels, -1, np.int32),
+                       train_mask=_resized(held.train_mask, False, bool),
+                       test_mask=_resized(held.test_mask, False, bool))
             try:
-                pg = self.sc.ladder.pad(g2)
-                rebucketed = True           # shrank back into the ladder
-            except ValueError:
-                part2 = partition_for_ladder(g2.edge_index, g2.num_nodes,
-                                             self.sc.ladder,
-                                             self.sc.shard_counts,
-                                             method=self.sc.partition_method)
-                pg = pad_graph(g2, capacity=part2.full_rows)
-                new_sharded = (part2, g2)
-                rebucketed = ((part2.shards, part2.shard_cap)
-                              != (part.shards, part.shard_cap))
-        else:
-            try:
-                pg, rebucketed = self.sc.ladder.grow(pg, edge_index,
-                                                     num_nodes, features)
+                cap = self.sc.ladder.bucket_for(num_nodes)
             except ValueError:
                 if not self.sc.shard_counts:
                     raise
-                # grew off the top of the ladder: enter the sharded path
-                g2 = Graph(edge_index=edge_index, num_nodes=num_nodes,
-                           features=features)
-                part2 = partition_for_ladder(g2.edge_index, g2.num_nodes,
-                                             self.sc.ladder,
-                                             self.sc.shard_counts,
-                                             method=self.sc.partition_method)
+                # past the top of the ladder: the sharded path
+                part2 = self._partition(model, g2)
                 pg = pad_graph(g2, capacity=part2.full_rows)
                 new_sharded = (part2, g2)
-                rebucketed = True
+                rebucketed = sharded is None or (
+                    (part2.shards, part2.shard_cap)
+                    != (sharded[0].shards, sharded[0].shard_cap))
+            else:
+                # a new bucket, back from the sharded path, or an edge-form
+                # graph, which keeps no dense form to update in place
+                rebucketed = sharded is not None or cap != pg.capacity
+                pg = self._pad(model, g2, cap)
         with self._lock:
             self.graphs[graph_id] = (model, pg)
             ver = self._graph_version[graph_id]
@@ -1607,6 +1760,17 @@ class GraphServe:
             ver = self._graph_version[graph_id]
             sharded = self._sharded.get(graph_id)
         e = self.models[model]
+        if not pg.dense:
+            # §16: no dense form to patch; the edge list changes and the
+            # graph rebuilds, as SAGE's sampled masks do
+            edge_index = edge_list_delta(pg.edge_index, pg.num_nodes,
+                                         add_edges, remove_edges)
+            if edge_index is None:
+                return True
+            self._count("delta_fallbacks")
+            self.update(graph_id, edge_index, pg.num_nodes,
+                        pg.features[:pg.num_nodes])
+            return False
         if not is_symmetric_adjacency(pg.adj):
             raise ValueError(
                 "update_delta edits undirected edge pairs; directed "
@@ -1781,12 +1945,28 @@ class GraphServe:
                                          submitted_s=submitted_s,
                                          deadline_ms=deadline_ms,
                                          tolerance=tolerance)
+        key = (graph_id, ver)
+        if not pg.dense:
+            # §16: the edge operands attach (or an update's first query)
+            # made resident
+            with self._lock:
+                ops = self._cache.get("edges", key)
+            if ops is None:
+                self._count("operand_cache_misses")
+                ops = self._edge_operands(graph_id, pg)
+                self._put_edges(graph_id, ver, ops)
+            else:
+                self._count("operand_cache_hits")
+            return self._prepare(model, pg, ops,
+                                 x=self._resident_features(graph_id, ver, pg),
+                                 tier=tier, backend="edges", fusion=fusion,
+                                 submitted_s=submitted_s,
+                                 deadline_ms=deadline_ms, tolerance=tolerance)
         if not self.sc.use_cacheg:
             return self._prepare(model, pg, tier=tier, fusion=fusion,
                                  submitted_s=submitted_s,
                                  deadline_ms=deadline_ms,
                                  tolerance=tolerance)
-        key = (graph_id, ver)
         with self._lock:
             ops = self._cache.get("operand", key)
         if ops is None:
@@ -1813,14 +1993,7 @@ class GraphServe:
                                                         model))
         else:
             self._count("operand_cache_hits")
-        with self._lock:
-            x = self._cache.get("features", key)
-        if x is None:
-            x = self._device_features(pg)
-            with self._lock:
-                if self._graph_version.get(graph_id) == ver:
-                    self._cache.put("features", key, x, nbytes=x.nbytes,
-                                    remat_s=transfer_cost(x.nbytes))
+        x = self._resident_features(graph_id, ver, pg)
         tops = None
         resolved = self._route_tier(model, tier, tolerance, pg.capacity)
         e = self.models[model]
@@ -1859,6 +2032,21 @@ class GraphServe:
                              backend=backend, fusion=fusion,
                              submitted_s=submitted_s,
                              deadline_ms=deadline_ms, tolerance=tolerance)
+
+    def _resident_features(self, graph_id: int, ver: int, pg: PaddedGraph
+                           ) -> jnp.ndarray:
+        """One version's padded features on the device: cached, or put
+        there once (`feature_bytes_h2d`) and cached if still current."""
+        key = (graph_id, ver)
+        with self._lock:
+            x = self._cache.get("features", key)
+        if x is None:
+            x = self._device_features(pg)
+            with self._lock:
+                if self._graph_version.get(graph_id) == ver:
+                    self._cache.put("features", key, x, nbytes=x.nbytes,
+                                    remat_s=transfer_cost(x.nbytes))
+        return x
 
     def _prepare_sharded(self, graph_id: int, model: str, pg: PaddedGraph,
                          sharded: Tuple[GraphShards, Graph], ver: int, *,
@@ -1980,9 +2168,7 @@ class GraphServe:
                                 replica_slots=self.sc.replica_groups)
         # sharded: one request per replica row (§15; width-1 when R == 1)
         take = self.sc.replica_groups if key[5] else self.sc.batch_slots
-        batch = [r for r in self.queue
-                 if (r.model, r.bucket, r.tier, r.backend, r.fusion,
-                     r.shards) == key][:take]
+        batch = [r for r in self.queue if r.batch_key == key][:take]
         taken = {r.uid for r in batch}
         self.queue = [r for r in self.queue if r.uid not in taken]
         self._execute_batch(batch)
@@ -2022,8 +2208,7 @@ class GraphServe:
             self._execute_sharded(batch)
             return
         b = self.sc.batch_slots
-        bkey = (head.model, head.bucket, head.tier, head.backend,
-                head.fusion, 0)
+        bkey = head.batch_key
         serial = self._next_serial()
         span = self.tracer.span
         with span("dispatch", self.clock, serial) as disp:
@@ -2037,10 +2222,17 @@ class GraphServe:
             with span("dispatch.stack", self.clock, serial):
                 x = self._stack_x([r.x for r in slots])
             with span("dispatch.operands", self.clock, serial):
-                ops = stack_operands([r.ops for r in slots])
+                if head.backend == "edges":
+                    ops = self._stack_edges([r.ops for r in slots])
+                else:
+                    ops = stack_operands([r.ops for r in slots])
                 tops = (stack_tier_operands([r.tier_ops for r in slots])
                         if slots[0].tier_ops is not None else None)
-            with span("dispatch.device", self.clock, serial):
+            # an edges dispatch names the real nodes and edges it aggregates
+            real = ({"nodes": sum(r.pg.num_nodes for r in batch),
+                     "edges": sum(r.pg.edge_index.shape[1] for r in batch)}
+                    if head.backend == "edges" else {})
+            with span("dispatch.device", self.clock, serial, **real):
                 plan = self.plan_for(head.model, head.bucket, head.tier,
                                      head.backend, head.fusion)
                 logits = plan(e.params, x, ops, e.calibrations.get(head.tier),
@@ -2061,6 +2253,8 @@ class GraphServe:
                     if self.sc.return_logits:
                         r.logits = lg
             counts = {}
+            if real:
+                counts["edges_dispatched"] = e.cfg.num_layers * real["edges"]
             if head.backend == "grasp":
                 counts["grasp_batches"] = 1
                 if ran_dense_fallback:
@@ -2305,6 +2499,10 @@ class GraphServe:
                 self.metrics["delta_halo_bytes_exchanged"],
             "delta_halo_bytes_full": self.metrics["delta_halo_bytes_full"],
             "delta_dirty_rows": self.metrics["delta_dirty_rows"],
+            # §16 edge-list form: resident edge-operand bytes, and the real
+            # edges its plans aggregated (once per layer)
+            "edge_operand_bytes": self._cache.kind_bytes("edges"),
+            "edges_dispatched": self.metrics["edges_dispatched"],
             # §14 SLO loop: deadline outcomes, governor decisions, and the
             # measured-vs-modelled drift of the latency bank (mean
             # EWMA/seed ratio over keys with both — the signal that the

@@ -270,8 +270,7 @@ class PipelineScheduler:
                 self._cond.notify_all()
 
     def _push_ready_locked(self, ticket: int, req: GNNRequest) -> None:
-        key = (req.model, req.bucket, req.tier, req.backend, req.fusion,
-               req.shards)
+        key = req.batch_key
         self._ready.setdefault(key, deque()).append(
             (self._arrival_serial, self.engine.clock.now(), req))
         self._arrival_serial += 1

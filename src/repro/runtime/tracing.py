@@ -20,16 +20,30 @@ Span names (`PERF.md` lists the metric that reads each):
   dispatch           one whole device-stage dispatch (id: serial)
   dispatch.stack     stacking the slots' resident features on the device
   dispatch.operands  stacking the resident operands on the device
-  dispatch.device    the plan call through `block_until_ready`
+  dispatch.device    the plan call through `block_until_ready`; on an
+                     edge-list dispatch (DESIGN.md §16) its attrs `nodes`
+                     and `edges` count the real nodes and directed edges
+                     it aggregates
   dispatch.d2h       copying the logits back and unpacking each answer
   request.queue      submission to the start of the answering dispatch
                      (id: uid, parent: serial; ring only)
+  operands.edges     building and uploading one graph's edge-list operands,
+                     at `attach`, after an update, or for a one-shot request
+                     (id: graph_id, None for a one-shot request)
 
 The `dispatch.*` spans tile `dispatch` (the sharded path has no
 `.stack`: its replica stack of features is part of `.operands`); what
 they leave uncovered is the dispatch's bookkeeping under the engine lock.
 No dispatch sends anything to the device: every request's features are
 there since its host stage.
+
+Counters of the edge-list form (`GraphServe.summary()`):
+`edge_operand_bytes`, the resident edge operands' device bytes, and
+`edges_dispatched`, real directed edges aggregated, once per layer. Inside
+the edges plan each layer's aggregation runs under the
+`jax.named_scope("graphserve.agg.l<i>")`; its device ops are the ones
+whose f32 output has bucket + 1 rows (the spare row of the padding
+edges), which is how a device trace tells them apart.
 """
 from __future__ import annotations
 

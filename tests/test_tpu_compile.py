@@ -1,4 +1,5 @@
-"""The main-path Pallas kernels compile for a TPU v5e, with no chip attached.
+"""The main-path Pallas kernels, and the edge-list plan at ogbn-arxiv
+size, compile for a TPU v5e, with no chip attached.
 
 Interpret mode (every other kernel test) cannot see what the TPU compiler
 refuses: block shapes off the (8, 128) tiling, lane slices that are not
@@ -140,3 +141,27 @@ def test_kernel_compiles_for_v5e(name, shape, monkeypatch):
     compiled = jax.jit(fn).lower(*args(shape)).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
 
+
+
+def test_edges_plan_compiles_for_v5e_at_arxiv_size(shape):
+    """The edge-list plan (DESIGN.md §16) of OGB's ogbn-arxiv SAGE at its
+    bucket and the edge rung of its 2.33 M directed edges, batch 1, at
+    "highest": the chip's compiler accepts it, and the plan's own
+    temporaries stay far below the chip's memory."""
+    from repro.configs.gnn import sage
+    from repro.core.graph import edge_rung
+    from repro.core.models import EdgeOperands, build_plan, init_params
+    from repro.runtime.gnn_server import tier_techniques
+    cfg, cap = sage("arxiv"), 169984
+    rung = edge_rung(2331684)
+    plan = build_plan(cfg, cap, tier_techniques("sage")["fp32"],
+                      batch_size=1, backend="edges")
+    params = jax.tree_util.tree_map(
+        lambda leaf: shape(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    eo = EdgeOperands(shape((1, rung), jnp.int32), shape((1, rung), jnp.int32),
+                      shape((1, cap)))
+    with jax.default_matmul_precision("highest"):
+        compiled = plan.fn.lower(params, shape((1, cap, cfg.in_feats)), eo,
+                                 None, None).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

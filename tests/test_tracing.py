@@ -306,3 +306,65 @@ def test_profiler_sees_every_live_span_on_the_ring_clock(warm_engine,
         assert len(theirs) == len(mine), name
         offsets += [b - a for a, b in zip(mine, theirs)]
     assert max(offsets) - min(offsets) < 1e-3, (min(offsets), max(offsets))
+
+
+def _edge_engine(monkeypatch, tracer):
+    """A three-layer SAGE engine whose bucket only the edge-list form holds
+    (DESIGN.md §16): 1 MiB of device memory steered here."""
+    from repro.runtime import gnn_server
+    monkeypatch.setattr(gnn_server, "device_memory_bytes", lambda: 1 << 20)
+    eng = GraphServe(GraphServeConfig(ladder=BucketLadder(buckets=(256,)),
+                                      batch_slots=SLOTS), tracer=tracer)
+    eng.register_model("sage", GNNConfig(
+        kind="sage", in_feats=IN_FEATS, hidden=32, num_classes=CLASSES,
+        num_layers=3, batch_norm=True, max_neighbors=None),
+        agg_backend="auto")
+    return eng
+
+
+def test_edge_operands_span_once_per_miss(monkeypatch):
+    tr = Tracer()
+    eng = _edge_engine(monkeypatch, tr)
+    gid = eng.attach(_graph(200, seed=1), model="sage")
+    built = tr.spans("operands.edges")
+    assert [s.id for s in built] == [gid]                 # built at attach
+    for _ in range(3):
+        eng.query(gid)
+    eng.run()
+    assert len(tr.spans("operands.edges")) == 1           # hits build none
+    assert eng.metrics["operand_cache_hits"] == 3
+    g2 = _graph(220, seed=2)
+    eng.update(gid, g2.edge_index, g2.num_nodes, g2.features)
+    eng.query(gid)
+    eng.query(gid)
+    eng.run()
+    assert len(tr.spans("operands.edges")) == 2           # one miss
+    assert eng.metrics["operand_cache_misses"] == 1
+
+
+def test_edge_operand_bytes_follow_attach_and_detach(monkeypatch):
+    eng = _edge_engine(monkeypatch, Tracer())
+    assert eng.summary()["edge_operand_bytes"] == 0
+    gids = [eng.attach(_graph(200 + 10 * i, seed=i), model="sage")
+            for i in range(2)]
+    both = eng.summary()["edge_operand_bytes"]
+    assert both > 0
+    eng.detach(gids[0])
+    assert 0 < eng.summary()["edge_operand_bytes"] < both
+    eng.detach(gids[1])
+    assert eng.summary()["edge_operand_bytes"] == 0
+
+
+def test_edges_dispatched_counts_real_edges_once_per_layer(monkeypatch):
+    tr = Tracer()
+    eng = _edge_engine(monkeypatch, tr)
+    gs = [_graph(200, seed=3), _graph(210, seed=4)]
+    gids = [eng.attach(g, model="sage") for g in gs]
+    for gid in gids:
+        eng.query(gid)
+    eng.run()
+    edges = sum(g.num_edges for g in gs)
+    assert eng.metrics["batches"] == 1
+    assert eng.metrics["edges_dispatched"] == 3 * edges
+    (device,) = tr.spans("dispatch.device")
+    assert device.attrs == {"nodes": 410, "edges": edges}
