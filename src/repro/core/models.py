@@ -211,6 +211,23 @@ def build_operands(pg: PaddedGraph, cfg: GNNConfig, *, grasp: bool = False,
         block_sparse=to_block_sparse(pg.norm_adj) if grasp else None, **vals)
 
 
+def check_batchable(ops: Sequence[GranniteOperands]) -> bool:
+    """Refuse operand sets that cannot share one vmapped dispatch; return
+    whether they carry GraSp block structures (all of them, or none)."""
+    if any(o.quant is not None for o in ops):
+        raise ValueError(
+            "per-graph offline QuantGr operands (ops.quant, built by "
+            "calibrate_quant) cannot be batched — their QuantizedAgg bakes "
+            "one graph's Â; serve quantized tiers through the model-level "
+            "calibrate_tier path instead (DESIGN.md §8)")
+    with_blocks = [o.block_sparse is not None for o in ops]
+    if any(with_blocks) and not all(with_blocks):
+        raise ValueError(
+            "cannot batch a mix of GraSp and dense operand sets — resolve "
+            "one aggregation backend per batch (DESIGN.md §10)")
+    return all(with_blocks)
+
+
 def stack_operands(ops: Sequence[GranniteOperands]) -> GranniteOperands:
     """Stack per-graph operands into one batched (B, ...) operand set.
 
@@ -225,17 +242,7 @@ def stack_operands(ops: Sequence[GranniteOperands]) -> GranniteOperands:
     calibration is model-level (`calibrate_tier`) and rides the plan's
     broadcast `quant` argument, never the operands (DESIGN.md §8).
     """
-    if any(o.quant is not None for o in ops):
-        raise ValueError(
-            "per-graph offline QuantGr operands (ops.quant, built by "
-            "calibrate_quant) cannot be batched — their QuantizedAgg bakes "
-            "one graph's Â; serve quantized tiers through the model-level "
-            "calibrate_tier path instead (DESIGN.md §8)")
-    with_blocks = [o.block_sparse is not None for o in ops]
-    if any(with_blocks) and not all(with_blocks):
-        raise ValueError(
-            "cannot batch a mix of GraSp and dense operand sets — resolve "
-            "one aggregation backend per batch (DESIGN.md §10)")
+    with_blocks = check_batchable(ops)
     return GranniteOperands(
         norm_adj=jnp.stack([o.norm_adj for o in ops]),
         mask_mult=jnp.stack([o.mask_mult for o in ops]),
@@ -243,7 +250,7 @@ def stack_operands(ops: Sequence[GranniteOperands]) -> GranniteOperands:
         sample_mask=jnp.stack([o.sample_mask for o in ops]),
         mean_mask=jnp.stack([o.mean_mask for o in ops]),
         block_sparse=(stack_block_sparse([o.block_sparse for o in ops])
-                      if all(with_blocks) else None),
+                      if with_blocks else None),
     )
 
 
@@ -634,6 +641,40 @@ def stack_tier_operands(tos: Sequence[TierOperands]) -> TierOperands:
     """Stack per-graph tier operands for one vmapped batched dispatch."""
     return TierOperands(agg_aq=jnp.stack([t.agg_aq for t in tos]),
                         agg_a_scale=jnp.stack([t.agg_a_scale for t in tos]))
+
+
+@dataclasses.dataclass
+class OperandStacker:
+    """The dispatch's jitted operand stack: ONE compiled program copies the
+    slots' resident `GranniteOperands` (block structures included) and,
+    when the tier carries them, their `TierOperands` into the batched sets
+    `stack_operands` and `stack_tier_operands` return — the same exact
+    copies, where the eager forms launch one program per op per field.
+    `check_batchable` runs on the host before the compiled call. Same
+    trace accounting as ExecutionPlan: jit specializes on the slot count,
+    the operand shapes and which optional parts are present, so
+    `trace_count` is the number of such combinations compiled — GraphServe
+    warms them in `warmup()` and reports the count in `summary()`."""
+    fn: Callable = dataclasses.field(default=None, repr=False)
+    trace_count: int = 0
+
+    def __call__(self, ops: Sequence[GranniteOperands],
+                 tier_ops: Optional[Sequence[TierOperands]] = None
+                 ) -> Tuple[GranniteOperands, Optional[TierOperands]]:
+        check_batchable(ops)
+        return self.fn(ops, tier_ops)
+
+
+def build_operand_stacker() -> OperandStacker:
+    st = OperandStacker()
+
+    def _stack_operands(ops, tier_ops):
+        st.trace_count += 1               # python side effect: traces only
+        return (stack_operands(ops),
+                None if tier_ops is None else stack_tier_operands(tier_ops))
+
+    st.fn = jax.jit(_stack_operands)
+    return st
 
 
 @dataclasses.dataclass

@@ -27,8 +27,11 @@ of *graphs* — the paper's actual workload:
     count joins the batch key so a dispatch never mixes sharded and
     unsharded plans, and `warmup()` pre-traces every configured
     (shard count, bucket, tier) — mixed traffic replays warm.
-  * Batching — same-bucket requests are stacked with a leading batch dim
-    (`core.models.stack_operands`) and executed through the plan's vmapped
+  * Batching — same-bucket requests are stacked with a leading batch dim,
+    on the device, by one compiled program for the features and one for
+    the operands (`core.models.OperandStacker`: the whole operand set,
+    block structures and tier operands included, in one launch) and
+    executed through the plan's vmapped
     callable at a FIXED batch width; partial batches repeat a real request
     into the junk slots (dropped on output) so batch width never changes
     shape — the same trick as the LM server's empty decode slots. Batch
@@ -166,7 +169,8 @@ from repro.core.models import (FUSION_MODES, OPERAND_FIELDS, DeltaSpec,
                                PlanKey, ShardSlice, TierOperands,
                                build_agg_quantizer, build_block_compactor,
                                build_delta_patcher, build_materializer,
-                               build_operands, build_plan,
+                               build_operand_stacker, build_operands,
+                               build_plan,
                                build_sharded_operands, build_sharded_plan,
                                calibrate_tier, compact_operands,
                                derive_tier_operands, edge_operands,
@@ -175,7 +179,7 @@ from repro.core.models import (FUSION_MODES, OPERAND_FIELDS, DeltaSpec,
                                prepare_host_operands,
                                realize_operands, sharded_exchange_widths,
                                stack_operands, stack_shard_slices,
-                               stack_tier_operands, unshard_logits)
+                               unshard_logits)
 from repro.core.partition import (GraphShards, partition_for_ladder,
                                   patch_halo, transfer_cost)
 from repro.core.sparsity import (HBM_BW, MXU_RATE, block_stats,
@@ -491,9 +495,12 @@ class GraphServe:
         self._block_compactor = build_block_compactor()
         self._delta_patcher = build_delta_patcher()
         # the dispatch's device-side stack of its slots' resident features:
-        # one compiled program per (batch_slots, bucket, in_feats); and of
+        # one compiled program per (batch_slots, bucket, in_feats); of
+        # dense slots' operands (and tier operands), one per (batch_slots,
+        # bucket, fieldset, block structure, tier operands); and of
         # edge-form slots' operands, one per (batch_slots, bucket, rung)
         self._stack_x = jax.jit(jnp.stack)
+        self._stack_operands = build_operand_stacker()
         self._stack_edges = jax.jit(lambda ops: jax.tree_util.tree_map(
             lambda *a: jnp.stack(a), *ops))
         if self.sc.admission not in ("evict", "reject"):
@@ -854,10 +861,9 @@ class GraphServe:
                     single = self._materializer(compact_operands(pg, e.cfg))
                 else:
                     single = build_operands(pg, e.cfg, lean=True)
-                ops = stack_operands([single] * b)
                 x = self._stack_x([jnp.zeros((bucket, e.cfg.in_feats),
                                              jnp.float32)] * b)
-                ops_grasp = None
+                single_grasp = None
                 if self._grasp_capable(e):
                     # placeholder block structure at the bucket budget —
                     # these calls also warm the per-bucket block compactor
@@ -866,10 +872,11 @@ class GraphServe:
                     self._block_compactor.counts(single.norm_adj)
                     bsp, _ = self._block_compactor(
                         single.norm_adj, max_nnz=grasp_max_nnz(bucket))
-                    ops_grasp = stack_operands(
-                        [dataclasses.replace(single, block_sparse=bsp)] * b)
+                    single_grasp = dataclasses.replace(single,
+                                                       block_sparse=bsp)
                 for tier, t in e.tiers.items():
-                    backends = ("dense",) if (ops_grasp is None or t.quantgr
+                    backends = ("dense",) if (single_grasp is None
+                                              or t.quantgr
                                               ) else ("dense", "grasp")
                     for backend in backends:
                         for fusion in FUSION_MODES:
@@ -894,13 +901,14 @@ class GraphServe:
                             if self._needs_tier_ops(e, tier):
                                 # also warms the per-bucket tier-operand
                                 # deriver
-                                tops = stack_tier_operands(
-                                    [self._agg_quantizer(single.norm_adj)]
-                                    * b)
-                            out = plan(e.params, x,
-                                       ops_grasp if backend == "grasp"
-                                       else ops,
-                                       quant, tops)
+                                tops = [self._agg_quantizer(single.norm_adj)
+                                        ] * b
+                            # the dispatch's own operand stack, so each
+                            # shape it meets is traced here
+                            ops, tops = self._stack_operands(
+                                [single_grasp if backend == "grasp"
+                                 else single] * b, tops)
+                            out = plan(e.params, x, ops, quant, tops)
                             out.block_until_ready()
                 self._warm_delta(e, bucket, single, warmed)
         for shards in sorted({int(s) for s in self.sc.shard_counts
@@ -2182,7 +2190,11 @@ class GraphServe:
         repeat a real request so batch width never changes shape; their
         outputs are dropped. Every request arrives with its features on the
         device (cached per version, or put there in its host stage), so
-        nothing crosses the host→device link here. The dispatch is a
+        nothing crosses the host→device link here. The slots' features
+        and their operands are each stacked there by ONE compiled program
+        (`_stack_x`; `_stack_operands`, which also stacks the tier
+        operands, or `_stack_edges` for edge-form slots), so a dispatch
+        launches three programs: two stacks and the plan. The dispatch is a
         `dispatch` span tiled by four children (`dispatch.stack`,
         `.operands`, `.device`, `.d2h`; runtime/tracing.py), all under one
         dispatch serial.
@@ -2217,17 +2229,18 @@ class GraphServe:
             slots = batch + [batch[-1]] * (b - len(batch))
             e = self.models[head.model]
             # CacheG: r.x and r.ops are device-resident (cached, or built in
-            # the host stage), so both stacks are device-side concats
-            # (DESIGN.md §7)
+            # the host stage), so both stacks are device-side concats, one
+            # compiled program each (DESIGN.md §7)
             with span("dispatch.stack", self.clock, serial):
                 x = self._stack_x([r.x for r in slots])
             with span("dispatch.operands", self.clock, serial):
                 if head.backend == "edges":
-                    ops = self._stack_edges([r.ops for r in slots])
+                    ops, tops = self._stack_edges([r.ops for r in slots]), None
                 else:
-                    ops = stack_operands([r.ops for r in slots])
-                tops = (stack_tier_operands([r.tier_ops for r in slots])
-                        if slots[0].tier_ops is not None else None)
+                    ops, tops = self._stack_operands(
+                        [r.ops for r in slots],
+                        [r.tier_ops for r in slots]
+                        if head.tier_ops is not None else None)
             # an edges dispatch names the real nodes and edges it aggregates
             real = ({"nodes": sum(r.pg.num_nodes for r in batch),
                      "edges": sum(r.pg.edge_index.shape[1] for r in batch)}
@@ -2431,6 +2444,8 @@ class GraphServe:
         return {
             "requests": len(self.finished),
             "compiled_blobs": self.compiled_blobs,
+            # traces of the dispatch's operand stack; flat after warmup()
+            "operand_stack_traces": self._stack_operands.trace_count,
             "batches": self.metrics["batches"],
             "batch_occupancy": (self.metrics["slots_filled"]
                                 / max(self.metrics["slots_total"], 1)),
