@@ -7,7 +7,8 @@ Fig. 23 / energy — bytes-moved proxy (no power rails on CPU).
 Accuracy table — FP32 vs QuantGr vs GrAx accuracies per model.
 Serving — GraphServe engine throughput over mixed-size multi-graph traffic.
 CacheG — `operand_pipeline`: host→device operand bytes + per-query latency,
-eager dense uploads vs the device-resident operand cache (DESIGN.md §7).
+the SymG compact transfer vs a directed graph's eager dense upload, both
+cached on the device (DESIGN.md §7).
 Tiers — `quality_tiers`: per-tier (fp32 / int8 / int8+grax) latency, operand
 bytes, and accuracy delta through GraphServe (DESIGN.md §8).
 """
@@ -362,37 +363,45 @@ def serving_throughput(dataset: str = "cora", *, n_requests: int = 12,
 
 def operand_pipeline(dataset: str = "cora", *, cap: int = 2048,
                      n_queries: int = 6, seed: int = 0) -> List[Dict]:
-    """CacheG operand pipeline vs eager host-built operands (DESIGN.md §7).
+    """CacheG compact transfer vs the eager fallback (DESIGN.md §7).
 
-    Attaches ONE undirected graph at a `cap`-capacity rung and queries it
-    repeatedly with GAT — the worst eager case: every request rebuilds and
-    re-uploads two dense (cap, cap) float32 masks (2 x 16 MB at cap=2048).
-    CacheG uploads one SymG bit-packed adjacency on the first query (the
-    structure miss), materializes the masks on device, and serves every
-    later query from the device-resident cache: zero host operand builds,
-    zero operand bytes over the link. Reports bytes moved, hit/miss counts,
-    and per-query wall-clock for both paths; the paper's Fig. 21 scaling
-    argument (only activations cross DRAM) rests on exactly this pipeline.
+    Attaches one graph at a `cap`-capacity rung and queries it repeatedly
+    with GAT, twice: undirected ("cacheg"), and with one extra edge whose
+    reverse is absent ("eager"). The undirected graph uploads one SymG
+    bit-packed adjacency on its first query and materializes the two
+    dense (cap, cap) float32 masks on the device; the directed one cannot
+    take SymG, so its masks are built on the host and uploaded (2 x 16 MB
+    at cap=2048). Both are cached per structure version, so every later
+    query moves zero operand bytes either way. Reports bytes moved,
+    hit/miss counts, and per-query wall-clock for both; the paper's Fig. 21
+    scaling argument (only activations cross DRAM) rests on exactly this
+    pipeline.
     """
     import time as _time
 
-    from repro.core.graph import BucketLadder
+    from repro.core.graph import BucketLadder, Graph
     from repro.data.graphs import planetoid_like
     from repro.runtime.gnn_server import GraphServe, GraphServeConfig
 
     n = int(cap * 3 / 4)
     g = planetoid_like(num_nodes=n, num_edges=3 * n, num_feats=16,
                        num_classes=5, seed=seed, train_per_class=2)
+    adj = np.zeros((n, n), bool)
+    adj[g.edge_index[1], g.edge_index[0]] = True
+    i, j = np.argwhere(~adj & ~adj.T & ~np.eye(n, dtype=bool))[0]
+    directed = Graph(edge_index=np.concatenate(
+        [g.edge_index, np.array([[i], [j]], np.int32)], axis=1),
+        num_nodes=n, features=g.features)
     rows, stats = [], {}
-    for mode in ("eager", "cacheg"):
+    for mode, graph in (("eager", directed), ("cacheg", g)):
         sc = GraphServeConfig(ladder=BucketLadder(buckets=(cap,)),
-                              batch_slots=2, use_cacheg=(mode == "cacheg"))
+                              batch_slots=2)
         eng = GraphServe(sc, seed=seed)
         eng.register_model("gat", GNNConfig(kind="gat", in_feats=16,
                                             hidden=16, num_classes=5,
                                             heads=4))
         eng.warmup()
-        gid = eng.attach(g, model="gat")
+        gid = eng.attach(graph, model="gat")
         t0 = _time.perf_counter()
         for _ in range(n_queries):
             eng.query(gid)

@@ -46,7 +46,7 @@ from .graph import PaddedGraph
 from .layers import Techniques
 from .quant import QuantizedLinear, quantize_linear
 from .sparsity import (BlockSparse, block_counts, compact_block_sparse,
-                       pad_block_sparse, stack_block_sparse, to_block_sparse)
+                       stack_block_sparse, to_block_sparse)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -430,7 +430,7 @@ def build_materializer() -> OperandMaterializer:
 
 @dataclasses.dataclass
 class HostOperands:
-    """Product of the pipeline's HOST stage for one request (DESIGN.md §9).
+    """Product of the pipeline's HOST stage for one graph (DESIGN.md §9).
 
     The GraphSplit host work — padding aside (the caller pads), this is
     CompactOperands bit-packing or the eager dense build — is separable
@@ -441,77 +441,42 @@ class HostOperands:
     consumes. Exactly one of `compact` / `eager` is set; `nbytes` is the
     host→device operand traffic this form moves (the `operand_bytes_h2d`
     unit), and `fallback` marks a directed GCN/GAT graph that could not
-    take the SymG compact path (counted as `cacheg_fallbacks`).
-
-    `grasp` carries the GraSp block structure through the host stage when
-    the request resolved to the grasp backend AND the structure had to be
-    built host-side (`to_block_sparse` + `pad_block_sparse` — the eager
-    path, where the dense Â crosses the link anyway). On the compact path
-    it stays None: the engine derives the structure DEVICE-side from the
-    materialized Â (`BlockCompactor`, zero extra bytes — DESIGN.md §10).
+    take the SymG compact path (counted as `cacheg_fallbacks`). A GraSp
+    block structure is never part of it: the engine derives that on the
+    device from the realized Â (`BlockCompactor`, DESIGN.md §10).
     """
     compact: Optional[CompactOperands] = None
     eager: Optional[GranniteOperands] = None
-    grasp: Optional[BlockSparse] = None
     nbytes: int = 0
     fallback: bool = False
 
 
 def prepare_host_operands(pg: PaddedGraph, cfg: GNNConfig, *,
-                          use_cacheg: bool = True,
-                          rng: Optional[np.random.Generator] = None,
-                          grasp_max_nnz: Optional[int] = None,
-                          grasp_bitmap: Optional[np.ndarray] = None,
-                          symmetric: Optional[bool] = None
+                          rng: Optional[np.random.Generator] = None
                           ) -> HostOperands:
     """HOST stage of the operand pipeline: pack (CacheG) or build (eager).
 
-    Prefers the CacheG compact transfer form; directed GCN/GAT graphs
-    (SymG needs symmetry) and engines running with `use_cacheg=False` fall
-    back to the eager dense host build. No device work happens here — a
-    scheduler host worker can call this from any thread.
-
-    `grasp_max_nnz` marks a request the engine resolved to the GraSp
-    backend (DESIGN.md §10): the eager path then also compacts the block
-    structure here on the host (`to_block_sparse`, padded to the bucket
-    budget, its bytes counted in `nbytes` since they cross the link —
-    `grasp_bitmap`, when the backend rule already scanned this Â, skips
-    the compaction's own reduction pass); the compact path ignores it —
-    the structure is derived device-side from the materialized Â, which
-    is the whole point of caching it. `symmetric` short-circuits the
-    O(cap²) symmetry check when the caller already ran it on this
-    adjacency (one scan per request, not two).
+    SAGE and undirected GCN/GAT graphs take the CacheG compact transfer
+    form; directed GCN/GAT graphs (SymG needs symmetry) fall back to the
+    eager dense host build. No device work happens here — a scheduler host
+    worker can call this from any thread.
     """
     from .graph import is_symmetric_adjacency
-    if use_cacheg and (cfg.kind == "sage"
-                       or (symmetric if symmetric is not None
-                           else is_symmetric_adjacency(pg.adj))):
+    if cfg.kind == "sage" or is_symmetric_adjacency(pg.adj):
         co = compact_operands(pg, cfg, rng=rng, check_symmetry=False)
         return HostOperands(compact=co, nbytes=co.nbytes)
     ops = build_operands(pg, cfg, lean=True, rng=rng)
-    grasp = None
-    nbytes = operand_nbytes(ops)
-    if grasp_max_nnz is not None and cfg.kind == "gcn":
-        grasp = pad_block_sparse(
-            to_block_sparse(pg.norm_adj, bitmap=grasp_bitmap), grasp_max_nnz)
-        nbytes += grasp.nbytes
-    return HostOperands(eager=ops, grasp=grasp, nbytes=nbytes,
-                        fallback=use_cacheg)
+    return HostOperands(eager=ops, nbytes=operand_nbytes(ops), fallback=True)
 
 
 def realize_operands(ho: HostOperands,
                      materializer: OperandMaterializer) -> GranniteOperands:
     """DEVICE stage counterpart: expand the host product into the dense
     operand set (a jitted materializer call for the compact form, identity
-    for the eager fallback), attaching the host-built GraSp structure when
-    the host stage carried one. Dispatch is async under jax, so a host
-    worker calling this merely *enqueues* device work — the dense arrays
-    are created in device memory either way."""
-    if ho.compact is not None:
-        return materializer(ho.compact)
-    if ho.grasp is not None:
-        return dataclasses.replace(ho.eager, block_sparse=ho.grasp)
-    return ho.eager
+    for the eager fallback). Dispatch is async under jax, so a host worker
+    calling this merely *enqueues* device work — the dense arrays are
+    created in device memory either way."""
+    return materializer(ho.compact) if ho.compact is not None else ho.eager
 
 
 @dataclasses.dataclass
@@ -578,10 +543,9 @@ def forward_edges(params: Dict, cfg: GNNConfig, x: jnp.ndarray,
 
 
 def operand_nbytes(ops: GranniteOperands) -> int:
-    """Host→device bytes of one eagerly built operand set (the five dense
-    fields; a GraSp structure's bytes are accounted where it is built —
-    `prepare_host_operands` on the eager path, zero on the device-derived
-    path — and offline QuantGr never takes the batched serve path).
+    """Host→device bytes of one eagerly built operand set: the five dense
+    fields (a GraSp structure is derived on the device, and offline QuantGr
+    never takes the batched serve path).
     Reads `.nbytes` (both jnp and np expose it) — no device→host copy."""
     return int(sum(f.nbytes for f in (
         ops.norm_adj, ops.mask_mult, ops.bias_add, ops.sample_mask,
@@ -1189,11 +1153,17 @@ class ShardSlice:
 
     The serving CacheG unit for sharded graphs: cached per
     (graph_id, structure_version, shard) and stacked along a leading shard
-    axis at dispatch (`stack_shard_slices`).
+    axis at dispatch (`stack_shard_slices`). A registered pytree, so a
+    cached slice tuple's device bytes are its leaves'.
     """
     x: jnp.ndarray              # (shard_cap, F) this shard's feature rows
     ops: GranniteOperands       # kind fields (shard_cap, full_rows); holes (1,1)
     node_mask: jnp.ndarray      # (shard_cap,) 1.0 real / 0.0 padding
+
+
+jax.tree_util.register_pytree_node(
+    ShardSlice, lambda s: ((s.x, s.ops, s.node_mask), None),
+    lambda _, c: ShardSlice(*c))
 
 
 def build_sharded_operands(g, part, cfg: GNNConfig, *,

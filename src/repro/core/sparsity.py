@@ -15,9 +15,10 @@ Serving contract (DESIGN.md §10): `BlockSparse` is a registered pytree, so
 a compacted structure rides `GranniteOperands` across jit/vmap boundaries
 as a runtime argument. To make that SHAPE-STABLE per NodePad bucket, every
 serving-path structure is padded to the bucket's `grasp_max_nnz` budget —
-`pad_block_sparse` on the host, `compact_block_sparse` (pure jnp, jitted
-per bucket) when the fp32 Â is already device-resident — and same-bucket
-structures stack into one batched operand (`stack_block_sparse`).
+derived on the device from the realized fp32 Â by `compact_block_sparse`
+(pure jnp, jitted per bucket; `to_block_sparse` + `pad_block_sparse` are
+its host reference) — and same-bucket structures stack into one batched
+operand (`stack_block_sparse`).
 `select_agg_backend` is the density/cost rule (same modelled-latency style
 as `partition.py` / `benchmarks/tpu_model.py`) that decides, per graph and
 bucket, whether the batched `bitmap_spmm` dispatch beats the dense matmul.
@@ -112,19 +113,17 @@ jax.tree_util.register_pytree_node(
     lambda aux, ch: BlockSparse(*ch, *aux))
 
 
-def to_block_sparse(a: np.ndarray, *, block_size: int = MXU_TILE,
-                    bitmap: np.ndarray = None) -> BlockSparse:
-    """Host-side block compaction. `bitmap` short-circuits the O(n·m)
-    non-zero reduction when the caller already ran `block_stats` on this
-    matrix (the serving backend rule does — one scan, not two)."""
+def to_block_sparse(a: np.ndarray, *, block_size: int = MXU_TILE
+                    ) -> BlockSparse:
+    """Host-side block compaction: the reference the device-side
+    `compact_block_sparse` is tested against."""
     n, m = a.shape
     bs = block_size
     if n % bs or m % bs:
         raise ValueError(f"shape {a.shape} not a multiple of block {bs} (NodePad first)")
     rb, cb = n // bs, m // bs
     view = a.reshape(rb, bs, cb, bs).transpose(0, 2, 1, 3)  # (rb, cb, bs, bs)
-    if bitmap is None:
-        bitmap = (np.abs(view).sum(axis=(2, 3)) > 0).astype(np.uint8)
+    bitmap = (np.abs(view).sum(axis=(2, 3)) > 0).astype(np.uint8)
     counts = bitmap.sum(axis=1).astype(np.int32)
     max_nnz = max(int(counts.max()), 1)
     # Pad each block-row's list to max_nnz; gather the blocks densely so the

@@ -16,13 +16,14 @@ counts. This module bounds it:
     across a key's entries — so a hot derived form keeps its primary
     resident), derived entries before the primary they hang off
     (`KIND_RANK`), cheapest re-materialization first among peers;
-  * evicted primaries optionally spill to a host-RAM compact form (the
-    SymG bit-packed `HostOperands`, ~64x smaller than the dense operand)
-    produced by the entry's `spill_fn` at eviction time; a later fault
-    re-materializes from the spilled form instead of re-running the host
-    build. Entries whose `spill_fn` is None or declines (directed graphs,
-    sharded slices — their host source survives in the engine registry)
-    are dropped instead. Conservation: evictions == spilled + dropped.
+  * evicted primaries optionally spill to a host-RAM compact form (a
+    `HostOperands` holding the SymG bit-packed `CompactOperands`, ~64x
+    smaller than the dense operand) produced by the entry's `spill_fn` at
+    eviction time; a later fault re-materializes from the spilled form
+    instead of re-running the host build. Entries whose `spill_fn` is None
+    or declines (directed graphs, which have no compact form; sharded
+    slices, whose host source survives in the engine registry) are
+    dropped instead. Conservation: evictions == spilled + dropped.
 
 Lifecycle vs capacity: `invalidate()` (update/detach removing a dead
 version) is NOT an eviction — it touches no counter, so the eviction

@@ -16,15 +16,17 @@ of *graphs* — the paper's actual workload:
   * GraphSplit — padding, PreG normalization, and mask construction happen
     on the host at submit/update time; the device executes one dense,
     statically-shaped, vmapped forward per batch. With `shard_counts`
-    configured the split also goes multi-device (DESIGN.md §12): a graph
-    too large for the TOP ladder bucket no longer errors out of `attach()`
-    — the engine partitions it N-way (`core.partition.partition_for_ladder`,
-    greedy edge-cut under a per-shard bucket cap) and serves it through a
-    sharded plan: per-shard aggregate+combine under a shard axis, the halo
-    exchanged per layer as an int8-compressed psum (`dist.compress` —
-    QuantGr applied to the wire). Sharded dispatches are width-1 (the
-    shard axis occupies the leading dim a batch would use), the shard
-    count joins the batch key so a dispatch never mixes sharded and
+    configured the split also goes multi-device (DESIGN.md §12, §15): a
+    graph too large for the TOP ladder bucket no longer errors out of
+    `attach()` — the engine partitions it N-way
+    (`core.partition.partition_for_ladder`, multilevel coarsen + KL/FM
+    refinement under a per-shard bucket cap, `partition_method`) and serves
+    it through a sharded plan: per-shard aggregate+combine under a shard
+    axis, the halo exchanged per layer as an int8-compressed psum
+    (`dist.compress` — QuantGr applied to the wire). A sharded dispatch
+    carries up to `replica_groups` requests, one per replica row of the
+    mesh (the shard axis occupies the leading dim a batch would use), the
+    shard count joins the batch key so a dispatch never mixes sharded and
     unsharded plans, and `warmup()` pre-traces every configured
     (shard count, bucket, tier) — mixed traffic replays warm.
   * Batching — same-bucket requests are stacked with a leading batch dim,
@@ -55,7 +57,10 @@ of *graphs* — the paper's actual workload:
     stacks device-resident buffers. `update()` bumps the version and
     re-materializes once.
     Directed GCN/GAT graphs fall back to the eager dense upload (counted as
-    `cacheg_fallbacks`) — same plans, no extra traces.
+    `cacheg_fallbacks`), cached per version the same way — same plans, no
+    extra traces. One routine (`_operand_set`) decides every unsharded
+    request's operands, tier operands and agg backend: through the cache
+    for an attached graph, uncached for a one-shot submit.
   * GraSp agg backends (DESIGN.md §10) — every request's aggregation
     dispatches through one of two backends: `dense` (one matmul over the
     full padded Â) or `grasp` (the block-sparse `bitmap_spmm` kernel over
@@ -63,10 +68,11 @@ of *graphs* — the paper's actual workload:
     A model registered with `agg_backend="auto"` routes each graph by the
     modelled density/cost rule (`core.sparsity.select_agg_backend`);
     `"grasp"` forces the sparse path where eligible. The block structure
-    is DERIVED state like the int8 Â: computed device-side from the cached
-    fp32 Â once per (graph_id, structure_version) (`BlockCompactor`),
-    host-built (`to_block_sparse`) only on the eager fallback path. The
-    backend joins the batch key, so a dispatch never mixes backends, and
+    is DERIVED state like the int8 Â: computed device-side from the
+    realized fp32 Â (`BlockCompactor`) — once per (graph_id,
+    structure_version) for an attached graph, once per request for a
+    one-shot one — and never crosses the link. The backend joins the batch
+    key, so a dispatch never mixes backends, and
     warmup pre-traces BOTH backends' plans — mixed dense/grasp traffic
     replays warm. Every REQUEST whose grasp intent could not run the skip
     grid (forced-but-ineligible structure, or the kernel routing's dense
@@ -170,10 +176,9 @@ from repro.core.models import (FUSION_MODES, OPERAND_FIELDS, DeltaSpec,
                                build_agg_quantizer, build_block_compactor,
                                build_delta_patcher, build_materializer,
                                build_operand_stacker, build_operands,
-                               build_plan,
-                               build_sharded_operands, build_sharded_plan,
-                               calibrate_tier, compact_operands,
-                               derive_tier_operands, edge_operands,
+                               build_plan, build_sharded_operands,
+                               build_sharded_plan, calibrate_tier,
+                               compact_operands, edge_operands,
                                EdgeOperands, forward_grannite,
                                has_edge_form, init_params, layer_widths,
                                prepare_host_operands,
@@ -182,8 +187,8 @@ from repro.core.models import (FUSION_MODES, OPERAND_FIELDS, DeltaSpec,
                                unshard_logits)
 from repro.core.partition import (GraphShards, partition_for_ladder,
                                   patch_halo, transfer_cost)
-from repro.core.sparsity import (HBM_BW, MXU_RATE, block_stats,
-                                 grasp_max_nnz, select_agg_backend)
+from repro.core.sparsity import (HBM_BW, MXU_RATE, grasp_max_nnz,
+                                 select_agg_backend)
 from repro.dist.compress import ring_psum_nbytes
 from repro.runtime.cache import (CacheAdmissionError, DeviceCacheManager,
                                  estimate_dense_entry_bytes,
@@ -419,8 +424,6 @@ class GraphServeConfig:
     ladder: BucketLadder = dataclasses.field(default_factory=BucketLadder)
     batch_slots: int = 4                   # fixed batch width per dispatch
     return_logits: bool = False
-    use_cacheg: bool = True                # CacheG operand pipeline (§7);
-    # False = eager host-built dense operands uploaded per request
     shard_counts: Tuple[int, ...] = ()     # §12: shard counts attach() may
     # auto-shard an over-ladder graph across; () keeps sharding disabled
     # (oversized graphs raise, exactly the pre-§12 behavior)
@@ -569,25 +572,6 @@ class GraphServe:
         with self._lock:
             self.metrics[name] += delta
 
-    # ----------------------------------------------------- cache compat views
-    # (snapshot views of the §13 cache manager in the plain-dict shape the
-    # four caches had before it — tests and diagnostics read these)
-    @property
-    def _operand_cache(self) -> Dict[Tuple[int, int], GranniteOperands]:
-        return self._cache.view("operand")
-
-    @property
-    def _tier_operand_cache(self) -> Dict[Tuple[int, int], TierOperands]:
-        return self._cache.view("tier")
-
-    @property
-    def _grasp_cache(self) -> Dict[Tuple[int, int], Tuple[str, object]]:
-        return self._cache.view("grasp")
-
-    @property
-    def _shard_cache(self) -> Dict[Tuple[int, int], Tuple[ShardSlice, ...]]:
-        return self._cache.view("shard")
-
     # ------------------------------------------------------- cache cost model
     def _projected_primary_bytes(self, model: str, pg: PaddedGraph,
                                  part: Optional[GraphShards]) -> int:
@@ -609,13 +593,7 @@ class GraphServe:
         return (estimate_dense_entry_bytes(nf, pg.capacity)
                 + pg.capacity * cfg.in_feats * 4)
 
-    @staticmethod
-    def _shard_entry_nbytes(slices: Tuple[ShardSlice, ...]) -> int:
-        """Measured device bytes of a sharded slice-tuple entry (ShardSlice
-        is a plain dataclass, not a pytree — sum its array members)."""
-        return sum(pytree_nbytes((s.x, s.ops, s.node_mask)) for s in slices)
-
-    def _operand_spill_fn(self, graph_id: int, ver: int, model: str):
+    def _operand_spill_fn(self, key: Tuple[int, int]):
         """Eviction-time producer of the §13 host-RAM spill form: re-packs
         the CacheG compact `HostOperands` from the graph's CURRENT host
         snapshot (SymG bit-packed, ~64x smaller than the dense fp32 entry;
@@ -625,15 +603,14 @@ class GraphServe:
         and the next miss runs the full build — when the version moved on,
         the graph detached or went sharded, or the pack fell back to the
         eager dense form (directed structure: nothing compact to keep)."""
+        graph_id, ver = key
+
         def _spill():
             if (self._graph_version.get(graph_id) != ver
                     or graph_id in self._sharded):
                 return None
-            entry = self.graphs.get(graph_id)
-            if entry is None:
-                return None
-            ho = prepare_host_operands(entry[1], self.models[model].cfg,
-                                       use_cacheg=True)
+            model, pg = self.graphs[graph_id]
+            ho = prepare_host_operands(pg, self.models[model].cfg)
             return None if ho.fallback else ho
         return _spill
 
@@ -806,8 +783,8 @@ class GraphServe:
                 + self._delta_patcher.trace_count)
 
     def warmup(self, *, buckets: Optional[Tuple[int, ...]] = None) -> int:
-        """Compile every (model, bucket, tier, backend, fusion) plan — and,
-        with CacheG enabled, every (bucket, fieldset) materializer — once
+        """Compile every (model, bucket, tier, backend, fusion) plan — and
+        every (bucket, fieldset) CacheG materializer — once
         with placeholder inputs. BOTH fusion modes warm per (tier,
         backend): fusion is a per-request plan dimension (DESIGN.md §11),
         so mixed fused/unfused traffic must replay warm exactly like mixed
@@ -857,10 +834,7 @@ class GraphServe:
                 pg = dataclasses.replace(
                     empty, features=np.zeros((bucket, e.cfg.in_feats),
                                              np.float32))
-                if self.sc.use_cacheg:
-                    single = self._materializer(compact_operands(pg, e.cfg))
-                else:
-                    single = build_operands(pg, e.cfg, lean=True)
+                single = self._materializer(compact_operands(pg, e.cfg))
                 x = self._stack_x([jnp.zeros((bucket, e.cfg.in_feats),
                                              jnp.float32)] * b)
                 single_grasp = None
@@ -1016,8 +990,7 @@ class GraphServe:
         """Warm the GrAd delta patcher for one (bucket, model): the operand
         patch trace per fieldset, plus the tier row-requant trace when a
         QuantGr GCN tier will keep a derived int8 Â to patch."""
-        if (self.sc.delta_pad_rows <= 0 or not self.sc.use_cacheg
-                or e.cfg.kind not in ("gcn", "gat")):
+        if self.sc.delta_pad_rows <= 0 or e.cfg.kind not in ("gcn", "gat"):
             return
         fields = OPERAND_FIELDS[e.cfg.kind]
         if ("delta", bucket, fields) not in warmed:
@@ -1233,8 +1206,8 @@ class GraphServe:
 
     def _derive_grasp(self, e: _ModelEntry, capacity: int, norm_adj
                       ) -> Tuple[str, object]:
-        """Counts-first device-side derivation shared by the cached query
-        path and one-shot compact submits: one cheap jitted bitmap
+        """Counts-first device-side derivation of `_operand_set`, for
+        attached and one-shot graphs alike: one cheap jitted bitmap
         reduction feeds the backend rule, and ONLY a grasp-routed graph
         pays the full block gather — so eligibility is always judged
         against the exact (materialized) Â the gather would read, and a
@@ -1310,75 +1283,96 @@ class GraphServe:
         self._count("operand_bytes_h2d", pytree_nbytes(eo))
         return eo
 
-    def _put_edges(self, graph_id: int, ver: int,
-                   eo: EdgeOperands) -> None:
-        """Cache one version's edge operands, if that version is still
-        current. No spill form: a miss rebuilds from the edge list the
-        engine keeps."""
-        nb = pytree_nbytes(eo)
-        with self._lock:
-            if self._graph_version.get(graph_id) == ver:
-                self._cache.put("edges", (graph_id, ver), eo, nbytes=nb,
-                                remat_s=transfer_cost(nb))
-
-    def _resolve_and_build(self, model: str, tier: str, pg: PaddedGraph
-                           ) -> Tuple[str, GranniteOperands]:
-        """One-shot intake: resolve this request's agg backend AND build
-        its device-resident operands, deriving the backend rule's inputs
-        wherever they are cheapest. QuantGr tiers aggregate through the
-        cached int8 Â, not the fp32 matmul, so they always resolve dense
-        without any scan. On the CacheG compact path the decision comes
-        from the jitted counts reduction over the MATERIALIZED Â — no
-        host O(cap²) pass, and eligibility is checked against the exact
-        matrix the block gather reads (same discipline as prepare_query).
-        The eager path decides from host-side `block_stats`, whose bitmap
-        the host block build then reuses instead of re-scanning."""
-        e = self.models[model]
-        if not pg.dense:
-            return "edges", self._edge_operands(None, pg)
-        if not self._grasp_capable(e) or e.tiers[tier].quantgr:
-            return "dense", self._device_operands(model, pg)
-        from repro.core.graph import is_symmetric_adjacency
-        if self.sc.use_cacheg and is_symmetric_adjacency(pg.adj):
-            # compact + materialize (symmetry already checked, scan once)
-            ops = self._device_operands(model, pg, symmetric=True)
-            backend, bsp = self._derive_grasp(e, pg.capacity, ops.norm_adj)
-            self._count_forced_fallback(e, backend)
-            if backend == "grasp":
-                ops = dataclasses.replace(ops, block_sparse=bsp)
-            return backend, ops
-        stats = block_stats(pg.norm_adj)
-        backend = self._backend_from_stats(e, pg.capacity, stats)
-        self._count_forced_fallback(e, backend)
-        return backend, self._device_operands(
-            model, pg, backend=backend, grasp_bitmap=stats["bitmap"],
-            symmetric=False if self.sc.use_cacheg else None)
-
     # ------------------------------------------------------------------ intake
-    def _device_operands(self, model: str, pg: PaddedGraph, *,
-                         backend: str = "dense", grasp_bitmap=None,
-                         symmetric: Optional[bool] = None
+    def _resident(self, kind: str, key: Optional[Tuple[int, int]], build,
+                  *, count: bool = False):
+        """One cached form of an attached graph (§7, §13): the entry of
+        `kind` at `key` = (graph_id, structure_version), or `build()` run
+        OUTSIDE the engine lock and inserted only if that version is still
+        current — a request racing an `update()` serves the snapshot it
+        read, and a stale build never pins device memory under a dead key.
+        Two workers missing one key may both build; the values are
+        identical. An evicted operand entry faults back from its host-RAM
+        spill form (`cache_spill_hits`, compact bytes over the link again,
+        no host packing), which is not a miss; only the operand kind spills
+        — a slice tuple or an edge list re-derives from the engine's own
+        registry. `count` feeds a primary's hits and misses to
+        `operand_cache_hits`/`_misses`. With `key=None` (a one-shot
+        request) it builds and caches nothing."""
+        if key is None:
+            return build()
+        with self._lock:
+            value = self._cache.get(kind, key)
+            if value is not None:
+                if count:
+                    self.metrics["operand_cache_hits"] += 1
+                return value
+            spilled = self._cache.spill_get(kind, key)
+        if spilled is not None:
+            self._count("cache_spill_hits")
+            self._count("operand_bytes_h2d", spilled.nbytes)
+            value = realize_operands(spilled, self._materializer)
+        else:
+            if count:
+                self._count("operand_cache_misses")
+            value = build()
+        nb = pytree_nbytes(value)
+        with self._lock:
+            if self._graph_version.get(key[0]) == key[1]:
+                # a derived form re-derives on the device from its primary
+                # (which its insert never evicts: the manager protects the
+                # inserted key); the rest cross the link again
+                self._cache.put(
+                    kind, key, value, nbytes=nb,
+                    remat_s=(0.0 if kind in ("tier", "grasp")
+                             else transfer_cost(nb)),
+                    spill_fn=(self._operand_spill_fn(key)
+                              if kind == "operand" else None))
+        return value
+
+    def _operand_set(self, e: _ModelEntry, pg: PaddedGraph, tier: str,
+                     key: Optional[Tuple[int, int]] = None
+                     ) -> Tuple[str, object, Optional[TierOperands]]:
+        """The one place an unsharded request's operands are decided:
+        `(backend, ops, tier_ops)` for a request served at `tier`. An
+        edge-form graph (§16) takes its `EdgeOperands`. A dense graph takes
+        its fp32 operands (`_device_operands`), the int8 Â a QuantGr GCN
+        tier reads (§8), and its agg backend with the block structure a
+        grasp-routed graph reads (§10), both derived on the device from
+        the fp32 Â. With `key`, an attached graph's (graph_id, version),
+        every form is cached once per version (`_resident`); a one-shot
+        request (`key=None`) builds them for itself alone."""
+        if not pg.dense:
+            gid = key[0] if key is not None else None
+            return "edges", self._resident(
+                "edges", key, lambda: self._edge_operands(gid, pg),
+                count=True), None
+        ops = self._resident("operand", key,
+                             lambda: self._device_operands(e.cfg, pg),
+                             count=True)
+        tops = None
+        if self._needs_tier_ops(e, tier):
+            tops = self._resident("tier", key,
+                                  lambda: self._agg_quantizer(ops.norm_adj))
+        backend = "dense"
+        if self._grasp_capable(e) and not e.tiers[tier].quantgr:
+            backend, bsp = self._resident(
+                "grasp", key,
+                lambda: self._derive_grasp(e, pg.capacity, ops.norm_adj))
+            self._count_forced_fallback(e, backend)   # per request, cached
+            if backend == "grasp":                    # decision or not
+                ops = dataclasses.replace(ops, block_sparse=bsp)
+        return backend, ops, tops
+
+    def _device_operands(self, cfg: GNNConfig, pg: PaddedGraph
                          ) -> GranniteOperands:
         """Build one graph's device-resident operands: the HOST stage
         (`prepare_host_operands` — CacheG compact packing, or the eager
         dense build for directed GCN/GAT graphs, counted as
         `cacheg_fallbacks`) followed immediately by the DEVICE stage
         (`realize_operands`). The pipeline scheduler runs the same two
-        calls, just on a host worker thread.
-
-        A grasp-backend request on the eager host path additionally
-        builds and ships the block structure here (`HostOperands.grasp`,
-        bytes counted, DESIGN.md §10). Compact-path grasp derivation does
-        NOT happen here: the callers that own it (`_resolve_and_build`,
-        `prepare_query`) run the device-side counts check first, so no
-        structure is ever gathered without its eligibility verified
-        against the same materialized Â."""
-        budget = grasp_max_nnz(pg.capacity) if backend == "grasp" else None
-        ho = prepare_host_operands(pg, self.models[model].cfg,
-                                   use_cacheg=self.sc.use_cacheg,
-                                   grasp_max_nnz=budget,
-                                   grasp_bitmap=grasp_bitmap,
-                                   symmetric=symmetric)
+        calls, just on a host worker thread."""
+        ho = prepare_host_operands(pg, cfg)
         self._count("operand_bytes_h2d", ho.nbytes)
         if ho.fallback:
             self._count("cacheg_fallbacks")
@@ -1387,54 +1381,35 @@ class GraphServe:
     def _device_features(self, pg: PaddedGraph) -> jnp.ndarray:
         """Put one graph's padded (cap, F) features on the device, counted
         in `feature_bytes_h2d`: once per CacheG miss of an attached graph,
-        once per one-shot or non-CacheG request, always in the host stage."""
+        once per one-shot request, always in the host stage."""
         x = jnp.asarray(pg.features)
         self._count("feature_bytes_h2d", x.nbytes)
         return x
 
-    def _prepare(self, model: str, pg: PaddedGraph,
-                 ops: Optional[GranniteOperands] = None, *,
-                 x: Optional[jnp.ndarray] = None,
-                 tier: Optional[str] = None,
-                 tier_ops: Optional[TierOperands] = None,
-                 tier_resolved: bool = False,
-                 backend: Optional[str] = None,
+    def _prepare(self, model: str, pg: PaddedGraph, tier: str, backend: str,
+                 ops, tier_ops: Optional[TierOperands],
+                 x: Optional[jnp.ndarray], *,
                  fusion: Optional[str] = None,
                  submitted_s: Optional[float] = None,
                  deadline_ms: Optional[float] = None,
-                 tolerance: Optional[float] = None) -> GNNRequest:
-        """Host-stage tail shared by every intake path: resolve the tier
-        (router-aware, §14), agg backend, and fusion mode, realize
-        operands and put the features on the device if the caller didn't,
-        assign the uid. Returns the ready-to-dispatch request WITHOUT
-        touching the engine queue — the sync path pushes it (`_push`), the
-        pipeline
-        scheduler hands it to its own ready stage. `submitted_s` lets the
-        scheduler pin latency accounting to intake time (queue wait
-        included) rather than to host-stage completion; `deadline_ms` is
-        RELATIVE to that same submit instant, so queue wait spends the
-        budget."""
+                 tolerance: Optional[float] = None,
+                 bucket: Optional[int] = None, **sharded) -> GNNRequest:
+        """Host-stage tail shared by every intake path, once the served
+        tier, backend, operands and features are resolved: resolve the
+        fusion mode and assign the uid. Returns the ready-to-dispatch
+        request WITHOUT touching the engine queue — the sync path pushes it
+        (`_push`), the pipeline scheduler hands it to its own ready stage.
+        `submitted_s` lets the scheduler pin latency accounting to intake
+        time (queue wait included) rather than to host-stage completion;
+        `deadline_ms` is RELATIVE to that same submit instant, so queue
+        wait spends the budget. A sharded request passes its shard
+        `bucket` and the rest of its calling convention (`sharded`)."""
         now = self.clock.now()
         submitted_s = submitted_s if submitted_s is not None else now
-        if not tier_resolved:
-            tier = self._route_tier(model, tier, tolerance, pg.capacity)
         fusion = self._resolve_fusion(model, fusion)
         if not pg.dense and fusion != "none":
             raise ValueError("edge-list graphs serve fusion='none' only "
                              "(DESIGN.md §16)")
-        if backend is None:
-            backend, ops = self._resolve_and_build(model, tier, pg)
-        elif ops is None:
-            # a resolved backend implies the caller owned the grasp
-            # derivation discipline (counts-checked structure attached for
-            # grasp, none for dense) — building operands here would skip it
-            raise ValueError("callers resolving the backend themselves "
-                             "must pass the operands they derived it for")
-        if tier_ops is None and self._needs_tier_ops(self.models[model], tier):
-            # one-shot request: derive without caching (nothing to key on)
-            tier_ops = self._agg_quantizer(ops.norm_adj)
-        if x is None:
-            x = self._device_features(pg)
         with self._lock:
             uid = self._uid
             self._uid += 1
@@ -1443,10 +1418,11 @@ class GraphServe:
         deadline_s = (submitted_s + deadline_ms * 1e-3
                       if deadline_ms is not None else None)
         return GNNRequest(uid=uid, model=model, pg=pg, ops=ops,
-                          bucket=pg.capacity, submitted_s=submitted_s, x=x,
-                          tier=tier, backend=backend, fusion=fusion,
-                          tier_ops=tier_ops, deadline_s=deadline_s,
-                          tolerance=tolerance)
+                          bucket=bucket or pg.capacity,
+                          submitted_s=submitted_s, x=x, tier=tier,
+                          backend=backend, fusion=fusion, tier_ops=tier_ops,
+                          deadline_s=deadline_s, tolerance=tolerance,
+                          **sharded)
 
     def _push(self, req: GNNRequest) -> int:
         self.queue.append(req)
@@ -1459,10 +1435,17 @@ class GraphServe:
                        deadline_ms: Optional[float] = None,
                        tolerance: Optional[float] = None) -> GNNRequest:
         """HOST stage of a one-shot request: NodePad padding + operand
-        build/packing. Scheduler-callable from any worker thread."""
-        return self._prepare(model, self._pad(model, g), tier=tier,
-                             fusion=fusion, submitted_s=submitted_s,
-                             deadline_ms=deadline_ms, tolerance=tolerance)
+        build/packing, nothing cached. Scheduler-callable from any worker
+        thread."""
+        if submitted_s is None:         # the host stage counts as latency
+            submitted_s = self.clock.now()
+        pg = self._pad(model, g)
+        tier = self._route_tier(model, tier, tolerance, pg.capacity)
+        return self._prepare(model, pg, tier,
+                             *self._operand_set(self.models[model], pg, tier),
+                             self._device_features(pg), fusion=fusion,
+                             submitted_s=submitted_s, deadline_ms=deadline_ms,
+                             tolerance=tolerance)
 
     def submit(self, g: Graph, *, model: str,
                tier: Optional[str] = None,
@@ -1541,7 +1524,8 @@ class GraphServe:
         if not pg.dense:
             # the host work of the edge form happens here, not in the
             # first query's host stage
-            self._put_edges(gid, 0, self._edge_operands(gid, pg))
+            self._resident("edges", (gid, 0),
+                           lambda: self._edge_operands(gid, pg))
         return gid
 
     def detach(self, graph_id: int) -> None:
@@ -1841,11 +1825,9 @@ class GraphServe:
                 self._cache.invalidate(old_key)
                 self._graph_version[graph_id] = ver + 1
                 if new_slices is not None:
-                    self._cache.put(
-                        "shard", new_key, new_slices,
-                        nbytes=self._shard_entry_nbytes(new_slices),
-                        remat_s=transfer_cost(
-                            self._shard_entry_nbytes(new_slices)))
+                    nb = pytree_nbytes(new_slices)
+                    self._cache.put("shard", new_key, new_slices, nbytes=nb,
+                                    remat_s=transfer_cost(nb))
                 self.metrics["delta_updates"] += 1
                 self.metrics["delta_halo_bytes_exchanged"] += delta_bytes
                 self.metrics["delta_halo_bytes_full"] += full_bytes
@@ -1859,7 +1841,7 @@ class GraphServe:
             # buffer moves to the new key
             x_old = self._cache.get("features", old_key)
         new_ops = new_tops = new_grasp = None
-        if self.sc.use_cacheg and ops_old is not None:
+        if ops_old is not None:
             fields = OPERAND_FIELDS[e.cfg.kind]
             spec = self._delta_spec(pg.capacity, fields, delta.flip_i,
                                     delta.flip_j, delta.flip_v,
@@ -1889,8 +1871,7 @@ class GraphServe:
                 self._cache.put(
                     "operand", new_key, new_ops, nbytes=nb,
                     remat_s=transfer_cost(nb),
-                    spill_fn=self._operand_spill_fn(graph_id, ver + 1,
-                                                    model))
+                    spill_fn=self._operand_spill_fn(new_key))
             if new_tops is not None:
                 self._cache.put("tier", new_key, new_tops,
                                 nbytes=pytree_nbytes(new_tops))
@@ -1917,26 +1898,20 @@ class GraphServe:
         device-resident cache — zero host-side operand construction, zero
         operand or feature bytes over the link (the padded features are
         cached beside the operands, put on the device once per version;
-        `update()` is what changes them). The cache keys carry NO tier: the
-        same fp32 operands feed every tier's plan, and the int8 Â that
-        QuantGr GCN tiers read is quantized from them once per structure
-        version into the tier cache below — so mixed-tier traffic over one
-        graph shares one entry of each. The GraSp structure is the third
-        derived form (DESIGN.md §10): the backend rule runs once per
-        (graph, version) over counts the block compactor derives from the
-        CACHED Â — device-side, zero extra host→device bytes — and both
-        the decision and (when grasp) the budget-padded structure are
-        cached under the same key, invalidated by the same `update()`
-        bump, released by the same `detach()`.
+        `update()` is what changes them). The operand set is
+        `_operand_set`'s, the same routine a one-shot submit runs, here
+        keyed by (graph_id, version): the fp32 operands, and the forms
+        DERIVED from them once per version — the int8 Â QuantGr GCN tiers
+        read and the GraSp decision with its block structure (DESIGN.md
+        §10). The keys carry NO tier, so mixed-tier traffic over one graph
+        shares one entry of each; `update()` invalidates them all, and
+        `detach()` releases them.
 
         Thread discipline (the scheduler calls this from host workers while
         `update()` may arrive concurrently): the (model, pg, version)
-        triple is snapshotted under the engine lock, operands are built
-        OUTSIDE it, and a built entry is inserted only if the version is
-        still current — a request racing an update serves the snapshot it
-        read, and a stale build can never pin dead device memory under an
-        unreachable key. Two workers missing the same key may both build
-        (both counted as misses); last insert wins, values are identical.
+        triple is snapshotted under the engine lock, and every form is
+        built outside it and inserted only if the version is still current
+        (`_resident`).
         """
         with self._lock:
             model, pg = self.graphs[graph_id]
@@ -1954,107 +1929,13 @@ class GraphServe:
                                          deadline_ms=deadline_ms,
                                          tolerance=tolerance)
         key = (graph_id, ver)
-        if not pg.dense:
-            # §16: the edge operands attach (or an update's first query)
-            # made resident
-            with self._lock:
-                ops = self._cache.get("edges", key)
-            if ops is None:
-                self._count("operand_cache_misses")
-                ops = self._edge_operands(graph_id, pg)
-                self._put_edges(graph_id, ver, ops)
-            else:
-                self._count("operand_cache_hits")
-            return self._prepare(model, pg, ops,
-                                 x=self._resident_features(graph_id, ver, pg),
-                                 tier=tier, backend="edges", fusion=fusion,
-                                 submitted_s=submitted_s,
-                                 deadline_ms=deadline_ms, tolerance=tolerance)
-        if not self.sc.use_cacheg:
-            return self._prepare(model, pg, tier=tier, fusion=fusion,
-                                 submitted_s=submitted_s,
-                                 deadline_ms=deadline_ms,
-                                 tolerance=tolerance)
-        with self._lock:
-            ops = self._cache.get("operand", key)
-        if ops is None:
-            with self._lock:
-                spilled = self._cache.spill_get("operand", key)
-            if spilled is not None:
-                # §13 spill fault: the evicted primary re-materializes from
-                # its host-RAM compact form — compact bytes cross the link
-                # again, but zero host packing work runs, and it is NOT an
-                # operand_cache_miss (this version's structure work is done)
-                self._count("cache_spill_hits")
-                self._count("operand_bytes_h2d", spilled.nbytes)
-                ops = realize_operands(spilled, self._materializer)
-            else:
-                self._count("operand_cache_misses")
-                ops = self._device_operands(model, pg)
-            nb = pytree_nbytes(ops)
-            with self._lock:
-                if self._graph_version.get(graph_id) == ver:
-                    self._cache.put(
-                        "operand", key, ops, nbytes=nb,
-                        remat_s=transfer_cost(nb),
-                        spill_fn=self._operand_spill_fn(graph_id, ver,
-                                                        model))
-        else:
-            self._count("operand_cache_hits")
-        x = self._resident_features(graph_id, ver, pg)
-        tops = None
-        resolved = self._route_tier(model, tier, tolerance, pg.capacity)
-        e = self.models[model]
-        if self._needs_tier_ops(e, resolved):
-            # derived-form hit path: the int8 Â is structure work too —
-            # once per (graph, version), never per query
-            with self._lock:
-                tops = self._cache.get("tier", key)
-            if tops is None:
-                tops = self._agg_quantizer(ops.norm_adj)
-                with self._lock:
-                    # a derived insert can never evict an entry at its own
-                    # key — the manager protects the inserted key, which is
-                    # exactly the primary this form hangs off
-                    if self._graph_version.get(graph_id) == ver:
-                        self._cache.put("tier", key, tops,
-                                        nbytes=pytree_nbytes(tops))
-        backend = "dense"
-        if self._grasp_capable(e) and not e.tiers[resolved].quantgr:
-            # derived-form hit path for the block structure: rule + compact
-            # once per (graph, version) from the device-resident Â
-            with self._lock:
-                cached = self._cache.get("grasp", key)
-            if cached is None:
-                cached = self._derive_grasp(e, pg.capacity, ops.norm_adj)
-                with self._lock:
-                    if self._graph_version.get(graph_id) == ver:
-                        self._cache.put("grasp", key, cached,
-                                        nbytes=pytree_nbytes(cached))
-            backend, bsp = cached
-            self._count_forced_fallback(e, backend)   # per request, cached
-            if backend == "grasp":                    # decision or not
-                ops = dataclasses.replace(ops, block_sparse=bsp)
-        return self._prepare(model, pg, ops, x=x, tier=resolved,
-                             tier_ops=tops, tier_resolved=True,
-                             backend=backend, fusion=fusion,
-                             submitted_s=submitted_s,
+        tier = self._route_tier(model, tier, tolerance, pg.capacity)
+        backend, ops, tops = self._operand_set(self.models[model], pg, tier,
+                                               key)
+        x = self._resident("features", key, lambda: self._device_features(pg))
+        return self._prepare(model, pg, tier, backend, ops, tops, x,
+                             fusion=fusion, submitted_s=submitted_s,
                              deadline_ms=deadline_ms, tolerance=tolerance)
-
-    def _resident_features(self, graph_id: int, ver: int, pg: PaddedGraph
-                           ) -> jnp.ndarray:
-        """One version's padded features on the device: cached, or put
-        there once (`feature_bytes_h2d`) and cached if still current."""
-        key = (graph_id, ver)
-        with self._lock:
-            x = self._cache.get("features", key)
-        if x is None:
-            x = self._device_features(pg)
-            with self._lock:
-                if self._graph_version.get(graph_id) == ver:
-                    self._cache.put("features", key, x, nbytes=x.nbytes,
-                                    remat_s=transfer_cost(x.nbytes))
-        return x
 
     def _prepare_sharded(self, graph_id: int, model: str, pg: PaddedGraph,
                          sharded: Tuple[GraphShards, Graph], ver: int, *,
@@ -2080,38 +1961,15 @@ class GraphServe:
         part, g = sharded
         e = self.models[model]
         resolved = self._route_tier(model, tier, tolerance, part.shard_cap)
-        key = (graph_id, ver)
-        with self._lock:
-            slices = self._cache.get("shard", key)
-        if slices is None:
-            self._count("operand_cache_misses")
-            slices = build_sharded_operands(g, part, e.cfg)
-            nb = self._shard_entry_nbytes(slices)
-            with self._lock:
-                # no spill_fn: the slice tuple re-derives from the engine's
-                # own (partition, Graph) registry snapshot — a host-RAM
-                # spill would duplicate state the engine already holds
-                if self._graph_version.get(graph_id) == ver:
-                    self._cache.put("shard", key, slices, nbytes=nb,
-                                    remat_s=transfer_cost(nb))
-        else:
-            self._count("operand_cache_hits")
+        slices = self._resident(
+            "shard", (graph_id, ver),
+            lambda: build_sharded_operands(g, part, e.cfg), count=True)
         x, ops, mask = stack_shard_slices(slices)
-        now = self.clock.now()
-        submitted_s = submitted_s if submitted_s is not None else now
-        with self._lock:
-            uid = self._uid
-            self._uid += 1
-            if self.metrics["first_submit_s"] is None:
-                self.metrics["first_submit_s"] = submitted_s
-        deadline_s = (submitted_s + deadline_ms * 1e-3
-                      if deadline_ms is not None else None)
-        return GNNRequest(uid=uid, model=model, pg=pg, ops=ops,
-                          bucket=part.shard_cap, submitted_s=submitted_s,
-                          tier=resolved, backend="dense", fusion="none",
-                          shards=part.shards, part=part, shard_x=x,
-                          shard_mask=mask, deadline_s=deadline_s,
-                          tolerance=tolerance)
+        return self._prepare(model, pg, resolved, "dense", ops, None, None,
+                             fusion="none", submitted_s=submitted_s,
+                             deadline_ms=deadline_ms, tolerance=tolerance,
+                             bucket=part.shard_cap, shards=part.shards,
+                             part=part, shard_x=x, shard_mask=mask)
 
     def query(self, graph_id: int, *, tier: Optional[str] = None,
               fusion: Optional[str] = None,

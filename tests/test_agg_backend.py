@@ -27,11 +27,9 @@ from repro.runtime.gnn_server import GraphServe, GraphServeConfig
 IN_FEATS, CLASSES = 16, 4
 
 
-def _engine(mode, *, buckets=(1024,), batch_slots=2, use_cacheg=True,
-            hidden=8, seed=0):
+def _engine(mode, *, buckets=(1024,), batch_slots=2, hidden=8, seed=0):
     sc = GraphServeConfig(ladder=BucketLadder(buckets=buckets),
-                          batch_slots=batch_slots, return_logits=True,
-                          use_cacheg=use_cacheg)
+                          batch_slots=batch_slots, return_logits=True)
     eng = GraphServe(sc, seed=seed)
     eng.register_model("gcn", GNNConfig(kind="gcn", in_feats=IN_FEATS,
                                         hidden=hidden, num_classes=CLASSES),
@@ -198,22 +196,22 @@ def test_grasp_structure_cached_per_version_and_released():
     gid = eng.attach(g, model="gcn")
     eng.query(gid)
     eng.run()
-    assert (gid, 0) in eng._grasp_cache
+    assert (gid, 0) in eng._cache.view("grasp")
     traces = eng._block_compactor.trace_count
     eng.query(gid)
     eng.query(gid)
     eng.run()
     eng.assert_warm()
     assert eng._block_compactor.trace_count == traces   # replayed, not rebuilt
-    assert len(eng._grasp_cache) == 1                   # one entry, reused
+    assert len(eng._cache.view("grasp")) == 1                   # one entry, reused
     g2 = _sparse_graph(seed=7)
     eng.update(gid, g2.edge_index, g2.num_nodes, g2.features)
-    assert (gid, 0) not in eng._grasp_cache
+    assert (gid, 0) not in eng._cache.view("grasp")
     eng.query(gid)
     eng.run()
-    assert (gid, 1) in eng._grasp_cache
+    assert (gid, 1) in eng._cache.view("grasp")
     eng.detach(gid)
-    assert not eng._grasp_cache                         # regression: released
+    assert not eng._cache.view("grasp")                         # regression: released
 
 
 def test_forced_mode_ineligible_graph_counts_backend_fallback():
@@ -230,31 +228,37 @@ def test_forced_mode_ineligible_graph_counts_backend_fallback():
 
 
 def test_eager_engine_builds_structure_on_host(rng):
-    """`use_cacheg=False` keeps ALL structure work on the host: the block
-    form rides `HostOperands.grasp` (bytes counted) instead of the device
-    compactor, and logits still match the dense backend."""
-    eng = _engine("grasp", use_cacheg=False)
-    eng_d = _engine("dense", use_cacheg=False)
+    """A directed graph's operands are still built eagerly on the host
+    (counted in `cacheg_fallbacks`), but a one-shot grasp request over it
+    derives its block structure on the device like any other: only the
+    dense operands cross the link, and logits match the dense backend."""
+    eng = _engine("grasp")
+    eng_d = _engine("dense")
     g = _sparse_graph()
-    b0 = eng.metrics["operand_bytes_h2d"]
+    # one extra edge whose reverse is absent makes the graph directed
+    adj = np.zeros((g.num_nodes, g.num_nodes), bool)
+    adj[g.edge_index[1], g.edge_index[0]] = True
+    i, j = np.argwhere(~adj & ~adj.T & ~np.eye(g.num_nodes, dtype=bool))[0]
+    g = dataclasses.replace(g, edge_index=np.concatenate(
+        [g.edge_index, np.array([[i], [j]], np.int32)], axis=1))
     for e in (eng, eng_d):
         e.submit(g, model="gcn")
         e.submit(g, model="gcn")
         e.run()
         e.assert_warm()
-    assert eng.summary()["grasp_batches"] == 1
-    assert eng.metrics["operand_bytes_h2d"] - b0 > 0
+    s = eng.summary()
+    assert s["grasp_batches"] == 1
+    assert s["cacheg_fallbacks"] == 2
     ref = {r.uid: r.logits for r in eng_d.finished}
     for r in eng.finished:
         assert r.backend == "grasp"
         np.testing.assert_allclose(r.logits, ref[r.uid], atol=1e-4,
                                    rtol=1e-4)
-    # the host product itself carries the compaction (scheduler host stage)
-    pg = pad_graph(g, capacity=1024)
-    cfg = eng.models["gcn"].cfg
-    ho = prepare_host_operands(pg, cfg, use_cacheg=False,
-                               grasp_max_nnz=grasp_max_nnz(1024))
-    assert ho.grasp is not None and ho.nbytes > ho.grasp.nbytes
+    # the host product is the eager operand set alone (scheduler host stage)
+    ho = prepare_host_operands(pad_graph(g, capacity=1024),
+                               eng.models["gcn"].cfg)
+    assert ho.fallback and ho.compact is None
+    assert s["operand_bytes_h2d"] == 2 * ho.nbytes
 
 
 def test_backend_fallback_counts_ref_mode_dense_run(monkeypatch):

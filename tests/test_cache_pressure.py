@@ -265,7 +265,7 @@ def test_sharded_delta_patches_slices_and_halo():
     part1, g1 = eng._sharded[gid]
     assert np.array_equal(part1.perm, part0.perm)     # partition KEPT
     ver = eng._graph_version[gid]
-    patched = eng._shard_cache[(gid, ver)]
+    patched = eng._cache.view("shard")[(gid, ver)]
     cfg = eng.models["gcn"].cfg
     ref = build_sharded_operands(g1, part1, cfg)
     for s, r in zip(patched, ref):

@@ -4,6 +4,7 @@ accounting, and the satellite fixes that ride along (grow() supervision
 carry, vectorized SAGE sampling, bucket-rule dedup)."""
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -15,11 +16,12 @@ from repro.core.graph import (BucketLadder, Graph, is_symmetric_adjacency,
                               required_capacity, symg_pack_adjacency_bits,
                               triangular_nbits)
 from repro.core.masks import sage_sample_adjacency
-from repro.core.models import (GNNConfig, build_operands, compact_operands,
-                               forward_grannite, materialize_operands,
-                               operand_nbytes, _unpack_adjacency)
+from repro.core.models import (GNNConfig, build_operands, build_plan,
+                               compact_operands, forward_grannite,
+                               materialize_operands, operand_nbytes,
+                               _unpack_adjacency)
 from repro.core.partition import transfer_cost
-from repro.data.graphs import planetoid_like
+from repro.data.graphs import clustered_like, planetoid_like
 from repro.runtime.cache import estimate_dense_entry_bytes
 from repro.runtime.gnn_server import GraphServe, GraphServeConfig
 
@@ -36,10 +38,9 @@ def _cfg(kind):
                      num_classes=CLASSES, heads=4)
 
 
-def _engine(*kinds, use_cacheg=True, buckets=(128,), batch_slots=2):
+def _engine(*kinds, buckets=(128,), batch_slots=2):
     sc = GraphServeConfig(ladder=BucketLadder(buckets=buckets),
-                          batch_slots=batch_slots, return_logits=True,
-                          use_cacheg=use_cacheg)
+                          batch_slots=batch_slots, return_logits=True)
     eng = GraphServe(sc, seed=0)
     for kind in kinds:
         eng.register_model(kind, _cfg(kind))
@@ -116,20 +117,22 @@ def test_materialized_gcn_matches_eager_with_explicit_self_loop():
 
 @pytest.mark.parametrize("kind", ["gcn", "gat", "sage"])
 def test_batched_cacheg_matches_eager_path(kind):
-    """Same params, same graphs: the CacheG engine's batched logits equal the
-    eager-operand engine's within fp32 tolerance."""
+    """Same params, same graphs: the CacheG engine's batched logits equal
+    the single-graph plan run on eagerly host-built operands within fp32
+    tolerance."""
     graphs = [_graph(n, seed=i) for i, n in enumerate([60, 110, 90])]
-    outs = {}
-    for mode in (True, False):
-        eng = _engine(kind, use_cacheg=mode)
-        for g in graphs:
-            eng.submit(g, model=kind)
-        eng.run()
-        eng.assert_warm()
-        outs[mode] = {r.uid: r.logits for r in eng.finished}
-    assert outs[True].keys() == outs[False].keys()
-    for uid in outs[True]:
-        np.testing.assert_allclose(outs[True][uid], outs[False][uid],
+    eng = _engine(kind)
+    uids = [eng.submit(g, model=kind) for g in graphs]
+    eng.run()
+    eng.assert_warm()
+    got = {r.uid: r.logits for r in eng.finished}
+    e = eng.models[kind]
+    plan = build_plan(e.cfg, 128, e.techniques)
+    for uid, g in zip(uids, graphs):
+        pg = pad_graph(g, capacity=128)
+        ref = plan(e.params, jnp.asarray(pg.features),
+                   build_operands(pg, e.cfg, lean=True))
+        np.testing.assert_allclose(got[uid], np.asarray(ref)[:g.num_nodes],
                                    atol=1e-5)
 
 
@@ -209,20 +212,22 @@ def test_update_invalidates_operand_cache():
                                np.asarray(ref)[: g.num_nodes], atol=1e-5)
 
 
-def test_directed_graph_falls_back_to_eager_upload():
-    """A directed GCN graph cannot take the SymG transfer; the engine serves
-    it through the eager dense upload (counted) without breaking warmth."""
-    g = _graph(100)
+def _directed(g):
+    """`g` plus one edge whose reverse is absent."""
     adj = np.zeros((g.num_nodes, g.num_nodes), bool)
     adj[g.edge_index[1], g.edge_index[0]] = True
     pairs = np.argwhere(~adj & ~adj.T & ~np.eye(g.num_nodes, dtype=bool))
     i, j = pairs[0]                          # guaranteed-absent pair
     ei = np.concatenate([g.edge_index,
                          np.array([[i], [j]], np.int32)], axis=1)
-    directed = Graph(edge_index=ei, num_nodes=g.num_nodes,
-                     features=g.features)
+    return Graph(edge_index=ei, num_nodes=g.num_nodes, features=g.features)
+
+
+def test_directed_graph_falls_back_to_eager_upload():
+    """A directed GCN graph cannot take the SymG transfer; the engine serves
+    it through the eager dense upload (counted) without breaking warmth."""
     eng = _engine("gcn")
-    gid = eng.attach(directed, model="gcn")
+    gid = eng.attach(_directed(_graph(100)), model="gcn")
     eng.query(gid)
     eng.query(gid)                          # fallback ops still cache-hit
     eng.run()
@@ -232,14 +237,91 @@ def test_directed_graph_falls_back_to_eager_upload():
     assert s["operand_cache_hits"] == 1
 
 
+# ------------------------------------- one operand routine, two intakes
+
+
+def _gcn_auto_engine():
+    """GCN under the density/cost rule at a bucket where a clustered graph
+    routes grasp and a scattered one dense."""
+    sc = GraphServeConfig(ladder=BucketLadder(buckets=(512,)),
+                          batch_slots=2, return_logits=True)
+    eng = GraphServe(sc, seed=0)
+    eng.register_model("gcn", GNNConfig(kind="gcn", in_feats=IN_FEATS,
+                                        hidden=8, num_classes=CLASSES),
+                       agg_backend="auto")
+    return eng
+
+
+@pytest.mark.parametrize("case", ["gcn-dense", "gcn-grasp", "gat", "sage"])
+def test_one_shot_and_attached_requests_get_one_operand_set(case):
+    """For the same graph, a one-shot submit and a query of the attached
+    graph resolve their operands through one routine: the same backend,
+    equal operand pytrees (block structure and tier operands included),
+    and equal logits."""
+    kind = case.split("-")[0]
+    if kind == "gcn":
+        eng = _gcn_auto_engine()
+        g = (clustered_like(num_nodes=450, num_feats=IN_FEATS,
+                            num_classes=CLASSES, within_density=0.03, seed=1)
+             if case == "gcn-grasp" else
+             planetoid_like(num_nodes=450, num_edges=40 * 450,
+                            num_feats=IN_FEATS, num_classes=CLASSES, seed=2,
+                            train_per_class=2))
+    else:
+        eng, g = _engine(kind), _graph(100)
+    one = eng.prepare_submit(g, model=kind)
+    attached = eng.prepare_query(eng.attach(g, model=kind))
+    assert one.backend == attached.backend == (
+        "grasp" if case == "gcn-grasp" else "dense")
+    leaves, tree = jax.tree_util.tree_flatten((one.ops, one.tier_ops))
+    leaves2, tree2 = jax.tree_util.tree_flatten((attached.ops,
+                                                 attached.tier_ops))
+    assert tree == tree2
+    for a, b in zip(leaves, leaves2):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    eng._push(one)
+    eng._push(attached)
+    eng.run()
+    np.testing.assert_array_equal(one.logits, attached.logits)
+
+
+def test_a_build_that_outlives_its_version_inserts_nothing(monkeypatch):
+    """A host stage whose build finishes after `update()` has bumped the
+    version serves the snapshot it read and caches nothing, at the dead
+    key or the new one; the next query builds under the new version."""
+    eng = _engine("gcn")
+    g = _graph(100)
+    gid = eng.attach(g, model="gcn")
+    build = eng._device_operands
+
+    def racing(cfg, pg):
+        ops = build(cfg, pg)
+        eng.update(gid, g.edge_index, g.num_nodes, g.features)
+        return ops
+
+    monkeypatch.setattr(eng, "_device_operands", racing)
+    req = eng.prepare_query(gid)
+    assert eng._graph_version[gid] == 1
+    assert eng._cache.entry_sizes() == {}
+    monkeypatch.undo()
+    eng._push(req)
+    eng.query(gid)
+    eng.run()
+    assert set(eng._cache.view("operand")) == {(gid, 1)}
+    assert eng.summary()["operand_cache_misses"] == 2
+    np.testing.assert_array_equal(eng.finished[0].logits,
+                                  eng.finished[1].logits)
+    eng.assert_warm()
+
+
 def test_detach_releases_cache_and_graph():
     eng = _engine("gcn")
     gid = eng.attach(_graph(100), model="gcn")
     eng.query(gid)
     eng.run()
-    assert len(eng._operand_cache) == 1
+    assert len(eng._cache.view("operand")) == 1
     eng.detach(gid)
-    assert eng._operand_cache == {} and gid not in eng.graphs
+    assert eng._cache.view("operand") == {} and gid not in eng.graphs
     eng.detach(gid)                         # idempotent
 
 
@@ -279,27 +361,20 @@ def test_attached_features_cross_the_link_once_per_version():
 
 
 def test_one_shot_requests_send_their_features_in_the_host_stage():
-    """Each one-shot submit, and each query of a non-CacheG engine, puts
-    its features on the device once, before any dispatch; a finished
-    request holds no device features."""
-    for use_cacheg in (True, False):
-        eng = _engine("gcn", use_cacheg=use_cacheg)
-        for i in range(3):
-            req = eng.prepare_submit(_graph(60 + i, seed=i), model="gcn")
-            assert req.x is not None and req.x.shape == (128, IN_FEATS)
-            assert _sent(eng) == (i + 1) * FEATURE_BYTES
-            eng._push(req)
-        if not use_cacheg:
-            gid = eng.attach(_graph(100), model="gcn")
-            eng.query(gid)
-            eng.query(gid)
-            assert _sent(eng) == 5 * FEATURE_BYTES
-        sent = _sent(eng)
-        eng.run()
-        assert _sent(eng) == sent
-        assert eng.finished and all(r.done and r.x is None
-                                    for r in eng.finished)
-        eng.assert_warm()
+    """Each one-shot submit puts its features on the device once, before
+    any dispatch; a finished request holds no device features."""
+    eng = _engine("gcn")
+    for i in range(3):
+        req = eng.prepare_submit(_graph(60 + i, seed=i), model="gcn")
+        assert req.x is not None and req.x.shape == (128, IN_FEATS)
+        assert _sent(eng) == (i + 1) * FEATURE_BYTES
+        eng._push(req)
+    sent = _sent(eng)
+    eng.run()
+    assert _sent(eng) == sent
+    assert eng.finished and all(r.done and r.x is None
+                                for r in eng.finished)
+    eng.assert_warm()
 
 
 @pytest.mark.parametrize("kind", ["gcn", "gat"])
@@ -388,13 +463,13 @@ def test_evicted_features_come_back_and_answer_the_same():
 
 
 def test_operand_bytes_accounting_matches_array_sizes():
-    """operand_bytes_h2d is exactly the nbytes of what each path uploads:
-    packed bits + degree + num_nodes for CacheG, the five dense fields for
-    the eager path."""
+    """operand_bytes_h2d is exactly the nbytes of what each path uploads,
+    once per structure version: packed bits + degree + num_nodes for
+    CacheG, the five dense fields for a directed graph's eager fallback."""
     cap, n_q = 128, 3
     g = _graph(100)
 
-    eng = _engine("gat", use_cacheg=True)
+    eng = _engine("gat")
     gid = eng.attach(g, model="gat")
     for _ in range(n_q):
         eng.query(gid)
@@ -404,18 +479,22 @@ def test_operand_bytes_accounting_matches_array_sizes():
                         + 4)                          # num_nodes int32
     assert eng.summary()["operand_bytes_h2d"] == compact_expected
 
-    eng = _engine("gat", use_cacheg=False)
-    gid = eng.attach(g, model="gat")
+    directed = _directed(g)
+    eng = _engine("gat")
+    gid = eng.attach(directed, model="gat")
     for _ in range(n_q):
         eng.query(gid)
     eng.run()
-    pg = pad_graph(g, capacity=cap)
-    per_request = operand_nbytes(build_operands(pg, _cfg("gat"), lean=True))
-    assert per_request == 2 * 4 * cap * cap + 3 * 4   # 2 masks + 3 holes
-    assert eng.summary()["operand_bytes_h2d"] == n_q * per_request
+    pg = pad_graph(directed, capacity=cap)
+    eager = operand_nbytes(build_operands(pg, _cfg("gat"), lean=True))
+    assert eager == 2 * 4 * cap * cap + 3 * 4         # 2 masks + 3 holes
+    s = eng.summary()
+    assert s["operand_bytes_h2d"] == eager
+    assert s["cacheg_fallbacks"] == 1
+    assert s["operand_cache_hits"] == n_q - 1
     # the compact transfer beats the eager upload by far more than the
     # acceptance floor even on a single cold miss
-    assert per_request / compact_expected > 10
+    assert eager / compact_expected > 10
 
 
 # ------------------------------------------------------- satellite: grow()

@@ -488,13 +488,13 @@ def test_delta_update_equals_full_rebuild(kind, tier, n, seed, n_add, n_rm):
         eng.assert_warm()
         k1 = (gid, eng._graph_version[gid])
         k2 = (gid2, eng._graph_version[gid2])
-        o1, o2 = eng._operand_cache[k1], eng._operand_cache[k2]
+        o1, o2 = eng._cache.view("operand")[k1], eng._cache.view("operand")[k2]
         for f in OPERAND_FIELDS[kind]:
             np.testing.assert_array_equal(np.asarray(getattr(o1, f)),
                                           np.asarray(getattr(o2, f)))
         if kind == "gcn" and tier == "int8":
-            t1 = eng._tier_operand_cache[k1]
-            t2 = eng._tier_operand_cache[k2]
+            t1 = eng._cache.view("tier")[k1]
+            t2 = eng._cache.view("tier")[k2]
             np.testing.assert_array_equal(np.asarray(t1.agg_aq),
                                           np.asarray(t2.agg_aq))
             np.testing.assert_array_equal(np.asarray(t1.agg_a_scale),

@@ -174,17 +174,17 @@ def test_operand_cache_shared_across_tiers():
     s = eng.summary()
     assert s["operand_cache_misses"] == 1
     assert s["operand_cache_hits"] == 3
-    assert len(eng._operand_cache) == 1
-    assert len(eng._tier_operand_cache) == 1
+    assert len(eng._cache.view("operand")) == 1
+    assert len(eng._cache.view("tier")) == 1
     # update() invalidates BOTH caches under the same version key
     g2 = _graph(110, seed=9)
     eng.update(gid, g2.edge_index, g2.num_nodes, g2.features)
-    assert len(eng._operand_cache) == 0
-    assert len(eng._tier_operand_cache) == 0
+    assert len(eng._cache.view("operand")) == 0
+    assert len(eng._cache.view("tier")) == 0
     eng.query(gid, tier="int8")
     eng.run()
     eng.assert_warm()
-    assert len(eng._tier_operand_cache) == 1
+    assert len(eng._cache.view("tier")) == 1
 
 
 # ------------------------------------------------- fallback-before-calibrate
