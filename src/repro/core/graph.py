@@ -11,6 +11,7 @@ the JAX analogue of the paper's "one precompiled blob" (jit cache hit).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import numpy as np
@@ -204,6 +205,12 @@ class PaddedGraph:
     def dense(self) -> bool:
         return self.edge_index is None
 
+    @functools.cached_property
+    def layout(self) -> Tuple[int, int]:
+        """(chunks, slots per chunk) of the edge-list form's tiled layout
+        (`edge_layout`), worked out once per padded graph."""
+        return edge_layout(self.edge_index[1], self.capacity)
+
 
 def pad_graph(g: Graph, *, capacity: Optional[int] = None, slack: float = 0.0,
               norm: str = "gcn", dense: bool = True) -> PaddedGraph:
@@ -247,25 +254,55 @@ def pad_graph(g: Graph, *, capacity: Optional[int] = None, slack: float = 0.0,
 
 # ---------------------------------------------------------------------------
 # Edge-list operands: a graph whose dense (cap, cap) operands the device
-# cannot hold aggregates from its edge list, padded to an edge rung so that
-# graphs of one bucket and similar edge counts replay one compiled plan.
+# cannot hold aggregates from its edge list, laid out by destination tile
+# (DESIGN.md §16) and padded to a chunk rung, so that graphs of one bucket
+# and similar edge counts replay one compiled plan.
 # ---------------------------------------------------------------------------
 
-def edge_rung(num_edges: int) -> int:
-    """Padded edge count: the next multiple of 1/16 of the power of two at
-    or below `num_edges` (at least 128), so padding wastes at most 1/16."""
-    step = max(1 << max(int(num_edges).bit_length() - 5, 0), MXU_TILE)
-    return max(-(-int(num_edges) // step) * step, MXU_TILE)
+EDGE_TILE_ROWS = MXU_TILE  # destination rows summed by one output tile
+
+
+def edge_layout(dst: np.ndarray, capacity: int) -> Tuple[int, int]:
+    """(chunks, slots per chunk) of the tiled edge layout of the edges
+    whose destinations are `dst`: the shapes its operands take."""
+    _, per_tile, c = _chunks_per_tile(dst, capacity)
+    return int(per_tile.sum()), c
+
+
+def _chunks_per_tile(dst: np.ndarray, capacity: int
+                     ) -> Tuple[np.ndarray, np.ndarray, int]:
+    """(in-edges per tile, chunks per tile, chunk size C) of the tiled
+    edge layout; the last tile holds the spare row `capacity`, one past
+    the bucket.
+
+    C is the largest of 512, 256 and 128 slots that is at most half a
+    tile's mean in-edge count, so a tile wastes well under half its slots
+    to padding. Every tile takes at least one chunk, so a tile without
+    in-edges is still written; the spare tile takes the padding chunks up
+    to the rung: a multiple of 1/32 of the power of two at or below the
+    chunk count (at least 8), so padding wastes at most 1/32."""
+    counts = np.bincount(np.asarray(dst) // EDGE_TILE_ROWS,
+                         minlength=capacity // EDGE_TILE_ROWS + 1)
+    half_mean = dst.shape[0] / counts.shape[0] / 2
+    c = max([s for s in (512, 256) if s <= half_mean], default=MXU_TILE)
+    per_tile = np.maximum(1, -(-counts // c))
+    k = int(per_tile.sum())
+    step = max(1 << max(k.bit_length() - 6, 0), 8)
+    per_tile[-1] += -(-k // step) * step - k
+    return counts, per_tile, c
 
 
 def edge_arrays(edge_index: np.ndarray, num_nodes: int, capacity: int
-                ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """(src, dst, inv_deg) of one graph's edge-list operands.
+                ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """(src, slot_rows, chunk_tile, inv_deg) of one graph's edge-list
+    operands, in the tiled layout of `edge_layout` (K chunks of C slots).
 
-    `src`/`dst` are int32 of `edge_rung(E)` entries, sorted by (dst, src);
-    the spare entries run from node 0 into row `capacity`, one past the
-    bucket, which the aggregation sums and drops. `inv_deg` (cap,) float32
-    is 1 / in-degree, 0 for nodes with no in-edges and for padding rows.
+    The edges, sorted by (dst, src), fill their destination tile's chunks
+    in order. `src` (K * C,) int32 is each slot's source row, 0 on
+    padding; `slot_rows` (K, 1, C) int32 its destination row within the
+    tile, -1 on padding; `chunk_tile` (K,) int32 each chunk's tile,
+    non-decreasing. `inv_deg` (cap,) float32 is 1 / in-degree, 0 for nodes
+    with no in-edges and for padding rows.
     """
     ei = np.asarray(edge_index)
     if ei.size and (ei.min() < 0 or ei.max() >= num_nodes):
@@ -275,15 +312,23 @@ def edge_arrays(edge_index: np.ndarray, num_nodes: int, capacity: int
         raise ValueError(f"{num_nodes} nodes exceed capacity {capacity}")
     src, dst = ei[0].astype(np.int32), ei[1].astype(np.int32)
     order = np.lexsort((src, dst))
-    rung = edge_rung(src.shape[0])
-    out_src = np.zeros((rung,), np.int32)
-    out_dst = np.full((rung,), capacity, np.int32)
-    out_src[:src.shape[0]] = src[order]
-    out_dst[:dst.shape[0]] = dst[order]
+    src, dst = src[order], dst[order]
+    counts, per_tile, c = _chunks_per_tile(dst, capacity)
+    k = int(per_tile.sum())
+    chunk_tile = np.repeat(np.arange(per_tile.shape[0], dtype=np.int32),
+                           per_tile)
+    tile = dst // EDGE_TILE_ROWS
+    first_slot = (np.cumsum(per_tile) - per_tile) * c
+    first_edge = np.cumsum(counts) - counts
+    slot = first_slot[tile] + np.arange(dst.shape[0]) - first_edge[tile]
+    out_src = np.zeros((k * c,), np.int32)
+    out_rows = np.full((k * c,), -1, np.int32)
+    out_src[slot] = src
+    out_rows[slot] = dst - tile * EDGE_TILE_ROWS
     deg = np.bincount(dst, minlength=capacity).astype(np.float32)
     inv_deg = np.where(deg > 0, 1.0 / np.maximum(deg, 1.0), 0.0
                        ).astype(np.float32)
-    return out_src, out_dst, inv_deg
+    return out_src, out_rows.reshape(k, 1, c), chunk_tile, inv_deg
 
 
 # ---------------------------------------------------------------------------

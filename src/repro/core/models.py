@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import effop, layers, masks
-from .graph import PaddedGraph
+from .graph import EDGE_TILE_ROWS, PaddedGraph
 from .layers import Techniques
 from .quant import QuantizedLinear, quantize_linear
 from .sparsity import (BlockSparse, block_counts, compact_block_sparse,
@@ -482,38 +482,107 @@ def realize_operands(ho: HostOperands,
 @dataclasses.dataclass
 class EdgeOperands:
     """Device-resident edge-list operands of one graph (`core.graph.
-    edge_arrays`): int32 `src`/`dst` padded to the graph's edge rung and
-    sorted by destination, spare edges landing in row `capacity` (one past
-    the bucket), and (cap,) float32 `inv_deg`. Memory grows with the
-    edges, never with the bucket squared. A registered pytree, so a stack
-    of them crosses the batched plan's vmap like `GranniteOperands`."""
+    edge_arrays`), laid out by destination tile: int32 `src` (K * C,) the
+    source row of each chunk slot, `slot_rows` (K, 1, C) its destination
+    row within the tile (-1 on padding), `chunk_tile` (K,) each chunk's
+    tile, and (cap,) float32 `inv_deg`. Memory grows with the edges, never
+    with the bucket squared. A registered pytree, so a stack of them
+    crosses the batched plan's vmap like `GranniteOperands`."""
     src: jnp.ndarray
-    dst: jnp.ndarray
+    slot_rows: jnp.ndarray
+    chunk_tile: jnp.ndarray
     inv_deg: jnp.ndarray
+
+    @property
+    def layout(self) -> Tuple[int, int]:
+        """(chunks, slots per chunk): what the plan's shapes follow."""
+        k, _, c = self.slot_rows.shape[-3:]
+        return k, c
+
+    @staticmethod
+    def nbytes(k: int, c: int, cap: int) -> int:
+        """Device bytes of one graph's operands in a layout of k chunks of
+        c slots at bucket `cap`."""
+        return 4 * (k * (2 * c + 1) + cap)
+
+    @classmethod
+    def placeholder(cls, k: int, c: int, cap: int) -> "EdgeOperands":
+        """All-padding operands of that layout: what warmup compiles with."""
+        return cls(src=jnp.zeros((k * c,), jnp.int32),
+                   slot_rows=jnp.full((k, 1, c), -1, jnp.int32),
+                   chunk_tile=jnp.zeros((k,), jnp.int32),
+                   inv_deg=jnp.zeros((cap,), jnp.float32))
 
 
 jax.tree_util.register_pytree_node(
-    EdgeOperands, lambda o: ((o.src, o.dst, o.inv_deg), None),
+    EdgeOperands,
+    lambda o: ((o.src, o.slot_rows, o.chunk_tile, o.inv_deg), None),
     lambda _, c: EdgeOperands(*c))
 
 
 def edge_operands(pg: PaddedGraph) -> EdgeOperands:
-    """Host side of the edge-list form: sort, pad and upload one graph's
-    edges (`pg` from `pad_graph(dense=False)`)."""
+    """Host side of the edge-list form: sort, tile, pad and upload one
+    graph's edges (`pg` from `pad_graph(dense=False)`)."""
     from .graph import edge_arrays
     return EdgeOperands(*(jnp.asarray(a) for a in edge_arrays(
         pg.edge_index, pg.num_nodes, pg.capacity)))
 
 
+# The most device bytes of gathered messages one aggregation holds at once
+# (lanes padded to 128): a larger edge list is gathered and summed in
+# groups of chunks, one group after another (`edge_gather_groups`).
+EDGE_GATHER_BLOCK_BYTES = 1 << 32
+
+
+def edge_gather_groups(k: int, c: int, width: int) -> int:
+    """Groups of chunks `edge_mean` gathers one after another at this
+    layout and message width: the fewest, by halving, whose gathered
+    (k * c / groups, width) f32 block stays within
+    `EDGE_GATHER_BLOCK_BYTES`. The chunk rung makes k a multiple of a
+    power of two (at least 8) that the halving stops at."""
+    block = 4 * k * c * -(-width // 128) * 128
+    groups = 1
+    while block > groups * EDGE_GATHER_BLOCK_BYTES and k % (2 * groups) == 0:
+        groups *= 2
+    return groups
+
+
 def edge_mean(h: jnp.ndarray, eo: EdgeOperands) -> jnp.ndarray:
-    """Mean over each row's in-neighbours: gather the source rows, sum them
-    by destination (the edges are sorted by it) into cap + 1 rows, drop the
-    spare edges' row, scale by 1 / in-degree. A row with no in-edges gets
-    0."""
-    cap = h.shape[0]
-    msgs = h.at[eo.src].get(mode="promise_in_bounds")
-    summed = jax.ops.segment_sum(msgs, eo.dst, num_segments=cap + 1,
-                                 indices_are_sorted=True)
+    """Mean over each row's in-neighbours: gather the source row of every
+    chunk slot (an XLA gather), sum the messages by destination tile on
+    the MXU (`kernels.ops.edge_sum`) into cap + 1 rows, drop the spare
+    row, scale by 1 / in-degree. A row with no in-edges gets 0.
+
+    Past `EDGE_GATHER_BLOCK_BYTES` of messages the chunks go in groups:
+    each group's sums cover the tiles its chunks name (a tile split
+    between two groups gets a part from each) and are added up."""
+    from repro.kernels import ops as kops
+    cap, width = h.shape
+    k, c = eo.layout
+    groups = edge_gather_groups(k, c, width)
+
+    def part(src, slot_rows, chunk_tile):
+        msgs = h.at[src].get(mode="promise_in_bounds")
+        return kops.edge_sum(msgs, slot_rows, chunk_tile, out_rows=cap + 1,
+                             tile_rows=EDGE_TILE_ROWS)
+
+    if groups == 1:
+        summed = part(eo.src, eo.slot_rows, eo.chunk_tile)
+    else:
+        row_tile = jnp.arange(cap + 1) // EDGE_TILE_ROWS
+
+        def add_group(acc, g):
+            src, slot_rows, chunk_tile = g
+            seen = (row_tile >= chunk_tile[0]) & (row_tile <= chunk_tile[-1])
+            # tiles the group does not name are never written
+            return acc + jnp.where(seen[:, None],
+                                   part(src, slot_rows, chunk_tile), 0.0), None
+
+        summed, _ = jax.lax.scan(
+            add_group, jnp.zeros((cap + 1, width), jnp.float32),
+            (eo.src.reshape(groups, -1),
+             eo.slot_rows.reshape(groups, k // groups, 1, c),
+             eo.chunk_tile.reshape(groups, -1)))
     return summed[:cap] * eo.inv_deg[:, None]
 
 
@@ -526,7 +595,8 @@ def forward_edges(params: Dict, cfg: GNNConfig, x: jnp.ndarray,
     exact (mean(h) W = mean(h W)): the last layer's 40 classes, say, in
     place of its 256 inputs. The aggregation of layer i runs under
     `graphserve.agg.l<i>` (the ops' metadata); a TPU trace names its ops
-    by their output instead: the only f32 results of cap + 1 rows."""
+    by their output instead: the edge-sum kernel's, the only f32 results
+    of cap + 1 rows (the gathers write K * C rows)."""
     h = x
     for i in range(1, cfg.num_layers + 1):
         p = params[f"l{i}"]
@@ -995,7 +1065,7 @@ def forward_grannite(params: Dict, cfg: GNNConfig, x: jnp.ndarray,
 #   grasp — the block-sparse bitmap_spmm kernel over a compacted structure
 #           (the operands MUST carry `block_sparse`, padded to the bucket's
 #           grasp_max_nnz budget; dense plans must carry None).
-#   edges — gather and segment-sum over edge-list operands (`EdgeOperands`,
+#   edges — gather and MXU edge-sum over edge-list operands (`EdgeOperands`,
 #           `forward_edges`): SAGE-mean over every in-neighbour, for buckets
 #           whose dense operands the device cannot hold.
 AGG_BACKENDS = ("dense", "grasp", "edges")
@@ -1053,6 +1123,9 @@ class ExecutionPlan:
     # The compiled blob keeps whatever lowering it was traced with, so
     # fallback accounting must read this — not the env at dispatch time.
     grasp_ref_fallback: bool = False
+    # The same capture for edges plans: True when the reduction lowered to
+    # the `ref` segment-sum in place of the MXU kernel.
+    edge_ref_fallback: bool = False
 
     @property
     def key(self) -> PlanKey:
@@ -1095,7 +1168,7 @@ def build_plan(cfg: GNNConfig, capacity: int, t: Techniques, *,
     kernel pass — like the backend, a dispatch decision orthogonal to the
     tier, carried in the key because it changes the compiled blob.
     `backend="edges"` (DESIGN.md §16) aggregates from `EdgeOperands`
-    (`forward_edges`); its operands' shapes follow the edge rung, and each
+    (`forward_edges`); its operands' shapes follow the chunk rung, and each
     rung is one more trace of the same plan.
     """
     if backend not in AGG_BACKENDS:
@@ -1119,11 +1192,20 @@ def build_plan(cfg: GNNConfig, capacity: int, t: Techniques, *,
             from repro.kernels.ops import bitmap_spmm_mode
             plan.grasp_ref_fallback = bitmap_spmm_mode() == "ref"
         if backend == "edges":
+            from repro.kernels.ops import edge_sum_mode
+            plan.edge_ref_fallback = edge_sum_mode() == "ref"
             return forward_edges(params, cfg, x, ops_)
         return forward_grannite(params, cfg, x, ops_, exec_t, quant=quant,
                                 tier_ops=tier_ops, fusion=fusion)
 
-    if batch_size > 0:
+    if batch_size > 0 and backend == "edges":
+        # one slot after another: a slot's gathered messages, (K * C, w)
+        # f32, are the plan's largest temporary, and a vmap would hold
+        # batch_size of them at once
+        plan.fn = jax.jit(lambda params, x, ops_, quant, tier_ops: jax.lax.map(
+            lambda a: _forward(params, a[0], a[1], quant, tier_ops),
+            (x, ops_)))
+    elif batch_size > 0:
         plan.fn = jax.jit(jax.vmap(_forward, in_axes=(None, 0, 0, None, 0)))
     else:
         plan.fn = jax.jit(_forward)

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from . import ref
 from .bitmap_spmm import bitmap_spmm as _bitmap_spmm_kernel
 from .block_matmul import block_matmul as _block_matmul
+from .edge_sum import edge_sum as _edge_sum_kernel
 from .flash_attention import flash_attention as _flash_kernel
 from .fused_layers import fused_gat_full as _fused_gat_full_kernel
 from .fused_layers import fused_gat_precombined as _fused_gat_pre_kernel
@@ -150,6 +151,29 @@ def bitmap_spmm_batched(block_sparse, h: jnp.ndarray) -> jnp.ndarray:
     batched ExecutionPlan produces when the operands carry a block
     structure, exposed here for direct callers and benchmarks."""
     return jax.vmap(bitmap_spmm, in_axes=(0, 0))(block_sparse, h)
+
+
+def edge_sum(msgs: jnp.ndarray, slot_rows: jnp.ndarray,
+             chunk_tile: jnp.ndarray, *, out_rows: int,
+             tile_rows: int) -> jnp.ndarray:
+    """Sum each destination row's messages over the tiled edge layout
+    (`core.graph.edge_arrays`) into (out_rows, w) f32: the MXU one-hot
+    kernel on TPU; on the ref path the same sums as an XLA segment-sum,
+    padding dropped (`edge_sum_mode()` says which)."""
+    mode = _mode()
+    if mode == "ref":
+        return ref.edge_sum_ref(msgs, slot_rows, chunk_tile,
+                                out_rows=out_rows, tile_rows=tile_rows)
+    return _edge_sum_kernel(msgs, slot_rows, chunk_tile, out_rows=out_rows,
+                            tile_rows=tile_rows,
+                            interpret=(mode == "interpret"))
+
+
+def edge_sum_mode() -> str:
+    """Which form an edges plan's reduction takes right now: "pallas",
+    "interpret" or "ref" (GraphServe counts a TPU dispatch that ran "ref"
+    as `edge_agg_fallbacks`, DESIGN.md §16)."""
+    return _mode()
 
 
 def gat_attention(h: jnp.ndarray, alpha_dst: jnp.ndarray, alpha_src: jnp.ndarray,

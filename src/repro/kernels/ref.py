@@ -58,6 +58,20 @@ def bitmap_spmm_block_ref(blocks: jnp.ndarray, block_cols: jnp.ndarray,
                       ).reshape(rb * bs, f).astype(h.dtype)
 
 
+def edge_sum_ref(msgs: jnp.ndarray, slot_rows: jnp.ndarray,
+                 chunk_tile: jnp.ndarray, *, out_rows: int,
+                 tile_rows: int) -> jnp.ndarray:
+    """Edge-sum oracle: the tiled layout's slots as global destination rows
+    (tile * tile_rows + local row), padding past `out_rows` so the
+    segment-sum drops it."""
+    k, _, c = slot_rows.shape
+    local = slot_rows.reshape(k, c)
+    rows = jnp.where(local >= 0, chunk_tile[:, None] * tile_rows + local,
+                     out_rows)
+    return jax.ops.segment_sum(msgs.astype(jnp.float32), rows.reshape(-1),
+                               num_segments=out_rows)
+
+
 def gat_attention_ref(h: jnp.ndarray, alpha_dst: jnp.ndarray,
                       alpha_src: jnp.ndarray, bias_add: jnp.ndarray,
                       *, negative_slope: float = 0.2) -> jnp.ndarray:

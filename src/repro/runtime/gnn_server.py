@@ -150,8 +150,9 @@ Engine contracts (what tests and operators may rely on):
     device's own memory), a model registered with `agg_backend="auto"`
     whose kind has an edge form (SAGE-mean over every in-neighbour) keeps
     each graph as its edge list: `pad_graph(dense=False)` builds no dense
-    array, `attach` uploads `EdgeOperands` padded to the graph's edge rung
-    once per version, and the `edges` plan gathers and segment-sums them.
+    array, `attach` uploads `EdgeOperands` tiled by destination and padded
+    to the graph's chunk rung once per version, and the `edges` plan
+    gathers the messages and sums them on the MXU (`kernels/edge_sum.py`).
     Such graphs serve unfused fp32; a model that cannot take the form is
     refused at `attach` instead of allocating the dense operands.
 """
@@ -167,8 +168,8 @@ import numpy as np
 
 from repro.core.graph import (BucketLadder, Graph, PaddedGraph,
                               apply_edge_delta, edge_index_from_adjacency,
-                              edge_list_delta, edge_rung,
-                              is_symmetric_adjacency, pad_graph)
+                              edge_list_delta, is_symmetric_adjacency,
+                              pad_graph)
 from repro.core.layers import Techniques
 from repro.core.models import (FUSION_MODES, OPERAND_FIELDS, DeltaSpec,
                                ExecutionPlan, GNNConfig, GranniteOperands,
@@ -411,10 +412,10 @@ class GNNRequest:
     @property
     def batch_key(self) -> BatchKey:
         """What the requests of one dispatch share. An edges request's
-        backend element also names its edge rung (§16): operands of two
-        rungs do not stack."""
-        backend = (f"edges/{self.ops.src.shape[0]}" if self.backend == "edges"
-                   else self.backend)
+        backend element also names its layout, chunks x slots per chunk
+        (§16): operands of two layouts do not stack."""
+        backend = ("edges/{}x{}".format(*self.ops.layout)
+                   if self.backend == "edges" else self.backend)
         return (self.model, self.bucket, self.tier, backend, self.fusion,
                 self.shards)
 
@@ -501,7 +502,7 @@ class GraphServe:
         # one compiled program per (batch_slots, bucket, in_feats); of
         # dense slots' operands (and tier operands), one per (batch_slots,
         # bucket, fieldset, block structure, tier operands); and of
-        # edge-form slots' operands, one per (batch_slots, bucket, rung)
+        # edge-form slots' operands, one per (batch_slots, bucket, layout)
         self._stack_x = jax.jit(jnp.stack)
         self._stack_operands = build_operand_stacker()
         self._stack_edges = jax.jit(lambda ops: jax.tree_util.tree_map(
@@ -564,8 +565,9 @@ class GraphServe:
                         "delta_halo_bytes_full": 0,
                         "delta_dirty_rows": 0,
                         # §16: real directed edges the edges plans
-                        # aggregated, once per layer
-                        "edges_dispatched": 0,
+                        # aggregated, once per layer; requests whose edges
+                        # plan ran the ref reduction on a TPU
+                        "edges_dispatched": 0, "edge_agg_fallbacks": 0,
                         "deadline_misses": 0, "shed_requests": 0}
 
     def _count(self, name: str, delta=1) -> None:
@@ -584,8 +586,8 @@ class GraphServe:
         cfg = self.models[model].cfg
         nf = len(OPERAND_FIELDS[cfg.kind])
         if not pg.dense:
-            return 4 * (2 * edge_rung(pg.edge_index.shape[1]) + pg.capacity
-                        + pg.capacity * cfg.in_feats)
+            return (EdgeOperands.nbytes(*pg.layout, pg.capacity)
+                    + 4 * pg.capacity * cfg.in_feats)
         if part is not None:
             return estimate_shard_entry_bytes(part.shards, part.shard_cap,
                                               part.full_rows, nf,
@@ -945,27 +947,26 @@ class GraphServe:
         return self._warm_blobs
 
     def _warm_edges(self, name: str, bucket: int, warmed: set) -> None:
-        """Compile the edges plan of one (model, bucket) at the edge rung
-        of every edge-form graph of that model attached there: a rung is
-        known only once a graph brings its edges."""
+        """Compile the edges plan of one (model, bucket) at the layout
+        (chunks x slots per chunk) of every edge-form graph of that model
+        attached there: a layout is known only once a graph brings its
+        edges."""
         e = self.models[name]
         b = self.sc.batch_slots
         with self._lock:
-            rungs = {edge_rung(pg.edge_index.shape[1])
-                     for m, pg in self.graphs.values()
-                     if m == name and not pg.dense and pg.capacity == bucket}
-        if not rungs:
+            pgs = [pg for m, pg in self.graphs.values()
+                   if m == name and not pg.dense and pg.capacity == bucket]
+        layouts = {pg.layout for pg in pgs}
+        if not layouts:
             return
         plan = self.plan_for(name, bucket, "fp32", "edges", "none")
         x = self._stack_x([jnp.zeros((bucket, e.cfg.in_feats), jnp.float32)]
                           * b)
-        for rung in sorted(rungs):
-            if (name, plan.key, rung) in warmed:
+        for k, c in sorted(layouts):
+            if (name, plan.key, k, c) in warmed:
                 continue
-            warmed.add((name, plan.key, rung))
-            idx = jnp.zeros((rung,), jnp.int32)
-            eo = EdgeOperands(src=idx, dst=idx,
-                              inv_deg=jnp.zeros((bucket,), jnp.float32))
+            warmed.add((name, plan.key, k, c))
+            eo = EdgeOperands.placeholder(k, c, bucket)
             plan(e.params, x, self._stack_edges([eo] * b)
                  ).block_until_ready()
 
@@ -2099,9 +2100,12 @@ class GraphServe:
                         [r.ops for r in slots],
                         [r.tier_ops for r in slots]
                         if head.tier_ops is not None else None)
-            # an edges dispatch names the real nodes and edges it aggregates
+            # an edges dispatch names the real nodes and edges it
+            # aggregates, and the rows each slot's message gathers write
+            # (chunk slots, §16), by which a trace can find those gathers
             real = ({"nodes": sum(r.pg.num_nodes for r in batch),
-                     "edges": sum(r.pg.edge_index.shape[1] for r in batch)}
+                     "edges": sum(r.pg.edge_index.shape[1] for r in batch),
+                     "gather_rows": head.ops.src.shape[0]}
                     if head.backend == "edges" else {})
             with span("dispatch.device", self.clock, serial, **real):
                 plan = self.plan_for(head.model, head.bucket, head.tier,
@@ -2116,6 +2120,8 @@ class GraphServe:
             # trace-time capture, not a dispatch-time env read: the compiled
             # blob keeps whatever lowering it was traced with
             ran_dense_fallback = plan.grasp_ref_fallback
+            ran_edge_ref = (plan.edge_ref_fallback
+                            and jax.default_backend() == "tpu")
             with span("dispatch.d2h", self.clock, serial) as d2h:
                 host_logits = np.asarray(logits)
                 for i, r in enumerate(batch):
@@ -2126,6 +2132,8 @@ class GraphServe:
             counts = {}
             if real:
                 counts["edges_dispatched"] = e.cfg.num_layers * real["edges"]
+                if ran_edge_ref:
+                    counts["edge_agg_fallbacks"] = len(batch)
             if head.backend == "grasp":
                 counts["grasp_batches"] = 1
                 if ran_dense_fallback:
@@ -2295,6 +2303,19 @@ class GraphServe:
             }
         return out
 
+    def _edge_chunk_fill(self) -> float:
+        """Real edges over chunk slots across the attached edge-form graphs
+        whose current version's operands are resident (§16); 0 with
+        none."""
+        resident = self._cache.view("edges")
+        with self._lock:
+            held = [(pg.edge_index.shape[1],
+                     resident[(gid, self._graph_version[gid])].src.shape[0])
+                    for gid, (_, pg) in self.graphs.items()
+                    if (gid, self._graph_version[gid]) in resident]
+        slots = sum(s for _, s in held)
+        return sum(e for e, _ in held) / slots if slots else 0.0
+
     def summary(self) -> Dict[str, object]:
         lat = np.asarray(self.metrics["latency_s"], np.float64)
         t0, t1 = self.metrics["first_submit_s"], self.metrics["last_finish_s"]
@@ -2372,10 +2393,14 @@ class GraphServe:
                 self.metrics["delta_halo_bytes_exchanged"],
             "delta_halo_bytes_full": self.metrics["delta_halo_bytes_full"],
             "delta_dirty_rows": self.metrics["delta_dirty_rows"],
-            # §16 edge-list form: resident edge-operand bytes, and the real
-            # edges its plans aggregated (once per layer)
+            # §16 edge-list form: resident edge-operand bytes, real edges
+            # over the chunk slots that hold them, the real edges its plans
+            # aggregated (once per layer), and requests that ran the ref
+            # reduction on a TPU
             "edge_operand_bytes": self._cache.kind_bytes("edges"),
+            "edge_chunk_fill": self._edge_chunk_fill(),
             "edges_dispatched": self.metrics["edges_dispatched"],
+            "edge_agg_fallbacks": self.metrics["edge_agg_fallbacks"],
             # §14 SLO loop: deadline outcomes, governor decisions, and the
             # measured-vs-modelled drift of the latency bank (mean
             # EWMA/seed ratio over keys with both — the signal that the

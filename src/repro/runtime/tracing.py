@@ -23,7 +23,8 @@ Span names (`PERF.md` lists the metric that reads each):
   dispatch.device    the plan call through `block_until_ready`; on an
                      edge-list dispatch (DESIGN.md §16) its attrs `nodes`
                      and `edges` count the real nodes and directed edges
-                     it aggregates
+                     it aggregates, and `gather_rows` is the row count of
+                     each slot's message gathers (its chunk slots)
   dispatch.d2h       copying the logits back and unpacking each answer
   request.queue      submission to the start of the answering dispatch
                      (id: uid, parent: serial; ring only)

@@ -13,7 +13,8 @@ import pytest
 
 from repro.core import graph as graph_mod
 from repro.core import layers
-from repro.core.graph import BucketLadder, Graph, edge_rung, pad_graph
+from repro.core.graph import (EDGE_TILE_ROWS, BucketLadder, Graph,
+                              edge_layout, pad_graph)
 from repro.core.models import (GNNConfig, build_operands, edge_operands,
                                forward_baseline, forward_grannite,
                                init_params)
@@ -71,6 +72,11 @@ def _reference(params, cfg, g):
     return np.asarray(out)
 
 
+def _layout(g, cap=EDGE_BUCKET):
+    """(chunks, slots per chunk) of a graph's tiled edge layout."""
+    return edge_layout(g.edge_index[1], cap)
+
+
 def _engine(cfg, params, *, buckets=(EDGE_BUCKET,), slots=2, **reg):
     eng = GraphServe(GraphServeConfig(ladder=BucketLadder(buckets=buckets),
                                       batch_slots=slots, return_logits=True))
@@ -91,7 +97,7 @@ def _close(got, ref):
 
 
 def test_three_layers_match_the_reference_on_one_rung():
-    """Two tenants of one bucket, different edge counts, one edge rung, in
+    """Two tenants of one bucket, different edge counts, one chunk rung, in
     one dispatch: each answer is its own graph's, with nodes that have no
     in-edges reading a mean of 0."""
     cfg = _cfg()
@@ -100,7 +106,7 @@ def test_three_layers_match_the_reference_on_one_rung():
     gs = [_graph(200, 700, seed=1), _graph(230, 720, seed=2)]
     counts = [g.num_edges for g in gs]
     assert counts[0] != counts[1]
-    assert edge_rung(counts[0]) == edge_rung(counts[1])
+    assert _layout(gs[0]) == _layout(gs[1])
     gids = [eng.attach(g, model="sage") for g in gs]
     assert all(not eng.graphs[gid][1].dense for gid in gids)
     got = _serve(eng, gids)
@@ -109,6 +115,60 @@ def test_three_layers_match_the_reference_on_one_rung():
     for r, g in zip(got, gs):
         assert np.bincount(g.edge_index[1], minlength=g.num_nodes).min() == 0
         _close(r.logits, _reference(params, cfg, g))
+
+
+@pytest.mark.parametrize("backend,mode,fallbacks", [
+    ("cpu", None, 0),            # the CPU's ref route is no fallback
+    ("tpu", "ref", 2)])          # a TPU dispatch forced to ref counts both
+def test_summary_reports_chunk_fill_and_ref_fallbacks(backend, mode,
+                                                      fallbacks,
+                                                      monkeypatch):
+    """`edge_chunk_fill` is the attached graphs' real edges over their
+    chunk slots; `edge_agg_fallbacks` counts the requests whose edges plan
+    ran the ref reduction on a TPU backend."""
+    if mode:
+        monkeypatch.setenv("REPRO_KERNEL_MODE", mode)
+    monkeypatch.setattr(gnn_server.jax, "default_backend", lambda: backend)
+    cfg = _cfg()
+    eng = _engine(cfg, _params(cfg))
+    gs = [_graph(200, 700, seed=1), _graph(230, 720, seed=2)]
+    gids = [eng.attach(g, model="sage") for g in gs]
+    _serve(eng, gids)
+    s = eng.summary()
+    slots = sum(np.prod(_layout(g)) for g in gs)
+    assert s["edge_chunk_fill"] == pytest.approx(
+        sum(g.num_edges for g in gs) / slots, rel=1e-12)
+    assert 0.3 < s["edge_chunk_fill"] < 1
+    assert s["edge_agg_fallbacks"] == fallbacks
+
+
+@pytest.mark.parametrize("seed", (5, 6, 7))
+def test_arxiv_plan_kernel_and_ref_routes_agree(seed, monkeypatch):
+    """OGB's arxiv SAGE on the sage-arxiv cell's rehearsal size (300 nodes,
+    1,200 edges, bucket 384), the edges plan's reduction run through the
+    MXU edge-sum kernel in interpret mode and through the ref segment-sum:
+    the routes agree to 5e-7 of the largest logit, and each lies within the
+    cell's 5e-6 of the plain reference."""
+    from repro.configs.gnn import sage
+    from repro.core.models import forward_edges
+    cfg = sage("arxiv")
+    g = planetoid_like(num_nodes=300, num_edges=1200, num_feats=128,
+                       num_classes=40, seed=seed, homophily=0.65,
+                       feat_sparsity=0.0, train_per_class=2)
+    params = _params(cfg, seed)
+    pg = pad_graph(g, capacity=384, dense=False)
+    eo, x = edge_operands(pg), jnp.asarray(pg.features)
+    got = {}
+    for mode in ("interpret", "ref"):
+        monkeypatch.setenv("REPRO_KERNEL_MODE", mode)
+        with jax.default_matmul_precision("highest"):
+            got[mode] = np.asarray(forward_edges(params, cfg, x, eo)
+                                   )[:g.num_nodes]
+    want = _reference(params, cfg, g)
+    scale = np.abs(want).max()
+    assert np.abs(got["interpret"] - got["ref"]).max() < 5e-7 * scale
+    for v in got.values():
+        assert np.abs(v - want).max() < 5e-6 * scale
 
 
 def test_dense_bucket_serves_the_same_model_densely():
@@ -288,14 +348,26 @@ def test_two_layer_models_are_unchanged(kind, extra):
 
 
 def test_edge_operands_pad_into_a_spare_row():
+    """The tiled layout holds every edge once, in (dst, src) order, at its
+    tile's local row; padding slots read row 0 and match no row; the spare
+    tile (row DENSE_BUCKET, one past the bucket) takes the rung's padding
+    chunks."""
     g = _graph(100, 300, seed=15)
     pg = pad_graph(g, capacity=DENSE_BUCKET, dense=False)
     eo = edge_operands(pg)
-    e = g.num_edges
-    rung = edge_rung(e)
-    assert eo.src.shape == eo.dst.shape == (rung,)
-    dst = np.asarray(eo.dst)
-    assert (dst[e:] == DENSE_BUCKET).all() and (np.diff(dst) >= 0).all()
+    k, c = _layout(g, DENSE_BUCKET)
+    assert eo.layout == (k, c) and eo.src.shape == (k * c,)
+    rows = np.asarray(eo.slot_rows).reshape(-1)
+    tile = np.repeat(np.asarray(eo.chunk_tile), c)
+    real = rows >= 0
+    order = np.lexsort(g.edge_index)            # by (dst, src)
+    np.testing.assert_array_equal(
+        (tile * EDGE_TILE_ROWS + rows)[real], g.edge_index[1][order])
+    np.testing.assert_array_equal(np.asarray(eo.src)[real],
+                                  g.edge_index[0][order])
+    assert (np.asarray(eo.src)[~real] == 0).all()
+    spare = DENSE_BUCKET // EDGE_TILE_ROWS
+    assert (tile[~real][-1] == spare) and not real[tile == spare].any()
     deg = np.bincount(g.edge_index[1], minlength=DENSE_BUCKET)
     np.testing.assert_allclose(np.asarray(eo.inv_deg),
                                np.where(deg > 0, 1 / np.maximum(deg, 1), 0),

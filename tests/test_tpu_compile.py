@@ -143,25 +143,52 @@ def test_kernel_compiles_for_v5e(name, shape, monkeypatch):
 
 
 
-def test_edges_plan_compiles_for_v5e_at_arxiv_size(shape):
-    """The edge-list plan (DESIGN.md §16) of OGB's ogbn-arxiv SAGE at its
-    bucket and the edge rung of its 2.33 M directed edges, batch 1, at
-    "highest": the chip's compiler accepts it, and the plan's own
-    temporaries stay far below the chip's memory."""
+def _arxiv_edges_plan(shape, b):
+    """The edges plan of OGB's ogbn-arxiv SAGE at its bucket and the chunk
+    rung of its 2.33 M directed edges (4,992 chunks of 512 slots), at `b`
+    batch slots and "highest", compiled for the described chip."""
     from repro.configs.gnn import sage
-    from repro.core.graph import edge_rung
     from repro.core.models import EdgeOperands, build_plan, init_params
     from repro.runtime.gnn_server import tier_techniques
-    cfg, cap = sage("arxiv"), 169984
-    rung = edge_rung(2331684)
+    cfg, cap, k, c = sage("arxiv"), 169984, 4992, 512
     plan = build_plan(cfg, cap, tier_techniques("sage")["fp32"],
-                      batch_size=1, backend="edges")
+                      batch_size=b, backend="edges")
     params = jax.tree_util.tree_map(
         lambda leaf: shape(leaf.shape, leaf.dtype),
         jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
-    eo = EdgeOperands(shape((1, rung), jnp.int32), shape((1, rung), jnp.int32),
-                      shape((1, cap)))
+    eo = EdgeOperands(shape((b, k * c), jnp.int32),
+                      shape((b, k, 1, c), jnp.int32), shape((b, k), jnp.int32),
+                      shape((b, cap)))
     with jax.default_matmul_precision("highest"):
-        compiled = plan.fn.lower(params, shape((1, cap, cfg.in_feats)), eo,
+        compiled = plan.fn.lower(params, shape((b, cap, cfg.in_feats)), eo,
                                  None, None).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    return cfg, k * c, compiled
+
+
+def test_edges_plan_compiles_for_v5e_at_arxiv_size(shape, monkeypatch):
+    """The edge-list plan (DESIGN.md §16) at ogbn-arxiv size and the
+    engine's default 4 batch slots: the chip's compiler accepts it with
+    the MXU edge-sum kernel in each layer, and the plan's own temporaries
+    stay far below the chip's memory: ONE slot's gathered (K * C, 256) f32
+    messages of the widest layer and little more, since the slots run one
+    after another."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
+    cfg, slots, compiled = _arxiv_edges_plan(shape, 4)
+    assert compiled.as_text().count("tpu_custom_call") >= cfg.num_layers
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        slots * cfg.hidden * 4 + (1 << 30))
+
+
+def test_edges_plan_compiles_in_groups_of_chunks_for_v5e(shape, monkeypatch):
+    """With the gather's block budget at 512 MiB the same plan gathers and
+    sums each layer's chunks in groups (8 at width 256): the chip's
+    compiler accepts the loop around the kernel, and the temporaries shrink
+    to about one group's messages, well under the one-group plan's."""
+    from repro.core import models
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
+    monkeypatch.setattr(models, "EDGE_GATHER_BLOCK_BYTES", 1 << 29)
+    assert models.edge_gather_groups(4992, 512, 256) == 8
+    cfg, slots, compiled = _arxiv_edges_plan(shape, 1)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        slots * cfg.hidden * 4 // 8 + (1 << 30))

@@ -366,5 +366,9 @@ def test_edges_dispatched_counts_real_edges_once_per_layer(monkeypatch):
     edges = sum(g.num_edges for g in gs)
     assert eng.metrics["batches"] == 1
     assert eng.metrics["edges_dispatched"] == 3 * edges
+    # and the rows each slot's message gathers write: its chunk slots
+    (slots,) = {k * c for k, c in (eng.graphs[gid][1].layout
+                                   for gid in gids)}
     (device,) = tr.spans("dispatch.device")
-    assert device.attrs == {"nodes": 410, "edges": edges}
+    assert device.attrs == {"nodes": 410, "edges": edges,
+                            "gather_rows": slots}
